@@ -1,125 +1,68 @@
 """Sharded persistent-halo deployment — the communication-avoiding rows.
 
-Runs the ``pallas-sharded`` backend on an 8-virtual-device CPU mesh
-(subprocess with ``--xla_force_host_platform_device_count=8``) and
-reports:
+Runs the ``pallas-sharded`` backend over every device of this process
+(rows split over a ``data`` axis; on CPU,
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` gives it eight)
+and reports:
 
 * per-iteration wall time of the distributed loop at unroll 1 and 4
   (the deep-halo temporal-blocking schedule checks the condition — and
   exchanges ghosts — once per 4 fused sweeps);
 * the ppermute rounds per while-body counted from the jaxpr, so the
   ≈T× ICI-message reduction of ``unroll=T`` is pinned by structure, not
-  just wall time (CPU interpret-mode timings only carry ratios);
+  just wall time;
 * the jnp 1:n deployment as the non-persistent reference.
 
-Absolute numbers are only meaningful on TPU; the recorded ratios
-(exchange rounds per sweep, sharded vs jnp-distributed wall time) carry
-the claims across PRs.
+Everything runs in this process: the chip belongs to one process.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import textwrap
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-from .common import record
+from repro.core import (GridPartition, LoopOfStencilReduce,
+                        distributed_loop_of_stencil_reduce)
+from repro.core.introspect import count_primitive, while_body_eqns
+from repro.kernels import ref as R
+from repro.sharding.specs import make_mesh
+from .common import record, time_fn
 
 ITERS = 8
 
 
-def _worker_code(size: int, iters: int) -> str:
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    return textwrap.dedent("""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import sys, time, json
-        sys.path.insert(0, %r)
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.core import LoopOfStencilReduce, GridPartition
-        from repro.core import distributed_loop_of_stencil_reduce
-        from repro.kernels import ref as R
-
-        SIZE, ITERS = %d, %d
-        rng = np.random.default_rng(0)
-        a = jnp.asarray(rng.normal(size=(SIZE, SIZE)), jnp.float32)
-        part = GridPartition(mesh=jax.make_mesh((8,), ("data",)),
-                             axis_names=("data",), array_axes=(0,))
-        heat = R.heat_taps(0.1)
-
-        def sharded(unroll):
-            return LoopOfStencilReduce(
+def run(sizes=(256,)) -> list[dict]:
+    n = len(jax.devices())
+    part = GridPartition(mesh=make_mesh((n,), ("data",)),
+                         axis_names=("data",), array_axes=(0,))
+    heat = R.heat_taps(0.1)
+    rows = []
+    rng = np.random.default_rng(0)
+    for size in sizes:
+        a = jnp.asarray(rng.normal(size=(size, size)), jnp.float32)
+        for unroll in (1, 4):
+            loop = LoopOfStencilReduce(
                 f=heat, k=1, combine="max", cond=lambda r: False,
                 delta=R.abs_delta, boundary="zero", max_iters=ITERS,
                 unroll=unroll, backend="pallas-sharded", partition=part,
-                interpret=True, block=(32, 128))
-
-        def time_run(runner):
-            # ONE jit wrapper per config: the warmup compiles it, the
-            # timed calls hit the cache and measure the loop itself
-            r = runner(a); jax.block_until_ready(r.a)
-            ts = []
-            for _ in range(3):
-                t0 = time.perf_counter()
-                r = runner(a); jax.block_until_ready(r.a)
-                ts.append(time.perf_counter() - t0)
-            return float(np.median(ts))
-
-        from repro.core.introspect import count_primitive, while_body_eqns
-
-        out = []
-        for unroll in (1, 4):
-            loop = sharded(unroll)
-            t = time_run(jax.jit(loop.run))
+                block=(32, 128))
+            t = time_fn(jax.jit(lambda x: loop.run(x).a), a)
             ppb = count_primitive(
                 while_body_eqns(lambda x: loop.run(x).a, a), "ppermute")
-            out.append({"kind": "sharded", "unroll": unroll,
-                        "seconds": t, "per_iter": t / ITERS,
-                        "ppermute_per_body": ppb})
-
-        dist = lambda x: distributed_loop_of_stencil_reduce(
+            # exchange rounds per SWEEP: body rounds / sweeps-per-body
+            rows.append(record(
+                f"sharded_{size}_persistent", t, backend="pallas-sharded",
+                unroll=unroll, mesh=f"{n}x1",
+                derived=(f"per_iter={t / ITERS * 1e6:.1f}us;"
+                         f"ppermute_per_body={ppb};"
+                         f"ppermute_per_sweep={ppb / unroll:g}")))
+        dist = jax.jit(lambda x: distributed_loop_of_stencil_reduce(
             heat, "max", lambda r: False, x, k=1, part=part,
-            delta=R.abs_delta, max_iters=ITERS)
-        t = time_run(jax.jit(dist))
-        out.append({"kind": "jnp-dist", "unroll": 1, "seconds": t,
-                    "per_iter": t / ITERS, "ppermute_per_body": None})
-        print(json.dumps(out))
-    """) % (src, size, iters)
-
-
-def run(sizes=(256,)) -> list[dict]:
-    rows = []
-    for size in sizes:
-        try:
-            out = subprocess.run(
-                [sys.executable, "-c", _worker_code(size, ITERS)],
-                capture_output=True, text=True, timeout=900)
-            if out.returncode != 0:
-                raise RuntimeError(out.stderr[-1500:])
-            results = json.loads(out.stdout.strip().splitlines()[-1])
-        except Exception as e:
-            rows.append(record(f"sharded_{size}", -1.0, mesh="8x1",
-                               derived=f"ERROR:{type(e).__name__}"))
-            continue
-        ppb = {r["unroll"]: r["ppermute_per_body"]
-               for r in results if r["kind"] == "sharded"}
-        for r in results:
-            if r["kind"] == "sharded":
-                u = r["unroll"]
-                # exchange rounds per SWEEP: body rounds / sweeps-per-body
-                per_sweep = ppb[u] / u
-                rows.append(record(
-                    f"sharded_{size}_persistent", r["seconds"],
-                    backend="pallas-sharded", unroll=u, mesh="8x1",
-                    derived=(f"per_iter={r['per_iter'] * 1e6:.1f}us;"
-                             f"ppermute_per_body={ppb[u]};"
-                             f"ppermute_per_sweep={per_sweep:g}")))
-            else:
-                rows.append(record(
-                    f"sharded_{size}_jnp_dist", r["seconds"],
-                    backend="jnp", mesh="8x1",
-                    derived=f"per_iter={r['per_iter'] * 1e6:.1f}us"))
+            delta=R.abs_delta, max_iters=ITERS).a)
+        t = time_fn(dist, a)
+        rows.append(record(
+            f"sharded_{size}_jnp_dist", t, backend="jnp", mesh=f"{n}x1",
+            derived=f"per_iter={t / ITERS * 1e6:.1f}us"))
     return rows
 
 

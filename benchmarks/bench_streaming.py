@@ -31,7 +31,7 @@ continuous-refill claim even on CPU-interpret CI where wall time is
 dominated by the emulated kernel.  The same round-vs-continuous
 comparison also runs on the COMPOSED deployment (lanes over ``data`` ×
 per-lane frames ppermute-decomposed over ``model``,
-``pallas-sharded``) in an 8-virtual-device subprocess
+``pallas-sharded``) over this process's devices
 (:func:`run_composed_continuous`).
 
 Reported per deployment: median wall time, items/sec, and (for the lane
@@ -50,11 +50,10 @@ trip-count spread the chained engine simply runs fewer lane sweeps
 per-segment cost below the waste it reclaims.
 
 :func:`run_recovery` measures the preemption-recovery path (DESIGN.md
-§Recovery): a recovery-armed continuous farm is killed at ~50% of its
-segments in a subprocess, respawned via
-``repro.resilience.run_to_completion``, and the resumed run's
-``recovery_seconds`` / ``replayed_items`` / ``recovered_occupants``
-are reported next to the fault-free wall time.
+§Recovery): a recovery-armed continuous farm is preempted at ~50% of
+its segments, a fresh engine resumes from snapshot + journal, and the
+resumed run's ``recovery_seconds`` / ``replayed_items`` /
+``recovered_occupants`` are reported next to the fault-free wall time.
 """
 from __future__ import annotations
 
@@ -62,8 +61,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import FarmEngine, LoopOfStencilReduce, sharded_farm
+from repro.core import (FarmEngine, GridPartition, LoopOfStencilReduce,
+                        sharded_farm)
 from repro.kernels import ref as R
+from repro.sharding.specs import make_mesh
 from .common import record
 
 
@@ -168,162 +169,68 @@ def run_continuous(sizes=(64,), stream_n=16, lanes=4,
     return rows
 
 
-_COMPOSED_WORKER = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import sys, time, json
-sys.path.insert(0, %r)
-import jax, numpy as np
-from repro.core import FarmEngine, GridPartition, LoopOfStencilReduce
-
-SIZE, STREAM_N, LANES, ITERS = %d, %d, %d, %d
-
-def countdown(get, *_):
-    return get(0, 0) - 1.0
-
-mesh = jax.make_mesh((2, 4), ("data", "model"))
-part = GridPartition(mesh=mesh, axis_names=("model",), array_axes=(0,))
-
-def mk():
-    return LoopOfStencilReduce(
-        f=countdown, k=1, combine="max", cond=lambda r: r < 0.5,
-        boundary="zero", max_iters=64, backend="pallas-sharded",
-        partition=part, interpret=True, block=(16, 128))
-
-base = np.linspace(0.1, 0.9, SIZE * SIZE,
-                   dtype=np.float32).reshape(SIZE, SIZE)
-trips = [40 if i %% 4 == 3 else 2 for i in range(STREAM_N)]
-items = [base + float(t) - 1.0 for t in trips]
-
-eng_round = FarmEngine(mk(), lanes=LANES, mesh=mesh)
-eng_cont = FarmEngine(mk(), lanes=LANES, mesh=mesh, segment=8)
-
-def time_mode(fn, eng):
-    fn()                                      # warmup/compile
-    ts = []
-    for _ in range(ITERS):
-        t0 = time.perf_counter()
-        fn()
-        ts.append(time.perf_counter() - t0)
-    runs = ITERS + 1
-    return (float(np.median(ts)), eng.wasted_lane_steps // runs,
-            eng.lane_steps // runs)
-
-t_r, w_r, s_r = time_mode(
-    lambda: eng_round.run(items, lambda r: None), eng_round)
-t_c, w_c, s_c = time_mode(
-    lambda: eng_cont.run(items, lambda r: None, continuous=True),
-    eng_cont)
-print(json.dumps({"round": [t_r, w_r, s_r],
-                  "continuous": [t_c, w_c, s_c]}))
-"""
-
-
 def run_composed_continuous(size=64, stream_n=12, lanes=4,
                             iters=3) -> list[dict]:
     """Round barrier vs continuous refill on the COMPOSED (lanes over
     'data' × per-lane frames ppermute-decomposed over 'model')
-    deployment — an 8-virtual-device subprocess, bimodal trip counts.
-    The waste ratio carries the claim (CPU interpret wall time is
-    emulation-bound); parity and jaxpr structure are pinned in
-    tests/core/test_farm.py::TestComposedContinuous."""
-    import json
-    import os
-    import subprocess
-    import sys
+    deployment over this process's devices, bimodal trip counts.  The
+    waste ratio carries the claim; parity and jaxpr structure are pinned
+    in tests/core/test_farm.py::TestComposedContinuous."""
+    n = len(jax.devices())
+    data = 2 if n >= 2 else 1
+    mesh = make_mesh((data, n // data), ("data", "model"))
+    tag = f"{data}x{n // data}"
+    part = GridPartition(mesh=mesh, axis_names=("model",), array_axes=(0,))
 
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    code = _COMPOSED_WORKER % (src, size, stream_n, lanes, iters)
-    try:
-        out = subprocess.run([sys.executable, "-c", code],
-                             capture_output=True, text=True, timeout=900)
-        if out.returncode != 0:
-            raise RuntimeError(out.stderr[-1500:])
-        res = json.loads(out.stdout.strip().splitlines()[-1])
-    except Exception as e:
-        return [record(f"stream_{size}_composed", -1.0, mesh="2x4",
-                       derived=f"ERROR:{type(e).__name__}")]
-    rows = []
-    (t_r, w_r, s_r), (t_c, w_c, s_c) = res["round"], res["continuous"]
-    rows.append(record(
-        f"stream_{size}_composed_round_bimodal", t_r,
-        backend="pallas-sharded", mesh="2x4",
-        derived=(f"items_per_s={stream_n / t_r:.1f};"
-                 f"wasted_lane_steps={w_r};lane_steps={s_r}")))
-    rows.append(record(
-        f"stream_{size}_composed_continuous_bimodal", t_c,
-        backend="pallas-sharded", mesh="2x4",
-        derived=(f"items_per_s={stream_n / t_c:.1f};"
-                 f"wasted_lane_steps={w_c};lane_steps={s_c};"
-                 f"waste_cut={w_r / max(w_c, 1):.1f}x")))
-    return rows
+    def mk():
+        return LoopOfStencilReduce(
+            f=lambda get, *_: get(0, 0) - 1.0, k=1, combine="max",
+            cond=lambda r: r < 0.5, boundary="zero", max_iters=64,
+            backend="pallas-sharded", partition=part, block=(16, 128))
 
-
-_RECOVERY_WORKER = """
-import json, os, sys
-sys.path.insert(0, %(src)r)
-import time
-import numpy as np
-from repro.core import FarmEngine, LoopOfStencilReduce
-from repro.resilience import FaultPlan, RecoveryConfig
-
-SIZE, STREAM_N, LANES, AT = %(size)d, %(stream_n)d, %(lanes)d, %(at)d
-
-def mk():
-    return LoopOfStencilReduce(
-        f=lambda get, *_: get(0, 0) - 1.0, k=1, combine="max",
-        cond=lambda r: r < 0.5, boundary="zero", max_iters=64,
-        backend="pallas", block=(32, 128))
-
-base = np.linspace(0.1, 0.9, SIZE * SIZE,
-                   dtype=np.float32).reshape(SIZE, SIZE)
-trips = [40 if i %% 4 == 3 else 2 for i in range(STREAM_N)]
-items = [base + float(t) - 1.0 for t in trips]
-
-rec = RecoveryConfig(dir=%(recdir)r, snapshot_every=1)
-resume = os.path.isdir(rec.snap_dir) or os.path.exists(rec.journal_path)
-# armed on first launch only; AT sits at ~50%% of the uninterrupted
-# run's segment count
-hook = None if resume else FaultPlan(
-    lanes=LANES, preempt_at_segment=AT).preempt_hook()
-eng = FarmEngine(mk(), lanes=LANES, segment=8)
-t0 = time.perf_counter()
-n = eng.run(items, lambda r: None, continuous=True, recovery=rec,
-            resume=resume, on_segment=hook)
-wall = time.perf_counter() - t0
-with open(%(statpath)r, "w") as f:
-    json.dump({"n_out": n, "wall": wall,
-               "recovery_seconds": eng.stats["recovery_seconds"],
-               "replayed_items": eng.stats["replayed_items"],
-               "recovered_occupants": eng.stats["recovered_occupants"],
-               "segments": eng.stats["segments"],
-               "snapshots": eng.stats["snapshots"]}, f)
-"""
+    items = _bimodal_items(size, stream_n)
+    eng_round = FarmEngine(mk(), lanes=lanes, mesh=mesh)
+    eng_cont = FarmEngine(mk(), lanes=lanes, mesh=mesh, segment=8)
+    ts = paired_times(
+        [("round", lambda: eng_round.run(items, lambda r: None)),
+         ("continuous", lambda: eng_cont.run(items, lambda r: None,
+                                             continuous=True))],
+        warmup=1, iters=iters)
+    runs = iters + 1
+    w_r, s_r = (eng_round.wasted_lane_steps // runs,
+                eng_round.lane_steps // runs)
+    w_c, s_c = (eng_cont.wasted_lane_steps // runs,
+                eng_cont.lane_steps // runs)
+    t_r, t_c = ts["round"], ts["continuous"]
+    return [
+        record(f"stream_{size}_composed_round_bimodal", t_r,
+               backend="pallas-sharded", mesh=tag,
+               derived=(f"items_per_s={stream_n / t_r:.1f};"
+                        f"wasted_lane_steps={w_r};lane_steps={s_r}")),
+        record(f"stream_{size}_composed_continuous_bimodal", t_c,
+               backend="pallas-sharded", mesh=tag,
+               derived=(f"items_per_s={stream_n / t_c:.1f};"
+                        f"wasted_lane_steps={w_c};lane_steps={s_c};"
+                        f"waste_cut={w_r / max(w_c, 1):.1f}x"))]
 
 
 def run_recovery(size=64, stream_n=16, lanes=4) -> list[dict]:
-    """Preempt-at-~50%% kill-and-respawn: a recovery-armed continuous
-    farm is killed (``os._exit``, no cleanup) halfway through a bimodal
-    stream and respawned with ``--resume`` semantics.  Records the
-    resumed run's ``recovery_seconds`` (journal replay + snapshot
-    restore + re-seating, the restart tax the snapshot cadence buys)
-    and ``replayed_items`` / ``recovered_occupants`` next to the
-    fault-free wall time — the robustness claim's standing perf row."""
-    import json
-    import os
-    import subprocess
-    import sys
+    """Preempt-at-~50% and resume: a recovery-armed continuous farm is
+    preempted halfway through a bimodal stream and a FRESH engine
+    resumes from its snapshot + journal.  Records the resumed run's
+    ``recovery_seconds`` (journal replay + snapshot restore +
+    re-seating, the restart tax the snapshot cadence buys) and
+    ``replayed_items`` / ``recovered_occupants`` next to the fault-free
+    wall time.  The preemption raises in this process (the chip belongs
+    to one process); crash-hardness under a real ``os._exit`` is pinned
+    by the subprocess tests in tests/resilience/test_recovery.py."""
     import tempfile
     import time as _time
 
-    from repro.resilience.recovery import run_to_completion
+    from repro.resilience import FaultPlan, RecoveryConfig
+    from repro.resilience.recovery import PreemptionError
 
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    # fault-free baseline in-process (same engine config)
-    base = np.linspace(0.1, 0.9, size * size,
-                       dtype=np.float32).reshape(size, size)
-    items = [base + float(40 if i % 4 == 3 else 2) - 1.0
-             for i in range(stream_n)]
+    items = _bimodal_items(size, stream_n)
     eng0 = FarmEngine(_mk_countdown(), lanes=lanes, segment=8)
     eng0.run(items, lambda r: None, continuous=True)     # compile
     segments0 = eng0.stats["segments"]
@@ -334,42 +241,42 @@ def run_recovery(size=64, stream_n=16, lanes=4) -> list[dict]:
     assert n0 == stream_n
 
     with tempfile.TemporaryDirectory() as d:
-        statpath = os.path.join(d, "stats.json")
-        code = _RECOVERY_WORKER % {
-            "src": src, "size": size, "stream_n": stream_n,
-            "lanes": lanes, "at": max(segments0 // 2, 1),
-            "recdir": os.path.join(d, "rec"), "statpath": statpath}
-        env = dict(os.environ)
+        rec = RecoveryConfig(dir=d, snapshot_every=1)
+        hook = FaultPlan(lanes=lanes,
+                         preempt_at_segment=max(segments0 // 2, 1)
+                         ).preempt_hook(mode="raise")
+        t0 = _time.perf_counter()
         try:
-            t0 = _time.perf_counter()
-            restarts = run_to_completion(
-                [sys.executable, "-c", code], env=env, max_restarts=4,
-                timeout=900)
-            t_total = _time.perf_counter() - t0
-            with open(statpath) as f:
-                st = json.load(f)
-        except Exception as e:
-            return [record(f"stream_{size}_recovery_preempt50", -1.0,
-                           derived=f"ERROR:{type(e).__name__}")]
-    if st["n_out"] != stream_n:
-        return [record(f"stream_{size}_recovery_preempt50", -1.0,
-                       derived=f"ERROR:items={st['n_out']}")]
+            FarmEngine(_mk_countdown(), lanes=lanes, segment=8).run(
+                items, lambda r: None, continuous=True, recovery=rec,
+                on_segment=hook)
+        except PreemptionError:
+            pass
+        else:
+            raise RuntimeError("the stream ended before its preemption")
+        eng = FarmEngine(_mk_countdown(), lanes=lanes, segment=8)
+        t1 = _time.perf_counter()
+        n = eng.run(items, lambda r: None, continuous=True, recovery=rec,
+                    resume=True)
+        wall = _time.perf_counter() - t1
+        t_total = _time.perf_counter() - t0
+    if n != stream_n:
+        raise RuntimeError(f"resumed stream emitted {n} of {stream_n}")
+    st = eng.stats
     return [record(
-        f"stream_{size}_recovery_preempt50", st["wall"],
-        backend="pallas",
+        f"stream_{size}_recovery_preempt50", wall, backend="pallas",
         derived=(f"recovery_seconds={st['recovery_seconds']:.4f};"
                  f"replayed_items={st['replayed_items']};"
                  f"recovered_occupants={st['recovered_occupants']};"
-                 f"restarts={restarts};"
-                 f"snapshots={st['snapshots']};"
+                 f"restarts=1;snapshots={st['snapshots']};"
                  f"clean_wall={t_clean:.4f};"
-                 f"total_wall_with_kill={t_total:.4f}"))]
+                 f"total_wall_with_preemption={t_total:.4f}"))]
 
 
 def run(sizes=(64,), stream_n=24, lanes=4, iters=9) -> list[dict]:
     rows = []
     rng = np.random.default_rng(0)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     for size in sizes:
         items = _stream(rng, size, stream_n)
         for backend in ("pallas",):

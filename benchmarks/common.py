@@ -5,7 +5,7 @@ on this host: the *naïve* deployment (host-driven loop, full D2H+H2D
 round-trip per iteration — the strawman of §3.3) against the *persistent*
 deployment (the Loop-of-stencil-reduce while_loop, device memory
 persistence) across the engine's backend axis, and 1-device vs 1:n
-(subprocess with placeholder devices).  Wall-clock ratios, not absolute
+over this process's devices.  Wall-clock ratios, not absolute
 times, carry the claims.
 
 Every suite emits ``record`` dicts — one per configuration — which the

@@ -10,8 +10,9 @@ current baseline.
     Table 1 (Helmholtz)      -> bench_helmholtz   (backend/unroll axis)
     Table 2 (Sobel stream)   -> bench_sobel
     Table 3 (restoration)    -> bench_restoration (backend/unroll axis)
-    1:n sharded (§3.4 + CA)  -> bench_sharded (8-device mesh subprocess,
-                                per-iteration time + ppermute rounds)
+    1:n sharded (§3.4 + CA)  -> bench_sharded (mesh over this process's
+                                devices, per-iteration time + ppermute
+                                rounds)
     1:1 streaming (§4.2/4.3) -> bench_streaming (lane-slot reuse vs the
                                 per-batch sharded_farm path; items/sec +
                                 host-transfer bytes/item; round vs
@@ -23,7 +24,11 @@ current baseline.
     §Roofline (TPU target)   -> bench_roofline (reads runs/dryrun)
 
 ``--quick`` shrinks sizes for CI-speed runs; ``--out-dir`` relocates the
-JSON file (default: current directory).
+JSON file (default: current directory).  Every suite runs in this one
+process (a chip belongs to one process); the multi-device suites span
+whatever devices it has — on CPU,
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` gives it eight.
+A suite that raises is reported and the harness exits non-zero.
 """
 from __future__ import annotations
 
@@ -42,10 +47,12 @@ def main() -> None:
                     help="where BENCH_summary.json is written")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_helmholtz, bench_restoration, bench_roofline,
                    bench_serve, bench_sharded, bench_sobel,
                    bench_streaming)
-    from .common import csv_row, record, write_summary
+    from .common import csv_row, write_summary
 
     suites = {
         "helmholtz": lambda: bench_helmholtz.run(
@@ -70,22 +77,24 @@ def main() -> None:
     only = set(args.only.split(",")) if args.only else set(suites)
 
     all_rows: dict[str, list] = {}
+    failed = []
     print("name,us_per_call,derived")
     for name, fn in suites.items():
         if name not in only:
             continue
         try:
             rows = list(fn())
-        except Exception as e:  # keep the harness running
+        except Exception:  # report, run the other suites, exit non-zero
             traceback.print_exc(file=sys.stderr)
-            print(f"{name}_suite,-1,ERROR:{type(e).__name__}")
-            rows = [record(f"{name}_suite", -1.0,
-                           derived=f"ERROR:{type(e).__name__}")]
+            failed.append(name)
+            continue
         for row in rows:
             print(csv_row(row), flush=True)
         all_rows[name] = rows
     path = write_summary(all_rows, args.out_dir)
     print(f"# wrote {path}", file=sys.stderr)
+    if failed:
+        sys.exit(f"failed suites: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

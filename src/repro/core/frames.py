@@ -8,14 +8,23 @@ after it.  This module hoists both out of the loop by making the *framed*
 array the canonical loop-carried representation:
 
     ┌──────────────────────────────┐
-    │ ghost ring (pad = k·T wide)  │   frame shape: (gm·bm + 2·pad,
-    │  ┌────────────┬───────────┐  │                 gn·bn + 2·pad)
+    │ margin (r0 rows, c0 cols)    │   frame shape: (gm·bm + 2·r0,
+    │  ┌────────────┬───────────┐  │                 gn·bn + 2·c0)
     │  │ domain     │ round-up  │  │
-    │  │ (m, n)     │ (inert)   │  │   domain at [pad:pad+m, pad:pad+n]
+    │  │ (m, n)     │ (inert)   │  │   domain at [r0:r0+m, c0:c0+n]
     │  ├────────────┴───────────┤  │
     │  │ block round-up (inert) │  │
     │  └────────────────────────┘  │
     └──────────────────────────────┘
+
+The margin holds the ghost ring (``pad = k·T`` deep, right around the
+domain) and is rounded up to the TPU's (8, 128) tiling:
+``r0 = ceil(pad / 8)·8``, ``c0 = ceil(pad / 128)·128``.  With (8, 128)-
+aligned blocks every HBM↔VMEM window the kernels move — the
+(bm + 2·r0, bn + 2·c0) input window at (i·bm, j·bn) and the (bm, bn)
+output tile at (r0 + i·bm, c0 + j·bn) — then has an aligned shape and an
+aligned offset, which Mosaic's DMA requires.  Margin cells outside the
+ring are never read by a domain cell's dependency cone.
 
 The frame is built **once** before the ``while_loop`` (:func:`make_frame`),
 kernels read and write it directly, and only the ghost ring — O(m+n) edge
@@ -36,6 +45,9 @@ import jax
 import jax.numpy as jnp
 
 from .semantics import Boundary
+
+
+TILE = (8, 128)      # TPU (sublane, lane) tiling of a 32-bit array
 
 
 def ceil_mul(x: int, q: int) -> int:
@@ -62,21 +74,33 @@ class FrameSpec:
         return self.gm * self.bm, self.gn * self.bn
 
     @property
+    def origin(self) -> tuple[int, int]:
+        """Frame coordinates of domain cell (0, 0): the tile-aligned
+        margin that holds the ``pad``-deep ghost ring."""
+        return ceil_mul(self.pad, TILE[0]), ceil_mul(self.pad, TILE[1])
+
+    @property
+    def domain(self) -> tuple[slice, slice]:
+        """Index of the (m, n) domain inside the frame."""
+        r0, c0 = self.origin
+        return slice(r0, r0 + self.m), slice(c0, c0 + self.n)
+
+    @property
     def shape(self) -> tuple[int, int]:
-        mi, ni = self.interior
-        return mi + 2 * self.pad, ni + 2 * self.pad
+        (mi, ni), (r0, c0) = self.interior, self.origin
+        return mi + 2 * r0, ni + 2 * c0
 
 
 def frame_spec(m: int, n: int, *, k: int = 1, block=(256, 256),
                sweeps: int = 1) -> FrameSpec:
     """Build the frame geometry for an (m, n) domain.
 
-    ``block`` is clipped to TPU-friendly rounded domain sizes (sublane
-    multiple of 8, lane multiple of 128) exactly like the one-shot kernels;
-    ``sweeps`` > 1 widens the ghost ring for temporal blocking.
+    ``block`` is clipped to the domain and rounded up to the (8, 128)
+    tiling, so every window the kernels DMA is tile-aligned; ``sweeps``
+    > 1 widens the ghost ring for temporal blocking.
     """
-    bm = min(block[0], ceil_mul(m, 8))
-    bn = min(block[1], ceil_mul(n, 128))
+    bm = ceil_mul(min(block[0], m), TILE[0])
+    bn = ceil_mul(min(block[1], n), TILE[1])
     gm, gn = -(-m // bm), -(-n // bn)
     pad = k * sweeps
     if pad >= min(m, n):
@@ -94,7 +118,7 @@ def make_frame(a: jnp.ndarray, spec: FrameSpec,
     persistent path.
     """
     frame = jnp.zeros(spec.shape, a.dtype)
-    frame = jax.lax.dynamic_update_slice(frame, a, (spec.pad, spec.pad))
+    frame = jax.lax.dynamic_update_slice(frame, a, spec.origin)
     return refresh_frame(frame, spec, boundary)
 
 
@@ -120,46 +144,22 @@ def refresh_frame(frame: jnp.ndarray, spec: FrameSpec,
                   boundary: Boundary | str) -> jnp.ndarray:
     """Re-assert the ⊥ ghost ring around the (m, n) domain — O(m+n) cells.
 
-    Column strips are filled from domain columns first, then row strips run
-    full-width over the column-refreshed frame, so corners compose exactly
-    like ``jnp.pad``'s axis-sequential modes.  Cells beyond the ``pad``-wide
-    ring (deep round-up garbage) are never read by any domain dependency
-    cone and are left untouched.
+    Row strips are filled from domain rows first, then column strips run
+    the ring's full height over the row-refreshed frame, so corners
+    compose exactly like ``jnp.pad``'s axis-sequential modes.  Cells
+    beyond the ``pad``-deep ring (margin and deep round-up garbage) are
+    never read by any domain dependency cone and are left untouched.
     """
     boundary = Boundary(boundary)
-    p, m, n = spec.pad, spec.m, spec.n
-    r0, r1 = p, p + m                      # domain rows in frame coords
-    if boundary in (Boundary.ZERO, Boundary.NAN):
-        fill = 0.0 if boundary is Boundary.ZERO else jnp.nan
-        frame = frame.at[r0:r1, 0:p].set(fill)
-        frame = frame.at[r0:r1, p + n:p + n + p].set(fill)
-        frame = frame.at[0:p, :].set(fill)
-        frame = frame.at[r1:r1 + p, :].set(fill)
-        return frame
-    if boundary is Boundary.REFLECT:
-        # ghost col p-d mirrors domain col p+d (no edge repeat), as jnp.pad
-        frame = frame.at[r0:r1, 0:p].set(
-            jnp.flip(frame[r0:r1, p + 1:2 * p + 1], axis=1))
-        frame = frame.at[r0:r1, p + n:p + n + p].set(
-            jnp.flip(frame[r0:r1, p + n - 1 - p:p + n - 1], axis=1))
-        frame = frame.at[0:p, :].set(
-            jnp.flip(frame[p + 1:2 * p + 1, :], axis=0))
-        frame = frame.at[r1:r1 + p, :].set(
-            jnp.flip(frame[r1 - 1 - p:r1 - 1, :], axis=0))
-        return frame
-    if boundary is Boundary.WRAP:
-        frame = frame.at[r0:r1, 0:p].set(frame[r0:r1, p + n - p:p + n])
-        frame = frame.at[r0:r1, p + n:p + n + p].set(frame[r0:r1, p:2 * p])
-        frame = frame.at[0:p, :].set(frame[r1 - p:r1, :])
-        frame = frame.at[r1:r1 + p, :].set(frame[p:2 * p, :])
-        return frame
-    raise ValueError(boundary)
+    (r0, c0), p = spec.origin, spec.pad
+    frame = _refresh_axis_local(frame, spec, 0, boundary, c0, c0 + spec.n)
+    return _refresh_axis_local(frame, spec, 1, boundary,
+                               r0 - p, r0 + spec.m + p)
 
 
 def unframe(frame: jnp.ndarray, spec: FrameSpec) -> jnp.ndarray:
     """Slice the (m, n) domain back out — once, after convergence."""
-    p = spec.pad
-    return frame[p:p + spec.m, p:p + spec.n]
+    return frame[spec.domain]
 
 
 # ---------------------------------------------------------------------------
@@ -214,14 +214,13 @@ def refill_lane_frames(frames: jnp.ndarray, interiors: jnp.ndarray,
     frame allocation: under jit donation the slots update in place.
     """
     frames = jax.lax.dynamic_update_slice(
-        frames, interiors.astype(frames.dtype), (0, spec.pad, spec.pad))
+        frames, interiors.astype(frames.dtype), (0, *spec.origin))
     return jax.vmap(lambda f: refresh_frame(f, spec, boundary))(frames)
 
 
 def unframe_lanes(frames: jnp.ndarray, spec: FrameSpec) -> jnp.ndarray:
     """Slice every lane's (m, n) domain back out — once per round."""
-    p = spec.pad
-    return frames[:, p:p + spec.m, p:p + spec.n]
+    return frames[(slice(None), *spec.domain)]
 
 
 def refill_slot_frame(frames: jnp.ndarray, interior: jnp.ndarray,
@@ -238,8 +237,7 @@ def refill_slot_frame(frames: jnp.ndarray, interior: jnp.ndarray,
     same compilation serves every refill of the stream.
     """
     frames = jax.lax.dynamic_update_slice(
-        frames, interior[None].astype(frames.dtype),
-        (idx, spec.pad, spec.pad))
+        frames, interior[None].astype(frames.dtype), (idx, *spec.origin))
     return jax.vmap(lambda f: refresh_frame(f, spec, boundary))(frames)
 
 
@@ -255,8 +253,7 @@ def refill_slot_env(env_frames: jnp.ndarray, e: jnp.ndarray, idx,
     b = Boundary(boundary)
     ghost = b if b is Boundary.WRAP else Boundary.ZERO
     env_frames = jax.lax.dynamic_update_slice(
-        env_frames, e[None].astype(env_frames.dtype),
-        (idx, spec.pad, spec.pad))
+        env_frames, e[None].astype(env_frames.dtype), (idx, *spec.origin))
     return jax.vmap(lambda f: refresh_frame(f, spec, ghost))(env_frames)
 
 
@@ -274,8 +271,7 @@ def refill_lanes_masked(frames: jnp.ndarray, take: jnp.ndarray,
     idempotent for untouched lanes (their rings already agree with
     their domains) — the same argument the per-slot refill relies on.
     """
-    p = spec.pad
-    cur = frames[:, p:p + spec.m, p:p + spec.n]
+    cur = unframe_lanes(frames, spec)
     new = jnp.where(take[:, None, None], interiors.astype(frames.dtype),
                     cur)
     return refill_lane_frames(frames, new, spec, boundary)
@@ -294,8 +290,7 @@ def refill_lanes_env_masked(env_frames: jnp.ndarray, take: jnp.ndarray,
                         cur)
         return refill_lane_env(env_frames, new, spec, boundary,
                                halo=False)
-    p = spec.pad
-    cur = env_frames[:, p:p + spec.m, p:p + spec.n]
+    cur = unframe_lanes(env_frames, spec)
     new = jnp.where(take[:, None, None], e.astype(env_frames.dtype), cur)
     return refill_lane_env(env_frames, new, spec, boundary, halo=True)
 
@@ -362,7 +357,7 @@ def refill_lane_env(env_frames: jnp.ndarray, e: jnp.ndarray,
     b = Boundary(boundary)
     ghost = b if b is Boundary.WRAP else Boundary.ZERO
     env_frames = jax.lax.dynamic_update_slice(
-        env_frames, e.astype(env_frames.dtype), (0, spec.pad, spec.pad))
+        env_frames, e.astype(env_frames.dtype), (0, *spec.origin))
     return jax.vmap(lambda f: refresh_frame(f, spec, ghost))(env_frames)
 
 
@@ -436,21 +431,22 @@ def _refresh_axis_local(frame, spec: FrameSpec, axis: int,
     """Local ⊥ fill of one axis's ghost strips (non-decomposed axis),
     restricted to [olo:ohi] along the other axis."""
     p = spec.pad
-    dom = spec.m if axis == 0 else spec.n
-    d0, d1 = p, p + dom
+    d0 = spec.origin[axis]
+    d1 = d0 + (spec.m, spec.n)[axis]
     if boundary in (Boundary.ZERO, Boundary.NAN):
         fill = 0.0 if boundary is Boundary.ZERO else jnp.nan
-        frame = _axset(frame, axis, 0, p, olo, ohi, fill)
+        frame = _axset(frame, axis, d0 - p, d0, olo, ohi, fill)
         return _axset(frame, axis, d1, d1 + p, olo, ohi, fill)
     if boundary is Boundary.REFLECT:
+        # ghost d0-e mirrors domain d0+e (no edge repeat), as jnp.pad
         lo = jnp.flip(_axslice(frame, axis, d0 + 1, d0 + 1 + p, olo, ohi),
                       axis=axis)
-        frame = _axset(frame, axis, 0, p, olo, ohi, lo)
+        frame = _axset(frame, axis, d0 - p, d0, olo, ohi, lo)
         hi = jnp.flip(_axslice(frame, axis, d1 - 1 - p, d1 - 1, olo, ohi),
                       axis=axis)
         return _axset(frame, axis, d1, d1 + p, olo, ohi, hi)
     if boundary is Boundary.WRAP:
-        frame = _axset(frame, axis, 0, p, olo, ohi,
+        frame = _axset(frame, axis, d0 - p, d0, olo, ohi,
                        _axslice(frame, axis, d1 - p, d1, olo, ohi))
         return _axset(frame, axis, d1, d1 + p, olo, ohi,
                       _axslice(frame, axis, d0, d0 + p, olo, ohi))
@@ -471,8 +467,8 @@ def _refresh_axis_sharded(frame, sspec: ShardedFrameSpec, axis: int,
     name = sspec.axis_names[axis]
     nsh = sspec.sizes[axis]
     p = spec.pad
-    dom = spec.m if axis == 0 else spec.n
-    d0, d1 = p, p + dom
+    d0 = spec.origin[axis]
+    d1 = d0 + (spec.m, spec.n)[axis]
 
     fwd = [(i, i + 1) for i in range(nsh - 1)]
     bwd = [(i + 1, i) for i in range(nsh - 1)]
@@ -504,7 +500,7 @@ def _refresh_axis_sharded(frame, sspec: ShardedFrameSpec, axis: int,
         from_prev = jnp.where(me == 0, lo_fill, from_prev)
         from_next = jnp.where(me == nsh - 1, hi_fill, from_next)
 
-    frame = _axset(frame, axis, 0, p, olo, ohi, from_prev)
+    frame = _axset(frame, axis, d0 - p, d0, olo, ohi, from_prev)
     return _axset(frame, axis, d1, d1 + p, olo, ohi, from_next)
 
 
@@ -513,16 +509,15 @@ def refresh_frame_sharded(frame: jnp.ndarray, sspec: ShardedFrameSpec,
     """Re-assert a sharded frame's ghost ring — the loop-body exchange.
 
     Axis 0 strips span the domain's column extent; axis 1 strips then run
-    the full frame height, so corner ghosts pick up the diagonal
+    the ring's full height, so corner ghosts pick up the diagonal
     neighbour through the standard two-pass trick (and the local fills
     compose like ``jnp.pad``'s axis-sequential modes).  Decomposed axes
     exchange via ppermute; the rest fill locally.
     """
     boundary = Boundary(boundary)
     spec = sspec.local
-    p, ln = spec.pad, spec.n
-    H = spec.shape[0]
-    extents = ((p, p + ln), (0, H))     # pass 1 restricted, pass 2 full
+    (r0, c0), p = spec.origin, spec.pad
+    extents = ((c0, c0 + spec.n), (r0 - p, r0 + spec.m + p))
     for axis in (0, 1):
         olo, ohi = extents[axis]
         if sspec.axis_names[axis] is None:
@@ -542,8 +537,7 @@ def make_frame_sharded(a_local: jnp.ndarray, sspec: ShardedFrameSpec,
     """
     spec = sspec.local
     frame = jnp.zeros(spec.shape, a_local.dtype)
-    frame = jax.lax.dynamic_update_slice(frame, a_local,
-                                         (spec.pad, spec.pad))
+    frame = jax.lax.dynamic_update_slice(frame, a_local, spec.origin)
     return refresh_frame_sharded(frame, sspec, boundary)
 
 
@@ -565,8 +559,7 @@ def frame_env_sharded(e_local: jnp.ndarray, sspec: ShardedFrameSpec,
         return jnp.pad(e_local, ((0, mi - spec.m), (0, ni - spec.n)))
     b = Boundary(boundary)
     frame = jnp.zeros(spec.shape, e_local.dtype)
-    frame = jax.lax.dynamic_update_slice(frame, e_local,
-                                         (spec.pad, spec.pad))
+    frame = jax.lax.dynamic_update_slice(frame, e_local, spec.origin)
     return refresh_frame_sharded(
         frame, sspec, b if b is Boundary.WRAP else Boundary.ZERO)
 
@@ -578,9 +571,8 @@ def refill_lane_frames_sharded(frames: jnp.ndarray, interiors: jnp.ndarray,
     LOCAL interior is written in place and the ghost rings re-assert via
     the lane-batched ppermute exchange — the sharded twin of
     :func:`refill_lane_frames`."""
-    p = sspec.local.pad
     frames = jax.lax.dynamic_update_slice(
-        frames, interiors.astype(frames.dtype), (0, p, p))
+        frames, interiors.astype(frames.dtype), (0, *sspec.local.origin))
     return jax.vmap(
         lambda f: refresh_frame_sharded(f, sspec, boundary))(frames)
 
@@ -595,9 +587,8 @@ def refill_lane_env_sharded(env_frames: jnp.ndarray, e: jnp.ndarray,
             env_frames, e.astype(env_frames.dtype), (0, 0, 0))
     b = Boundary(boundary)
     ghost = b if b is Boundary.WRAP else Boundary.ZERO
-    p = sspec.local.pad
     env_frames = jax.lax.dynamic_update_slice(
-        env_frames, e.astype(env_frames.dtype), (0, p, p))
+        env_frames, e.astype(env_frames.dtype), (0, *sspec.local.origin))
     return jax.vmap(
         lambda f: refresh_frame_sharded(f, sspec, ghost))(env_frames)
 
@@ -620,10 +611,10 @@ def refill_slot_frame_sharded(frames: jnp.ndarray, interior: jnp.ndarray,
     No pad, no full-frame copy, one compilation per stream.
     """
     spec = sspec.local
-    p = spec.pad
-    cur = jax.lax.dynamic_slice(frames, (li, p, p), (1, spec.m, spec.n))
+    at = (li, *spec.origin)
+    cur = jax.lax.dynamic_slice(frames, at, (1, spec.m, spec.n))
     new = jnp.where(owns, interior[None].astype(frames.dtype), cur)
-    frames = jax.lax.dynamic_update_slice(frames, new, (li, p, p))
+    frames = jax.lax.dynamic_update_slice(frames, new, at)
     return jax.vmap(
         lambda f: refresh_frame_sharded(f, sspec, boundary))(frames)
 
@@ -645,11 +636,10 @@ def refill_slot_env_sharded(env_frames: jnp.ndarray, e: jnp.ndarray,
         return jax.lax.dynamic_update_slice(env_frames, new, (li, 0, 0))
     b = Boundary(boundary)
     ghost = b if b is Boundary.WRAP else Boundary.ZERO
-    p = spec.pad
-    cur = jax.lax.dynamic_slice(env_frames, (li, p, p),
-                                (1, spec.m, spec.n))
+    at = (li, *spec.origin)
+    cur = jax.lax.dynamic_slice(env_frames, at, (1, spec.m, spec.n))
     new = jnp.where(owns, e[None].astype(env_frames.dtype), cur)
-    env_frames = jax.lax.dynamic_update_slice(env_frames, new, (li, p, p))
+    env_frames = jax.lax.dynamic_update_slice(env_frames, new, at)
     return jax.vmap(
         lambda f: refresh_frame_sharded(f, sspec, ghost))(env_frames)
 
@@ -666,17 +656,16 @@ def shard_domain_bounds(sspec: ShardedFrameSpec) -> jnp.ndarray:
     """
     spec = sspec.local
     big = jnp.int32(2 ** 30)
-    p = spec.pad
     vals = []
-    for ax, dom in enumerate((spec.m, spec.n)):
+    for ax, (d0, dom) in enumerate(zip(spec.origin, (spec.m, spec.n))):
         name = sspec.axis_names[ax]
         if name is None:
-            lo = jnp.int32(p)
-            hi = jnp.int32(p + dom)
+            lo = jnp.int32(d0)
+            hi = jnp.int32(d0 + dom)
         else:
             me = jax.lax.axis_index(name)
             nsh = sspec.sizes[ax]
-            lo = jnp.where(me == 0, jnp.int32(p), -big)
-            hi = jnp.where(me == nsh - 1, jnp.int32(p + dom), big)
+            lo = jnp.where(me == 0, jnp.int32(d0), -big)
+            hi = jnp.where(me == nsh - 1, jnp.int32(d0 + dom), big)
         vals += [lo, hi]
     return jnp.stack(vals).astype(jnp.int32).reshape(1, 4)

@@ -371,6 +371,9 @@ class FarmEngine:
             raise ValueError("FarmEngine needs mode='taps' on the pallas "
                              f"backends; got mode={loop.mode!r}")
         if self.mesh is not None:
+            if isinstance(self.mesh, Mesh):
+                from repro.sharding.specs import auto_mesh
+                self.mesh = auto_mesh(self.mesh)
             if self.lane_axis not in self.mesh.axis_names:
                 raise ValueError(
                     f"lane_axis {self.lane_axis!r} not in mesh axes "
@@ -951,12 +954,11 @@ class FarmEngine:
             from repro.sharding.specs import local_slot, shard_map
 
             spec = self._lspec.local
-            p = spec.pad
             local_L = self.lanes // self._nshards
 
             def local_extract(fr, idx):
                 _, li = local_slot(idx, local_L, self.lane_axis)
-                return jax.lax.dynamic_slice(fr, (li, p, p),
+                return jax.lax.dynamic_slice(fr, (li, *spec.origin),
                                              (1, spec.m, spec.n))
 
             fn = shard_map(local_extract, mesh=self.mesh,
@@ -969,9 +971,8 @@ class FarmEngine:
             return jax.lax.dynamic_index_in_dim(planes, owner, axis=0,
                                                 keepdims=False)
         spec = self._lspec.frame
-        p = spec.pad
         return jax.lax.dynamic_slice(
-            frames, (idx, p, p), (1, spec.m, spec.n))[0]
+            frames, (idx, *spec.origin), (1, spec.m, spec.n))[0]
 
     # -- chained dispatch: fused segment + ring refill + capture ---------
     def _unframe_all(self, frames):
@@ -1587,7 +1588,10 @@ class FarmEngine:
             K = self._ring_depth
             local_L = L // self._nshards
             rd = jnp.asarray(0, jnp.int32)   # device-side read cursor
-            wr_host = 0                      # staged-count watermark
+            if self.mesh is not None:        # as the entry returns it:
+                rd = jax.device_put(         # one trace per stream
+                    rd, NamedSharding(self.mesh, P()))
+            wr_host = 0                     # staged-count watermark
             rd_host = 0                      # host mirror of rd (lags
                                              # by the in-flight takes)
             inflight: deque = deque()        # dispatched, undrained

@@ -4,8 +4,10 @@ Beyond-paper kernel optimisation for the memory-bound iterative stencil:
 the single-step kernel moves the whole grid HBM↔VMEM once per iteration
 (arithmetic intensity of a 5-point f32 Jacobi ≈ 4 FLOPs / 8 bytes → far
 below the v5e ridge point of ~240 FLOPs/byte).  Temporal blocking loads
-a (bm + 2kT, bn + 2kT) halo window once and applies T sweeps in VMEM,
-shrinking the valid region by k per side per sweep:
+a tile-aligned (bm + 2·r0, bn + 2·c0) window once (the frame margin,
+:mod:`repro.core.frames`), cuts its (bm + 2kT, bn + 2kT) halo region out
+in VMEM and applies T sweeps there, shrinking the valid region by k per
+side per sweep:
 
     HBM traffic/iter ≈ ((bm+2kT)(bn+2kT)/T + bm·bn/T) · bytes   (≈ ÷T)
     redundant compute ≈ ((bm+2kT)(bn+2kT)/(bm·bn) − 1)          (~13%
@@ -20,8 +22,9 @@ Per model:
   (cheap ``where`` over the shrinking window);
 * ``reflect`` — mirror the just-computed interior back onto the ghost
   cells.  The mirror source always lies inside the current window (depth-d
-  ghost mirrors depth-d interior), realised as flip+roll with a
-  program-id-dependent shift — no gather needed;
+  ghost mirrors depth-d interior), realised as a static 2d-cell roll per
+  depth d — only the k·(sweeps left) ghost cells a domain cell can still
+  reach — with no flip or gather (neither lowers on the TPU);
 * ``wrap`` — nothing per-sweep: a wrapped ghost ring is a patch of the
   torus, so ghost cells evolve *exactly* like their pre-images and the
   shrinking-window containment argument applies unchanged.  (Requires the
@@ -51,10 +54,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.frames import frame_spec, make_frame, frame_env, unframe
 from repro.core.reduce import resolve_monoid
-from .stencil2d import decode_acc, reduce_epilogue, revolving_fetch
+from .stencil2d import (ACC_TILE, decode_acc, grid_step, hbm_at,
+                        lane_batched, reduce_epilogue, revolving_fetch,
+                        tile_coords, tile_spec)
 
 
-def _fix_boundary(cur, row_base, col_base, *, bounds, boundary):
+def _fix_boundary(cur, row_base, col_base, *, bounds, boundary, depth):
     """Re-assert ⊥ on out-of-domain cells of an internal sweep output.
 
     ``cur`` holds the sweep output whose [0, 0] cell sits at frame
@@ -64,7 +69,9 @@ def _fix_boundary(cur, row_base, col_base, *, bounds, boundary):
     single-device path, traced scalars (read from SMEM) on the sharded
     path, where interior shards carry ±2^30 sentinels so no cell is ever
     "outside" (their ghost cells are real neighbour cells and must evolve
-    freely).
+    freely).  ``depth`` is how far outside the domain a ghost can still
+    reach a domain cell in the sweeps left (k per sweep); deeper cells
+    are inert.
     """
     if boundary == "wrap":
         return cur                      # torus continuation is exact
@@ -79,28 +86,24 @@ def _fix_boundary(cur, row_base, col_base, *, bounds, boundary):
         return jnp.where(inside, cur, fill)
     if boundary != "reflect":
         raise ValueError(boundary)
-    # reflect: ghost row g < row_lo mirrors row 2·row_lo - g; g >= row_hi
-    # mirrors 2(row_hi-1) - g (jnp.pad 'reflect', no edge repeat).
-    # flip+roll turns the traced mirror map into a cyclic shift:
-    # flip(cur)[l'] = cur[L-1-l'], so roll(flip(cur), s)[l] = cur[L-1+s-l]
-    # — choosing s makes L-1+s-l the mirror image of row_base+l.
-    # Out-of-range (or sentinel-bound) rolls only land on rows the masks
-    # below never select.
-    fr = jnp.flip(cur, axis=0)
-    top = jnp.roll(fr, 2 * (row_lo - row_base) - L + 1, axis=0)
-    bot = jnp.roll(fr, 2 * (row_hi - 1 - row_base) - L + 1, axis=0)
-    cur = jnp.where(rows < row_lo, top,
-                    jnp.where(rows >= row_hi, bot, cur))
-    fc = jnp.flip(cur, axis=1)
-    left = jnp.roll(fc, 2 * (col_lo - col_base) - W + 1, axis=1)
-    right = jnp.roll(fc, 2 * (col_hi - 1 - col_base) - W + 1, axis=1)
-    return jnp.where(cols < col_lo, left,
-                     jnp.where(cols >= col_hi, right, cur))
+    # reflect: a ghost e cells outside an edge mirrors the domain cell e
+    # inside it (jnp.pad 'reflect', no edge repeat) — a static shift by
+    # 2e along the axis, selected where the ghost sits.  Rows first, then
+    # columns over the row-fixed values, so corners compose like jnp.pad.
+    for e in range(1, depth + 1):
+        cur = jnp.where(rows == row_lo - e, jnp.roll(cur, -2 * e, 0), cur)
+        cur = jnp.where(rows == row_hi - 1 + e, jnp.roll(cur, 2 * e, 0),
+                        cur)
+    for e in range(1, depth + 1):
+        cur = jnp.where(cols == col_lo - e, jnp.roll(cur, -2 * e, 1), cur)
+        cur = jnp.where(cols == col_hi - 1 + e, jnp.roll(cur, 2 * e, 1),
+                        cur)
+    return cur
 
 
-def _ms_kernel(x_hbm, *rest, f, measure, op, identity, k, T, bm, bn,
-               gm, gn, m, n, acc_dtype, boundary, n_env, double_buffer,
-               has_bounds):
+def _ms_kernel(x_hbm, *rest, f, measure, op, identity, k, T, origin, bm,
+               bn, gm, gn, lanes, m, n, acc_dtype, boundary, n_env,
+               double_buffer, has_bounds):
     env_hbm = rest[:n_env]
     pos = n_env
     if has_bounds:
@@ -111,53 +114,58 @@ def _ms_kernel(x_hbm, *rest, f, measure, op, identity, k, T, bm, bn,
     ewins = tail[:n_env]
     esem = tail[n_env] if n_env else None
     ostage, osem = tail[-2:]
-    pad_static = k * T
+    r0, c0 = origin
     if has_bounds:
         bounds = (bounds_ref[0, 0], bounds_ref[0, 1],
                   bounds_ref[0, 2], bounds_ref[0, 3])
     else:
-        bounds = (pad_static, pad_static + m, pad_static, pad_static + n)
+        bounds = (r0, r0 + m, c0, c0 + n)
 
-    i, j = pl.program_id(0), pl.program_id(1)
-    t = i * gn + j
+    l, i, j, t = grid_step(lanes, gm, gn)
     pad = k * T
-    wm, wn = bm + 2 * pad, bn + 2 * pad
+    wm, wn = bm + 2 * r0, bn + 2 * c0          # the aligned DMA window
+    hm, hn = bm + 2 * pad, bn + 2 * pad        # its halo region
+    halo = (slice(r0 - pad, r0 - pad + hm), slice(c0 - pad, c0 - pad + hn))
 
-    def window_copies(ti, tj, slot):
-        cps = [pltpu.make_async_copy(
-            x_hbm.at[pl.ds(ti * bm, wm), pl.ds(tj * bn, wn)],
-            win.at[slot], wsem.at[slot])]
+    def window_copies(s, slot):
+        sl, si, sj = tile_coords(s, lanes, gm, gn)
+        rows, cols = pl.ds(si * bm, wm), pl.ds(sj * bn, wn)
+        cps = [pltpu.make_async_copy(hbm_at(x_hbm, sl, rows, cols),
+                                     win.at[slot], wsem.at[slot])]
         for e in range(n_env):
             cps.append(pltpu.make_async_copy(
-                env_hbm[e].at[pl.ds(ti * bm, wm), pl.ds(tj * bn, wn)],
+                hbm_at(env_hbm[e], sl, rows, cols),
                 ewins[e].at[slot], esem.at[slot, e]))
         return cps
 
-    slot = revolving_fetch(t, i, j, gm, gn, window_copies, double_buffer)
-    cur = win[slot]
+    slot = revolving_fetch(t, (lanes or 1) * gm * gn, window_copies,
+                           double_buffer)
+    cur = win[slot][halo]
+    env_halos = [ewins[e][slot][halo] for e in range(n_env)]
+    # frame coordinates of the halo region's cell (0, 0)
+    row0, col0 = i * bm + r0 - pad, j * bn + c0 - pad
     prev_center = None
     for step in range(T):
-        size_m = wm - 2 * k * (step + 1)
-        size_n = wn - 2 * k * (step + 1)
+        size_m = hm - 2 * k * (step + 1)
+        size_n = hn - 2 * k * (step + 1)
         if step == T - 1:
             prev_center = cur[k:k + size_m, k:k + size_n]
         taps = _ShrinkTaps(cur, k, size_m, size_n)
-        off = k * (step + 1)            # window-local origin of this sweep
-        envs = [ewins[e][slot][off:off + size_m, off:off + size_n]
-                for e in range(n_env)]
+        off = k * (step + 1)            # halo-local origin of this sweep
+        envs = [e[off:off + size_m, off:off + size_n] for e in env_halos]
         new = f(taps, *envs)
         cur = _fix_boundary(
-            new, i * bm + off, j * bn + off, bounds=bounds,
-            boundary=boundary).astype(cur.dtype)
+            new, row0 + off, col0 + off, bounds=bounds, boundary=boundary,
+            depth=k * (T - 1 - step)).astype(cur.dtype)
 
     ostage[...] = cur.astype(ostage.dtype)    # (bm, bn) after T shrinks
     wr = pltpu.make_async_copy(
-        ostage, o_hbm.at[pl.ds(pad + i * bm, bm), pl.ds(pad + j * bn, bn)],
-        osem)
+        ostage, hbm_at(o_hbm, l, pl.ds(r0 + i * bm, bm),
+                       pl.ds(c0 + j * bn, bn)), osem)
     wr.start()
     wr.wait()
 
-    reduce_epilogue(acc_ref, t, cur, prev_center, measure=measure, op=op,
+    reduce_epilogue(acc_ref, cur, prev_center, measure=measure, op=op,
                     identity=identity, i=i, j=j, bm=bm, bn=bn, m=m, n=n,
                     acc_dtype=acc_dtype)
 
@@ -204,15 +212,10 @@ def stencil2d_multistep_framed(frame: jnp.ndarray, f: Callable, spec, *,
     k, bm, bn, gm, gn = spec.k, spec.bm, spec.bn, spec.gm, spec.gn
     assert spec.pad == k * T, (spec.pad, k, T)
     nbuf = 2 if double_buffer else 1
-    wm, wn = bm + 2 * spec.pad, bn + 2 * spec.pad
+    r0, c0 = spec.origin
+    wm, wn = bm + 2 * r0, bn + 2 * c0
     n_env = len(env_framed)
     has_bounds = domain_bounds is not None
-
-    kernel = functools.partial(
-        _ms_kernel, f=f, measure=measure, op=op, identity=ident, k=k,
-        T=T, bm=bm, bn=bn, gm=gm, gn=gn, m=spec.m, n=spec.n,
-        acc_dtype=acc_dtype, boundary=boundary, n_env=n_env,
-        double_buffer=double_buffer, has_bounds=has_bounds)
 
     scratch = [pltpu.VMEM((nbuf, wm, wn), frame.dtype),
                pltpu.SemaphoreType.DMA((nbuf,))]
@@ -220,26 +223,37 @@ def stencil2d_multistep_framed(frame: jnp.ndarray, f: Callable, spec, *,
     if n_env:
         scratch.append(pltpu.SemaphoreType.DMA((nbuf, n_env)))
     scratch += [pltpu.VMEM((bm, bn), frame.dtype), pltpu.SemaphoreType.DMA]
-
-    in_specs = ([pl.BlockSpec(memory_space=pl.ANY)]
-                + [pl.BlockSpec(memory_space=pl.ANY) for _ in env_framed])
-    operands = [frame, *env_framed]
+    shared = []                       # operands every lane reads
     if has_bounds:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        operands.append(jnp.asarray(domain_bounds, jnp.int32))
+        shared.append(jnp.asarray(domain_bounds, jnp.int32))
 
-    out, acc = pl.pallas_call(
-        kernel,
-        grid=(gm, gn),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                   pl.BlockSpec((1, 1), lambda i, j: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct(frame.shape, frame.dtype),
-                   jax.ShapeDtypeStruct((1, 1), acc_dtype)],
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*operands)
-    return out, decode_acc(op, acc[0, 0])
+    def call(lanes, frame, *rest):
+        kernel = functools.partial(
+            _ms_kernel, f=f, measure=measure, op=op, identity=ident, k=k,
+            T=T, origin=spec.origin, bm=bm, bn=bn, gm=gm, gn=gn,
+            lanes=lanes, m=spec.m, n=spec.n, acc_dtype=acc_dtype,
+            boundary=boundary, n_env=n_env, double_buffer=double_buffer,
+            has_bounds=has_bounds)
+        stack = () if lanes is None else (lanes,)
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (1 + n_env)
+        if has_bounds:
+            in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        return pl.pallas_call(
+            kernel,
+            grid=(*stack, gm, gn),
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                       tile_spec(lanes, ACC_TILE, lambda i, j: (0, 0))],
+            out_shape=[jax.ShapeDtypeStruct(frame.shape, frame.dtype),
+                       jax.ShapeDtypeStruct((*stack, *ACC_TILE),
+                                            acc_dtype)],
+            scratch_shapes=scratch,
+            interpret=interpret,
+            name="stencil2d_multistep_framed",
+        )(frame, *rest)
+
+    out, acc = lane_batched(call, 1 + n_env)(frame, *env_framed, *shared)
+    return out, decode_acc(op, acc)
 
 
 def stencil2d_multistep(a, f, *, env=(), k: int = 1, T: int = 4,

@@ -6,9 +6,10 @@ so the convergence measure costs no extra memory pass.  TPU-native
 re-thinking of that design:
 
 * the global grid lives in HBM as a *persistent halo frame*
-  (:mod:`repro.core.frames`): a (gm·bm + 2k, gn·bn + 2k) array whose ghost
-  ring realises ⊥.  Each grid step DMAs its halo-extended (bm+2k, bn+2k)
-  window into VMEM with an explicit async copy (``pltpu.make_async_copy``)
+  (:mod:`repro.core.frames`): a (gm·bm + 2·r0, gn·bn + 2·c0) array whose
+  k-deep ghost ring, inside an (8, 128)-aligned margin, realises ⊥.  Each
+  grid step DMAs its margin-extended (bm + 2·r0, bn + 2·c0) window into
+  VMEM with an explicit async copy (``pltpu.make_async_copy``)
   — the HBM→VMEM tier replaces the paper's global→local OpenCL memory
   staging, and the halo comes from the frame rather than inter-work-group
   synchronisation;
@@ -19,10 +20,11 @@ re-thinking of that design:
   no per-iteration ``jnp.pad``/slice (two full-grid HBM passes saved on an
   already memory-bound kernel) — only the O(m+n) ghost refresh between
   sweeps (:func:`repro.core.frames.refresh_frame`);
-* the per-tile partial reduce accumulates in a VMEM scratch carried across
-  the **sequential TPU grid** (acc BlockSpec pinned to (0,0)) — phase one of
-  the paper's two-phase reduce.  The tiny final combine happens in the jnp
-  wrapper and stays on device;
+* the per-tile partial reduce accumulates in an (8, 128) VMEM output block
+  carried across the **sequential TPU grid** (BlockSpec pinned to (0,0);
+  every cell holds the running value, since Mosaic stores no scalars to
+  VMEM) — phase one of the paper's two-phase reduce.  The wrapper reads
+  one cell back and stays on device;
 * optional **double-buffered DMA** (revolving windows) overlaps the next
   tile's copy with the current tile's compute — the TPU analogue of the
   paper's asynchronous H2D/D2H overlap via OpenCL events.
@@ -50,47 +52,82 @@ from repro.core.frames import frame_spec, make_frame, frame_env, unframe
 from repro.core.reduce import resolve_monoid
 
 
-class KernelTaps:
-    """Tap accessor over the halo-extended VMEM window (kernel-side twin of
-    :class:`repro.core.stencil.TapAccessor`)."""
+ACC_TILE = (8, 128)   # one vreg: the partial-reduce accumulator block
 
-    def __init__(self, win, k: int, bm: int, bn: int):
-        self._w, self._k, self._bm, self._bn = win, k, bm, bn
+
+class KernelTaps:
+    """Tap accessor over the margin-extended VMEM window (kernel-side twin
+    of :class:`repro.core.stencil.TapAccessor`); the (bm, bn) output tile
+    sits at window offset ``origin``."""
+
+    def __init__(self, win, origin: tuple[int, int], bm: int, bn: int):
+        self._w, self._o, self._bm, self._bn = win, origin, bm, bn
 
     def __call__(self, di: int, dj: int):
-        k, bm, bn = self._k, self._bm, self._bn
-        return self._w[k + di:k + di + bm, k + dj:k + dj + bn]
+        (r0, c0), bm, bn = self._o, self._bm, self._bn
+        return self._w[r0 + di:r0 + di + bm, c0 + dj:c0 + dj + bn]
 
     @property
     def center(self):
         return self(0, 0)
 
 
-def revolving_fetch(t, i, j, gm, gn, make_copies, double_buffer):
-    """Bring tile (i, j)'s windows into VMEM; return the slot they landed
-    in.  ``make_copies(ti, tj, slot)`` builds the async-copy list for one
-    tile.  With double buffering the next tile's copies are kicked off
-    into the other slot before waiting on the current one (revolving
-    windows over the sequential TPU grid).  Shared by the single-step and
-    temporal-blocking kernels."""
+def tile_coords(s, lanes, gm, gn):
+    """Decode a linear (lane-major) tile index into (lane, i, j); lane is
+    None for an unbatched (``lanes=None``) kernel."""
+    lane = None if lanes is None else s // (gm * gn)
+    return lane, (s // gn) % gm, s % gn
+
+
+def grid_step(lanes, gm, gn):
+    """``(lane, i, j, t)`` of the current grid step, ``t`` linear.  The
+    lane-batched kernels run a (lanes, gm, gn) grid; unbatched ones a
+    (gm, gn) grid, with ``lane`` None."""
+    if lanes is None:
+        i, j = pl.program_id(0), pl.program_id(1)
+        return None, i, j, i * gn + j
+    l, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    return l, i, j, (l * gm + i) * gn + j
+
+
+def hbm_at(ref, lane, rows, cols):
+    """Window of an HBM frame (lane-stacked when ``lane`` is not None)."""
+    return ref.at[rows, cols] if lane is None else ref.at[lane, rows, cols]
+
+
+def tile_spec(lanes, block, index):
+    """VMEM BlockSpec for one (lane, i, j) grid step; ``index(i, j)``
+    names the block within one lane."""
+    if lanes is None:
+        return pl.BlockSpec(block, lambda i, j: index(i, j))
+    return pl.BlockSpec((None, *block), lambda l, i, j: (l, *index(i, j)))
+
+
+def revolving_fetch(t, tiles, make_copies, double_buffer):
+    """Bring linear tile ``t``'s windows into VMEM; return the slot they
+    landed in.  ``make_copies(s, slot)`` builds the async-copy list for
+    linear tile ``s``.  With double buffering the next tile's copies —
+    across lane boundaries too — are kicked off into the other slot
+    before waiting on the current one (revolving windows over the
+    sequential TPU grid).  Shared by the single-step and temporal-blocking
+    kernels."""
     if double_buffer:
         # first tile of the whole grid: kick off slot 0
         @pl.when(t == 0)
         def _():
-            for cp in make_copies(i, j, 0):
+            for cp in make_copies(t, 0):
                 cp.start()
         # prefetch the next tile into the other slot
         nt = t + 1
-        ni, nj = nt // gn, nt % gn
 
-        @pl.when(nt < gm * gn)
+        @pl.when(nt < tiles)
         def _():
-            for cp in make_copies(ni, nj, nt % 2):
+            for cp in make_copies(nt, nt % 2):
                 cp.start()
-        for cp in make_copies(i, j, t % 2):
+        for cp in make_copies(t, t % 2):
             cp.wait()
         return t % 2
-    cps = make_copies(i, j, 0)
+    cps = make_copies(t, 0)
     for cp in cps:
         cp.start()
     for cp in cps:
@@ -98,69 +135,103 @@ def revolving_fetch(t, i, j, gm, gn, make_copies, double_buffer):
     return 0
 
 
-def reduce_epilogue(acc_ref, t, new, prev_center, *, measure, op, identity,
+def lane_batched(call, n_lane_args: int):
+    """Make a Pallas kernel wrapper vmappable on the TPU.
+
+    ``call(lanes, *args)`` runs the kernel on unbatched operands
+    (``lanes=None``) or on lane-stacked ones (``lanes=L``, a leading axis
+    of size L on the first ``n_lane_args`` operands; the rest are shared
+    by every lane).  Mosaic cannot lower the generic Pallas batching rule
+    for HBM (``pl.ANY``) operands, so ``vmap`` is routed to the kernel's
+    own lane grid instead.
+    """
+    @jax.custom_batching.custom_vmap
+    def kernel(*args):
+        return call(None, *args)
+
+    @kernel.def_vmap
+    def _(axis_size, in_batched, *args):
+        if any(in_batched[n_lane_args:]):
+            raise NotImplementedError(
+                "kernel operands shared by every lane cannot be batched")
+        lane = [a if b else jnp.broadcast_to(a, (axis_size, *a.shape))
+                for a, b in zip(args[:n_lane_args], in_batched)]
+        return call(axis_size, *lane, *args[n_lane_args:]), (True, True)
+
+    return kernel
+
+
+def reduce_epilogue(acc_ref, new, prev_center, *, measure, op, identity,
                     i, j, bm, bn, m, n, acc_dtype, do_reduce=True):
     """Fused per-tile partial reduce (phase 1 of the paper's two-phase
-    reduce), accumulated across the sequential grid into ``acc_ref``.
-    Cells beyond the (m, n) domain (block round-up) fold as ⊕'s identity.
-    ``do_reduce=False`` only initialises the accumulator — used on
-    intermediate unrolled sweeps, where the condition is not checked."""
-    @pl.when(t == 0)
+    reduce), accumulated across the sequential grid into the
+    :data:`ACC_TILE` block ``acc_ref`` (the tile's scalar is broadcast,
+    so every cell carries the running value).  Cells beyond the (m, n)
+    domain (block round-up) fold as ⊕'s identity.  ``do_reduce=False``
+    only initialises the accumulator — used on intermediate unrolled
+    sweeps, where the condition is not checked.  The accumulator starts
+    at each lane's first tile."""
+    # bool monoids accumulate as {0,1} indicators in acc_dtype (or ≡ max,
+    # and ≡ min on {0,1}); decode_acc turns the result back into a bool
+    if op is jnp.logical_or:
+        op = jnp.maximum
+    elif op is jnp.logical_and:
+        op = jnp.minimum
+    ident = jnp.asarray(identity, acc_dtype)
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
     def _():
-        acc_ref[0, 0] = jnp.asarray(identity, acc_dtype)
+        acc_ref[...] = jnp.full(acc_ref.shape, ident, acc_dtype)
     if not do_reduce:
         return
     meas = measure(new, prev_center) if measure is not None else new
     rows = i * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 0)
     cols = j * bn + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
     valid = (rows < m) & (cols < n)
-    meas = jnp.where(valid, meas.astype(acc_dtype),
-                     jnp.asarray(identity, acc_dtype))
-    part = _tile_fold(op, meas, identity, acc_dtype)
-    if op in (jnp.logical_or, jnp.logical_and):
-        # bool monoids accumulate as {0,1} indicators in the acc_dtype
-        # scratch (or ≡ max, and ≡ min on {0,1}); decode_acc in the jnp
-        # wrapper turns the scalar back into a bool.
-        acc_op = jnp.maximum if op is jnp.logical_or else jnp.minimum
-        acc_ref[0, 0] = acc_op(acc_ref[0, 0], part.astype(acc_dtype))
-    else:
-        acc_ref[0, 0] = op(acc_ref[0, 0], part)
+    meas = jnp.where(valid, meas.astype(acc_dtype), ident)
+    acc_ref[...] = op(acc_ref[...], _tile_fold(op, meas, identity, acc_dtype))
 
 
-def decode_acc(op, red):
-    """Map the kernel's scalar accumulator back to the monoid's carrier
-    (bool monoids ride through VMEM as {0,1} indicators)."""
+def decode_acc(op, acc):
+    """Read the reduced scalar out of the kernel's accumulator block and
+    map it back to the monoid's carrier (bool monoids ride through VMEM
+    as {0,1} indicators)."""
+    red = acc[0, 0]
     if op in (jnp.logical_or, jnp.logical_and):
         return red >= 0.5
     return red
 
 
-def _stencil_kernel(x_hbm, *rest, f, measure, op,
-                    identity, k, bm, bn, gm, gn, m, n, acc_dtype,
-                    double_buffer, n_env, do_reduce):
+def _stencil_kernel(x_hbm, *rest, f, measure, op, identity, origin, bm,
+                    bn, gm, gn, lanes, m, n, acc_dtype, double_buffer, n_env,
+                    do_reduce):
     env = rest[:n_env]            # per-cell read-only fields (paper's `env`)
     o_hbm, acc_ref, win, wsem, ostage, osem = rest[n_env:]
-    i, j = pl.program_id(0), pl.program_id(1)
-    t = i * gn + j
+    l, i, j, t = grid_step(lanes, gm, gn)
+    r0, c0 = origin
+    wm, wn = bm + 2 * r0, bn + 2 * c0
 
-    def window_copies(ti, tj, slot):
+    def window_copies(s, slot):
+        sl, si, sj = tile_coords(s, lanes, gm, gn)
         return [pltpu.make_async_copy(
-            x_hbm.at[pl.ds(ti * bm, bm + 2 * k), pl.ds(tj * bn, bn + 2 * k)],
+            hbm_at(x_hbm, sl, pl.ds(si * bm, wm), pl.ds(sj * bn, wn)),
             win.at[slot], wsem.at[slot])]
 
-    slot = revolving_fetch(t, i, j, gm, gn, window_copies, double_buffer)
-    taps = KernelTaps(win[slot], k, bm, bn)
+    slot = revolving_fetch(t, (lanes or 1) * gm * gn, window_copies,
+                           double_buffer)
+    taps = KernelTaps(win[slot], origin, bm, bn)
     new = f(taps, *[e[...] for e in env])
 
     # write the tile back into the frame layout (ghost ring untouched —
     # the engine's O(m+n) refresh re-asserts it between sweeps)
     ostage[...] = new.astype(ostage.dtype)
     wr = pltpu.make_async_copy(
-        ostage, o_hbm.at[pl.ds(k + i * bm, bm), pl.ds(k + j * bn, bn)], osem)
+        ostage, hbm_at(o_hbm, l, pl.ds(r0 + i * bm, bm),
+                       pl.ds(c0 + j * bn, bn)), osem)
     wr.start()
     wr.wait()
 
-    reduce_epilogue(acc_ref, t, new, taps.center, measure=measure, op=op,
+    reduce_epilogue(acc_ref, new, taps.center, measure=measure, op=op,
                     identity=identity, i=i, j=j, bm=bm, bn=bn, m=m, n=n,
                     acc_dtype=acc_dtype, do_reduce=do_reduce)
 
@@ -171,10 +242,6 @@ def _tile_fold(op, x2d, identity, acc_dtype):
         return jnp.max(x2d)
     if op is jnp.minimum:
         return jnp.min(x2d)
-    if op is jnp.logical_or:
-        return jnp.any(x2d)
-    if op is jnp.logical_and:
-        return jnp.all(x2d)
     import operator
     if op is operator.add:
         return jnp.sum(x2d)
@@ -213,32 +280,40 @@ def stencil2d_fused_framed(frame: jnp.ndarray, f: Callable, spec, *,
     wasted work.
     """
     op, ident = resolve_monoid(combine, identity)
-    k, bm, bn, gm, gn = spec.k, spec.bm, spec.bn, spec.gm, spec.gn
-    nbuf = 2 if double_buffer else 1
+    bm, bn, gm, gn = spec.bm, spec.bn, spec.gm, spec.gn
+    (r0, c0), nbuf = spec.origin, 2 if double_buffer else 1
 
-    kernel = functools.partial(
-        _stencil_kernel, f=f, measure=measure, op=op, identity=ident,
-        k=k, bm=bm, bn=bn, gm=gm, gn=gn, m=spec.m, n=spec.n,
-        acc_dtype=acc_dtype, double_buffer=double_buffer,
-        n_env=len(env_framed), do_reduce=do_reduce)
+    def call(lanes, frame, *env_framed):
+        kernel = functools.partial(
+            _stencil_kernel, f=f, measure=measure, op=op, identity=ident,
+            origin=spec.origin, bm=bm, bn=bn, gm=gm, gn=gn, lanes=lanes,
+            m=spec.m, n=spec.n, acc_dtype=acc_dtype,
+            double_buffer=double_buffer, n_env=len(env_framed),
+            do_reduce=do_reduce)
+        stack = () if lanes is None else (lanes,)
+        return pl.pallas_call(
+            kernel,
+            grid=(*stack, gm, gn),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)]
+            + [tile_spec(lanes, (bm, bn), lambda i, j: (i, j))
+               for _ in env_framed],
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                       tile_spec(lanes, ACC_TILE, lambda i, j: (0, 0))],
+            out_shape=[jax.ShapeDtypeStruct(frame.shape, frame.dtype),
+                       jax.ShapeDtypeStruct((*stack, *ACC_TILE),
+                                            acc_dtype)],
+            scratch_shapes=[pltpu.VMEM((nbuf, bm + 2 * r0, bn + 2 * c0),
+                                       frame.dtype),
+                            pltpu.SemaphoreType.DMA((nbuf,)),
+                            pltpu.VMEM((bm, bn), frame.dtype),
+                            pltpu.SemaphoreType.DMA],
+            interpret=interpret,
+            name="stencil2d_fused_framed",
+        )(frame, *env_framed)
 
-    out, acc = pl.pallas_call(
-        kernel,
-        grid=(gm, gn),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)]
-        + [pl.BlockSpec((bm, bn), lambda i, j: (i, j)) for _ in env_framed],
-        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                   pl.BlockSpec((1, 1), lambda i, j: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct(frame.shape, frame.dtype),
-                   jax.ShapeDtypeStruct((1, 1), acc_dtype)],
-        scratch_shapes=[pltpu.VMEM((nbuf, bm + 2 * k, bn + 2 * k),
-                                   frame.dtype),
-                        pltpu.SemaphoreType.DMA((nbuf,)),
-                        pltpu.VMEM((bm, bn), frame.dtype),
-                        pltpu.SemaphoreType.DMA],
-        interpret=interpret,
-    )(frame, *env_framed)
-    return out, decode_acc(op, acc[0, 0])
+    n_lane = 1 + len(env_framed)
+    out, acc = lane_batched(call, n_lane)(frame, *env_framed)
+    return out, decode_acc(op, acc)
 
 
 def stencil2d_fused(a: jnp.ndarray, f: Callable, *, env=(), k: int = 1,

@@ -12,7 +12,7 @@ on the shortest ICI rings; the "pod" axis carries only the gradient
 all-reduce (data-parallel across pods, over the slow inter-pod links).
 
 Meshes are built through :func:`repro.sharding.specs.make_mesh`, the
-version-portable shim (jax 0.4.x has no ``axis_types=`` kwarg).
+repo's one mesh constructor (every axis ``Auto``).
 """
 from __future__ import annotations
 

@@ -21,57 +21,37 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 
 
-def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
-              *, explicit: bool = False) -> Mesh:
-    """Version-portable ``jax.make_mesh``.
-
-    Newer jax takes ``axis_types=(AxisType.Auto, ...)`` (and
-    ``AxisType.Explicit`` for sharding-in-types); 0.4.x has neither the
-    kwarg nor ``jax.sharding.AxisType``.  Auto is the 0.4.x behaviour, so
-    the kwarg is only forwarded where it exists — the launch layer and
-    the multi-device tests go through this shim (same contract as
-    :func:`shard_map` below).
-    """
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(axis_shapes, axis_names)
-    ty = AxisType.Explicit if explicit else AxisType.Auto
+def make_mesh(axis_shapes: Sequence[int],
+              axis_names: Sequence[str]) -> Mesh:
+    """The repo's one mesh constructor: ``jax.make_mesh`` with every axis
+    ``Auto`` (GSPMD-propagated shardings, which the engine's
+    ``shard_map`` programs are written for)."""
     return jax.make_mesh(axis_shapes, axis_names,
-                         axis_types=(ty,) * len(axis_names))
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
-def make_abstract_mesh(axis_shapes: Sequence[int],
-                       axis_names: Sequence[str]):
-    """Version-portable ``jax.sharding.AbstractMesh`` (newer jax:
-    ``AbstractMesh(sizes, names)``; 0.4.x: one tuple of (name, size)
-    pairs)."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(axis_shapes, axis_names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``.  ``jax.make_mesh`` defaults to
+    ``Explicit`` axes, under which host-side indexing of a lane-sharded
+    buffer is a sharding type error; the engines normalise any mesh they
+    are handed through here."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def shard_map(f: Callable, mesh: Mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` without replication checking.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=)``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``.  Every
-    SPMD entry point in the repo (halo exchange, sharded engine, MoE
-    dispatch) goes through this shim so the whole tree runs on both.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` without replication checking — every SPMD entry
+    point in the repo (halo exchange, sharded engine, MoE dispatch) goes
+    through here."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def local_slot(idx, lanes_local: int, axis: str):
@@ -105,6 +85,7 @@ class GridPartition:
     array_axes: tuple[int, ...]      # which array axes they split
 
     def __post_init__(self):
+        object.__setattr__(self, "mesh", auto_mesh(self.mesh))
         object.__setattr__(self, "axis_names", tuple(self.axis_names))
         object.__setattr__(self, "array_axes", tuple(self.array_axes))
 

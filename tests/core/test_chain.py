@@ -248,7 +248,7 @@ class TestFrameUnits:
     def test_refill_lanes_masked_matches_per_slot(self):
         from repro.core.frames import frame_spec
         spec = frame_spec(8, 128, k=1, block=(8, 128))
-        lanes, p = 3, spec.pad
+        lanes, (rs, cs) = 3, spec.domain
         rng = np.random.default_rng(1)
         frames = jnp.asarray(rng.normal(size=(lanes, *spec.shape)),
                              jnp.float32)
@@ -259,18 +259,16 @@ class TestFrameUnits:
         # reference: keep the untaken lane's interior, refresh ALL
         # ghosts (exactly what the classic per-slot refill's vmapped
         # refresh does to bystander lanes)
-        cur = frames[:, p:p + 8, p:p + 128]
+        cur = frames[:, rs, cs]
         ref_interiors = jnp.where(take[:, None, None], fresh, cur)
         ref = refill_lane_frames(frames, ref_interiors, spec, "zero")
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
         # the untaken lane's interior is bit-untouched
         np.testing.assert_array_equal(
-            np.asarray(got)[1, p:p + 8, p:p + 128],
-            np.asarray(frames)[1, p:p + 8, p:p + 128])
+            np.asarray(got)[1, rs, cs], np.asarray(frames)[1, rs, cs])
         # the taken lanes carry the fresh interiors
         np.testing.assert_array_equal(
-            np.asarray(got)[0, p:p + 8, p:p + 128],
-            np.asarray(fresh)[0])
+            np.asarray(got)[0, rs, cs], np.asarray(fresh)[0])
 
     def test_refill_lanes_env_masked_non_halo(self):
         from repro.core.frames import frame_spec

@@ -113,14 +113,18 @@ class TestFrames:
     @pytest.mark.parametrize("boundary", BOUNDARIES)
     @pytest.mark.parametrize("pad", [1, 3])
     def test_make_frame_matches_jnp_pad(self, boundary, pad, rng):
-        """On an exactly block-rounded domain the whole frame must equal
-        jnp.pad's realisation of ⊥ (corners included)."""
+        """On an exactly block-rounded domain the domain plus its
+        pad-deep ring must equal jnp.pad's realisation of ⊥ (corners
+        included), with the domain at a tile-aligned origin."""
         a = jnp.asarray(rng.normal(size=(16, 128)), jnp.float32)
         spec = frames.frame_spec(16, 128, k=1, block=(16, 128), sweeps=pad)
         assert spec.interior == (16, 128)
+        r0, c0 = spec.origin
+        assert r0 % 8 == 0 and c0 % 128 == 0 and min(r0, c0) >= pad
         got = frames.make_frame(a, spec, boundary)
+        ring = got[r0 - pad:r0 + 16 + pad, c0 - pad:c0 + 128 + pad]
         want = Boundary(boundary).pad(a, pad)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(ring), np.asarray(want))
 
     def test_refresh_is_edge_sized(self):
         """The refresh touches O(m+n) cells: its jaxpr must not contain

@@ -401,7 +401,8 @@ class TestContinuousFarm:
         the multi-device matrix in TestComposedContinuous; here the
         1×1-mesh degenerate case runs in process."""
         from repro.core import GridPartition
-        mesh = jax.make_mesh((1, 1), ("lanes", "model"))
+        from repro.sharding.specs import make_mesh
+        mesh = make_mesh((1, 1), ("lanes", "model"))
         part = GridPartition(mesh=mesh, axis_names=("model",),
                              array_axes=(0,))
         loop = LoopOfStencilReduce(
@@ -617,6 +618,7 @@ import os, sys
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import FarmEngine, GridPartition, LoopOfStencilReduce
 from repro.kernels import ref as R
+from repro.sharding.specs import make_mesh
 rng = np.random.default_rng(0)
 items = [np.asarray(rng.normal(size=(64, 64)), np.float32) * s
          for s in (1.0, 5.0, 0.1, 2.0, 3.0, 0.5, 4.0)]
@@ -650,7 +652,7 @@ class TestFarmEngineSharded:
 
     def test_lanes_over_data_axis(self):
         out = run_multidevice(SHARDED_PRELUDE + """
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_mesh((4,), ("data",))
 check(FarmEngine(mkloop("pallas"), lanes=4, mesh=mesh))
 check(FarmEngine(mkloop("jnp"), lanes=4, mesh=mesh))
 print("OKLANES")
@@ -662,7 +664,7 @@ print("OKLANES")
         shard runs its own segments (no collectives cross the lane
         axis); parity vs the solo runs, every item exactly once."""
         out = run_multidevice(SHARDED_PRELUDE + """
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_mesh((4,), ("data",))
 for backend in ("pallas", "jnp"):
     eng = FarmEngine(mkloop(backend), lanes=4, mesh=mesh, segment=6)
     outs = []
@@ -685,7 +687,7 @@ print("OKCONT")
         WHOLE item before the spatial split, so its results match the
         single-device reference exactly."""
         out = run_multidevice(SHARDED_PRELUDE + """
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 part = GridPartition(mesh=mesh, axis_names=("model",), array_axes=(0,))
 
 def prep(item):
@@ -725,7 +727,7 @@ print("OKPREP")
         auto."""
         out = run_multidevice(SHARDED_PRELUDE + """
 from repro.core.executor import auto_unroll
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 part = GridPartition(mesh=mesh, axis_names=("model",), array_axes=(0,))
 check(FarmEngine(mkloop("pallas-sharded", part), lanes=4, mesh=mesh))
 # unroll='auto' checks the condition every T sweeps: parity against the
@@ -739,9 +741,35 @@ print("OKCOMPOSED")
 """)
         assert "OKCOMPOSED" in out
 
-    def test_validation(self):
+    @pytest.mark.parametrize("continuous", [False, True])
+    def test_default_mesh_accepted(self, continuous, rng):
+        """``jax.make_mesh``'s default mesh (Explicit axes) is normalised
+        to Auto by FarmEngine and GridPartition, so lanes over it stream
+        and match the solo runs."""
+        from jax.sharding import AxisType
         from repro.core import GridPartition
         mesh = jax.make_mesh((1,), ("data",))
+        assert AxisType.Explicit in mesh.axis_types
+        part = GridPartition(mesh=mesh, axis_names=("data",),
+                             array_axes=(0,))
+        assert all(t == AxisType.Auto for t in part.mesh.axis_types)
+        loop = mkloop("pallas")
+        eng = FarmEngine(loop, lanes=2, mesh=mesh, segment=6)
+        assert all(t == AxisType.Auto for t in eng.mesh.axis_types)
+        items = list(np.asarray(mixed_batch(rng, n=3)))
+        outs = []
+        assert eng.run(items, outs.append, continuous=continuous) == 3
+        outs.sort(key=lambda r: getattr(r, "index", 0))
+        for it, res in zip(items, outs):
+            ref = loop.run(jnp.asarray(it))
+            assert int(res.iters) == int(ref.iters)
+            np.testing.assert_allclose(np.asarray(res.a),
+                                       np.asarray(ref.a), atol=1e-5)
+
+    def test_validation(self):
+        from repro.core import GridPartition
+        from repro.sharding.specs import make_mesh
+        mesh = make_mesh((1,), ("data",))
         part = GridPartition(mesh=mesh, axis_names=("data",),
                              array_axes=(0,))
         loop = LoopOfStencilReduce(
@@ -761,6 +789,7 @@ COMPOSED_PRELUDE = """
 import os, sys
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import FarmEngine, GridPartition, LoopOfStencilReduce
+from repro.sharding.specs import make_mesh
 
 def countdown(get, *_):
     return get(0, 0) - 1.0
@@ -776,7 +805,7 @@ def trip_items(trips, shape=(32, 64)):
                        dtype=np.float32).reshape(shape)
     return [base + float(t) - 1.0 for t in trips]
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 part = GridPartition(mesh=mesh, axis_names=("model",), array_axes=(0,))
 """
 
@@ -898,7 +927,7 @@ print("OKJAXPR")
         OWN env (a slot keeping the previous occupant's env — or a
         non-owner shard clobbering a live slot — would diverge)."""
         out = run_multidevice(SHARDED_PRELUDE + """
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 part = GridPartition(mesh=mesh, axis_names=("model",), array_axes=(0,))
 
 def prep(item):

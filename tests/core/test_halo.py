@@ -42,7 +42,8 @@ solo = LoopOfStencilReduce(f=jac, k=1, combine="max", identity=-jnp.inf,
 class TestDistributedPattern:
     def test_1d_rows_decomposition(self):
         out = run_multidevice(PRELUDE + textwrap.dedent("""
-            mesh = jax.make_mesh((8,), ("data",))
+            from repro.sharding.specs import make_mesh
+            mesh = make_mesh((8,), ("data",))
             part = GridPartition(mesh=mesh, axis_names=("data",),
                                  array_axes=(0,))
             dist = distributed_loop_of_stencil_reduce(
@@ -57,7 +58,8 @@ class TestDistributedPattern:
 
     def test_2d_decomposition_with_corners(self):
         out = run_multidevice(PRELUDE + textwrap.dedent("""
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.sharding.specs import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             part = GridPartition(mesh=mesh, axis_names=("data", "model"),
                                  array_axes=(0, 1))
             # k=2 stencil with diagonal (corner) taps
@@ -76,7 +78,8 @@ class TestDistributedPattern:
 
     def test_wrap_boundary_ring_exchange(self):
         out = run_multidevice(PRELUDE + textwrap.dedent("""
-            mesh = jax.make_mesh((8,), ("data",))
+            from repro.sharding.specs import make_mesh
+            mesh = make_mesh((8,), ("data",))
             part = GridPartition(mesh=mesh, axis_names=("data",),
                                  array_axes=(0,))
             one = stencil_taps(lambda g: jac(g), b0, 1, "wrap")

@@ -69,9 +69,10 @@ def check(want, got, boundary):
     np.testing.assert_allclose(float(got.reduced), float(want.reduced),
                                atol=1e-5)
 
-part1d = lambda: GridPartition(mesh=jax.make_mesh((8,), ("data",)),
+from repro.sharding.specs import make_mesh
+part1d = lambda: GridPartition(mesh=make_mesh((8,), ("data",)),
                                axis_names=("data",), array_axes=(0,))
-part2d = lambda: GridPartition(mesh=jax.make_mesh((4, 2), ("data", "model")),
+part2d = lambda: GridPartition(mesh=make_mesh((4, 2), ("data", "model")),
                                axis_names=("data", "model"),
                                array_axes=(0, 1))
 """
@@ -243,7 +244,8 @@ class TestShardedValidation:
         import jax
         import jax.numpy as jnp
         from repro.core import GridPartition, LoopOfStencilReduce
-        mesh = jax.make_mesh((1,), ("data",))
+        from repro.sharding.specs import make_mesh
+        mesh = make_mesh((1,), ("data",))
         part = GridPartition(mesh=mesh, axis_names=("data",),
                              array_axes=(0,))
         loop = LoopOfStencilReduce(

@@ -13,7 +13,8 @@ from repro.core import StreamRunner, farm, ofarm, pipe, sharded_farm
 
 
 def test_sharded_farm_traces_once():
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.sharding.specs import make_mesh
+    mesh = make_mesh((1,), ("data",))
     traces = {"n": 0}
 
     def worker(x):
@@ -30,7 +31,8 @@ def test_sharded_farm_traces_once():
 
 
 def test_sharded_farm_new_shape_retraces_same_wrapper():
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.sharding.specs import make_mesh
+    mesh = make_mesh((1,), ("data",))
     traces = {"n": 0}
 
     def worker(x):

@@ -67,6 +67,27 @@ def test_radii_and_env(k, rng):
     np.testing.assert_allclose(float(red), float(wr), rtol=1e-4)
 
 
+def test_vmap_runs_the_lane_grid(rng):
+    """vmap of the framed kernel runs its own lane grid, with lanes
+    sharing the env field: every lane matches its solo sweep."""
+    from repro.core.frames import frame_spec, make_frame
+    from repro.kernels.stencil2d import stencil2d_fused_framed
+    spec = frame_spec(40, 136, k=1, block=(16, 128))
+    a = jnp.asarray(rng.normal(size=(3, 40, 136)), jnp.float32)
+    env = jnp.asarray(rng.normal(size=spec.interior), jnp.float32)
+    sweep = lambda fr: stencil2d_fused_framed(
+        fr, lambda get, e: R.heat_taps(0.1)(get) + e, spec,
+        env_framed=(env,), combine="max", measure=R.abs_delta,
+        interpret=True)
+    frames = jax.vmap(lambda x: make_frame(x, spec, "zero"))(a)
+    out, red = jax.vmap(sweep)(frames)
+    for i in range(3):
+        o, r = sweep(frames[i])
+        np.testing.assert_array_equal(np.asarray(out[i][spec.domain]),
+                                      np.asarray(o[spec.domain]))
+        assert float(red[i]) == float(r)
+
+
 class TestApps:
     def test_jacobi_solver_converges_and_matches_ref_path(self, rng):
         # alpha strengthens the diagonal => contraction converges quickly

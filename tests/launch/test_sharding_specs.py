@@ -4,19 +4,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.sharding import AbstractMesh
+
 from repro.configs import get_config
 from repro.sharding import specs as SH
 
 
-from repro.sharding.specs import make_abstract_mesh
-
-
 @pytest.fixture(scope="module")
 def mesh():
-    # spec rules only read mesh.shape / axis_names — a 1-device mesh with
-    # logical sizes is enough for unit tests? No: sizes matter. Use the
-    # abstract mesh API instead.
-    return make_abstract_mesh((16, 16), ("data", "model"))
+    # spec rules read mesh sizes: a device-free mesh of the real shape
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 class TestParamSpecRules:
@@ -74,11 +71,11 @@ class TestZero1:
 
 class TestBatchSpec:
     def test_composes_pod_and_data(self):
-        m = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+        m = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
         spec = SH.batch_spec(m, 256)
         assert spec[0] == ("pod", "data")
 
     def test_batch_one_unsharded(self):
-        m = make_abstract_mesh((16, 16), ("data", "model"))
+        m = AbstractMesh((16, 16), ("data", "model"))
         spec = SH.batch_spec(m, 1)
         assert spec[0] is None

@@ -22,7 +22,8 @@ def test_expert_parallel_matches_dense_dispatch():
         from repro.models.layers import init_moe, moe
         from repro.models.moe_parallel import expert_parallel_moe
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.sharding.specs import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         E, D, F, topk = 8, 32, 64, 2
         params = init_moe(jax.random.PRNGKey(0), D, E, F, 1, 48, True,
                           jnp.float32)
@@ -62,7 +63,8 @@ def test_expert_parallel_batch_one():
         import jax, jax.numpy as jnp, numpy as np
         from repro.models.layers import init_moe, moe
         from repro.models.moe_parallel import expert_parallel_moe
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.sharding.specs import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         params = init_moe(jax.random.PRNGKey(0), 32, 8, 64, 0, 0, True,
                           jnp.float32)
         x = jnp.asarray(np.random.default_rng(1).normal(size=(1, 1, 32)),

@@ -377,7 +377,8 @@ def trip_items(trips, shape=(32, 64)):
                        dtype=np.float32).reshape(shape)
     return [base + float(t) - 1.0 for t in trips]
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.sharding.specs import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 part = GridPartition(mesh=mesh, axis_names=("model",), array_axes=(0,))
 
 items = trip_items([3, 9, 5, 7, 4, 6])

@@ -619,7 +619,8 @@ from repro.resilience import FaultPlan, RecoveryConfig
 def countdown(get, *_):
     return get(0, 0) - 1.0
 
-mesh = jax.make_mesh(({lanes}, {shards}), ("data", "model"))
+from repro.sharding.specs import make_mesh
+mesh = make_mesh(({lanes}, {shards}), ("data", "model"))
 part = GridPartition(mesh=mesh, axis_names=("model",), array_axes=(0,))
 loop = LoopOfStencilReduce(
     f=countdown, k=1, combine="max", cond=lambda r: r < 0.5,

@@ -46,9 +46,10 @@ def test_ef_psum_unbiased_over_steps():
         import sys
         sys.path.insert(0, %r)
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import Mesh, PartitionSpec as P, AxisType
+        from jax.sharding import Mesh, PartitionSpec as P
+        from repro.sharding.specs import make_mesh
         from repro.train.compression import ef_int8_psum
-        mesh = jax.make_mesh((2,), ("pod",), axis_types=(AxisType.Auto,))
+        mesh = make_mesh((2,), ("pod",))
         rng = np.random.default_rng(0)
         gs = jnp.asarray(rng.normal(size=(2, 20, 256)).astype(np.float32))
 

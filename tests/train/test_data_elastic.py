@@ -82,7 +82,8 @@ def test_elastic_restore_onto_sharded_mesh():
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.train import checkpoint as C
-            mesh = jax.make_mesh((8,), ("data",))
+            from repro.sharding.specs import make_mesh
+            mesh = make_mesh((8,), ("data",))
             template = {"w": jnp.zeros((64, 16), jnp.float32),
                         "b": jnp.zeros((16,), jnp.bfloat16)}
             sh = {"w": NamedSharding(mesh, P("data", None)),
