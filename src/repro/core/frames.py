@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from .semantics import Boundary
+from .spans import scope
 
 
 TILE = (8, 128)      # TPU (sublane, lane) tiling of a 32-bit array
@@ -152,9 +153,11 @@ def refresh_frame(frame: jnp.ndarray, spec: FrameSpec,
     """
     boundary = Boundary(boundary)
     (r0, c0), p = spec.origin, spec.pad
-    frame = _refresh_axis_local(frame, spec, 0, boundary, c0, c0 + spec.n)
-    return _refresh_axis_local(frame, spec, 1, boundary,
-                               r0 - p, r0 + spec.m + p)
+    with scope("ghost_refresh"):
+        frame = _refresh_axis_local(frame, spec, 0, boundary,
+                                    c0, c0 + spec.n)
+        return _refresh_axis_local(frame, spec, 1, boundary,
+                                   r0 - p, r0 + spec.m + p)
 
 
 def unframe(frame: jnp.ndarray, spec: FrameSpec) -> jnp.ndarray:
@@ -518,14 +521,15 @@ def refresh_frame_sharded(frame: jnp.ndarray, sspec: ShardedFrameSpec,
     spec = sspec.local
     (r0, c0), p = spec.origin, spec.pad
     extents = ((c0, c0 + spec.n), (r0 - p, r0 + spec.m + p))
-    for axis in (0, 1):
-        olo, ohi = extents[axis]
-        if sspec.axis_names[axis] is None:
-            frame = _refresh_axis_local(frame, spec, axis, boundary,
-                                        olo, ohi)
-        else:
-            frame = _refresh_axis_sharded(frame, sspec, axis, boundary,
-                                          olo, ohi)
+    with scope("ghost_refresh"):
+        for axis in (0, 1):
+            olo, ohi = extents[axis]
+            if sspec.axis_names[axis] is None:
+                frame = _refresh_axis_local(frame, spec, axis, boundary,
+                                            olo, ohi)
+            else:
+                frame = _refresh_axis_sharded(frame, sspec, axis,
+                                              boundary, olo, ohi)
     return frame
 
 

@@ -49,6 +49,7 @@ from .executor import BACKENDS
 from .reduce import (HEALTH_STALL_MASK, health_update, resolve_monoid,
                      tree_reduce)
 from .semantics import Boundary
+from .spans import scope
 from .stencil import stencil_taps, stencil_windows, stencil_indexed
 
 
@@ -497,11 +498,13 @@ class LoopOfStencilReduce:
             hw_new, quar = health_update(hw, r_new, r, live, done_new,
                                          it, self.sentinel)
             retire = jnp.logical_or(done_new, quar)
-            return (lane_where(live, a, a_new),
-                    jnp.where(live, r_new, r),
-                    jnp.where(live, it + self.unroll, it),
-                    jnp.where(live, jnp.logical_or(done, retire), done),
-                    jnp.where(live, hw_new, hw))
+            with scope("done_mask"):
+                return (lane_where(live, a, a_new),
+                        jnp.where(live, r_new, r),
+                        jnp.where(live, it + self.unroll, it),
+                        jnp.where(live, jnp.logical_or(done, retire),
+                                  done),
+                        jnp.where(live, hw_new, hw))
 
         return body
 
@@ -594,11 +597,12 @@ class LoopOfStencilReduce:
             # done-masking => vmap/farm safe
             keep = lambda old, new: jax.tree.map(
                 lambda o, n: jnp.where(done, o, n), old, new)
-            return (keep(a, a_new), jnp.where(done, r, r_new),
-                    jnp.where(done, it, it_new), keep(s, s_new),
-                    jnp.logical_or(done,
-                                   jnp.logical_or(done_new, quar)),
-                    jnp.where(done, hw, hw_new))
+            with scope("done_mask"):
+                return (keep(a, a_new), jnp.where(done, r, r_new),
+                        jnp.where(done, it, it_new), keep(s, s_new),
+                        jnp.logical_or(done,
+                                       jnp.logical_or(done_new, quar)),
+                        jnp.where(done, hw, hw_new))
 
         def cond_fun(carry):
             _, _, it, _, done, _ = carry

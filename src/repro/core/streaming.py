@@ -62,6 +62,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .reduce import HEALTH_CONVERGED, HEALTH_DIVERGED, HEALTH_POISONED
+from .spans import span
 
 
 class NonFiniteItemError(ValueError):
@@ -1612,7 +1613,8 @@ class FarmEngine:
                     if entry["index"] in emitted_pre:
                         continue
                     try:
-                        self._check_item(entry["item"])
+                        with span("farm.check"):
+                            self._check_item(entry["item"])
                     except NonFiniteItemError:
                         self.stats["rejected"] += 1
                         emit(entry, "rejected")
@@ -1620,9 +1622,10 @@ class FarmEngine:
                     break
                 # item leaves ride as numpy through the jit fast path —
                 # no eager per-leaf device_put on the host's stage side
-                ring, ring_envs = self._stage_fn(
-                    ring, ring_envs, np.int32(wr_host % K),
-                    entry["item"])
+                with span("farm.prep"):
+                    ring, ring_envs = self._stage_fn(
+                        ring, ring_envs, np.int32(wr_host % K),
+                        entry["item"])
                 staged.append(entry)
                 wr_host += 1
                 self.stats["h2d_bytes"] += _item_nbytes(entry["item"])
@@ -1633,8 +1636,9 @@ class FarmEngine:
                 # cursor, so staying < K deep can never overwrite a
                 # ring position an in-flight chain might still read
                 while wr_host - rd_host < K:
-                    if not stage_next():
-                        return
+                    with span("farm.stage"):
+                        if not stage_next():
+                            return
 
             def unstage_all():
                 """Rewind the ring at a repair boundary: un-seated
@@ -1664,10 +1668,11 @@ class FarmEngine:
             def dispatch():
                 nonlocal frames, env_frames, r, itv, done, hw
                 nonlocal ring, ring_envs, rd
-                (frames, env_frames, r, itv, done, hw, ring, ring_envs,
-                 rd, meta, r_pre, outs) = self._chain_fn(
-                     frames, env_frames, r, itv, done, hw, ring,
-                     ring_envs, rd, np.int32(wr_host), live_mask())
+                with span("farm.dispatch"):
+                    (frames, env_frames, r, itv, done, hw, ring,
+                     ring_envs, rd, meta, r_pre, outs) = self._chain_fn(
+                         frames, env_frames, r, itv, done, hw, ring,
+                         ring_envs, rd, np.int32(wr_host), live_mask())
                 self.stats["segments"] += 1
                 if on_segment is not None:
                     # the preemption seam, as in the classic loop:
@@ -1686,65 +1691,66 @@ class FarmEngine:
                 (lane order over the finished live slots = the device's
                 rank order)."""
                 nonlocal prev_it, rd_host
-                meta_d, r_d, outs_d = inflight.popleft()
-                (meta_h,) = self._meta_read(meta_d)
-                fin_h = meta_h[0:L] != 0
-                it_h = meta_h[L:2 * L].astype(np.int64)
-                hw_h = meta_h[2 * L:3 * L]
-                took_h = meta_h[3 * L:4 * L] != 0
-                steps_h = meta_h[4 * L:]
-                for s in range(self._nshards):
-                    sl = slice(s * local_L, (s + 1) * local_L)
-                    total = int(steps_h[s]) * unroll * local_L
-                    useful = int((it_h[sl] - prev_it[sl]).sum())
-                    self.stats["lane_steps"] += total
-                    self.stats["wasted_lane_steps"] += total - useful
-                prev_it = np.where(took_h, 0, it_h)
-                outs_h = r_h = None
-                for slot in range(L):
-                    entry = occupants[slot]
-                    if entry is None or not fin_h[slot]:
-                        continue
-                    occupants[slot] = None
-                    status = item_status(hw_h[slot], it_h[slot],
-                                         loop.max_iters)
-                    if status != "ok":
-                        self.stats["quarantined_lane_steps"] += \
-                            int(it_h[slot])
-                        slot_fails[slot] += 1
-                    else:
-                        slot_fails[slot] = 0
-                    if status != "ok" and \
-                            entry["attempts"] < self.max_attempts:
-                        entry["bad_slots"].add(slot)
-                        retry_q.append(entry)
-                        self.stats["retries"] += 1
-                    else:
-                        if outs_h is None:   # ONE payload pull per
-                            outs_h, r_h = jax.device_get(  # drained seg
-                                (outs_d, r_d))
-                        out = outs_h[slot]
-                        self.stats["d2h_bytes"] += (
-                            out.nbytes + r_h[slot].nbytes + 4)
-                        emit(entry, status, a=out, reduced=r_h[slot],
-                             iters=it_h[slot])
-                    if (not slot_dead[slot]
-                            and slot_fails[slot] >= self.slot_patience
-                            and L - sum(slot_dead) > 1):
-                        # quarantine lags one in-flight dispatch: the
-                        # chain already in flight may seat one more
-                        # occupant here before the live mask catches up
-                        slot_dead[slot] = True
-                        self.stats["quarantined_slots"] += 1
-                for slot in range(L):
-                    if not took_h[slot]:
-                        continue
-                    assert staged, "device seated more than was staged"
-                    entry = staged.popleft()
-                    entry["attempts"] += 1
-                    occupants[slot] = entry
-                    self.stats["refills"] += 1
-                    rd_host += 1
+                with span("farm.drain"):
+                    meta_d, r_d, outs_d = inflight.popleft()
+                    (meta_h,) = self._meta_read(meta_d)
+                    fin_h = meta_h[0:L] != 0
+                    it_h = meta_h[L:2 * L].astype(np.int64)
+                    hw_h = meta_h[2 * L:3 * L]
+                    took_h = meta_h[3 * L:4 * L] != 0
+                    steps_h = meta_h[4 * L:]
+                    for s in range(self._nshards):
+                        sl = slice(s * local_L, (s + 1) * local_L)
+                        total = int(steps_h[s]) * unroll * local_L
+                        useful = int((it_h[sl] - prev_it[sl]).sum())
+                        self.stats["lane_steps"] += total
+                        self.stats["wasted_lane_steps"] += total - useful
+                    prev_it = np.where(took_h, 0, it_h)
+                    outs_h = r_h = None
+                    for slot in range(L):
+                        entry = occupants[slot]
+                        if entry is None or not fin_h[slot]:
+                            continue
+                        occupants[slot] = None
+                        status = item_status(hw_h[slot], it_h[slot],
+                                             loop.max_iters)
+                        if status != "ok":
+                            self.stats["quarantined_lane_steps"] += \
+                                int(it_h[slot])
+                            slot_fails[slot] += 1
+                        else:
+                            slot_fails[slot] = 0
+                        if status != "ok" and \
+                                entry["attempts"] < self.max_attempts:
+                            entry["bad_slots"].add(slot)
+                            retry_q.append(entry)
+                            self.stats["retries"] += 1
+                        else:
+                            if outs_h is None:   # ONE payload pull per
+                                outs_h, r_h = jax.device_get(  # drained seg
+                                    (outs_d, r_d))
+                            out = outs_h[slot]
+                            self.stats["d2h_bytes"] += (
+                                out.nbytes + r_h[slot].nbytes + 4)
+                            emit(entry, status, a=out, reduced=r_h[slot],
+                                 iters=it_h[slot])
+                        if (not slot_dead[slot]
+                                and slot_fails[slot] >= self.slot_patience
+                                and L - sum(slot_dead) > 1):
+                            # quarantine lags one in-flight dispatch: the
+                            # chain already in flight may seat one more
+                            # occupant here before the live mask catches up
+                            slot_dead[slot] = True
+                            self.stats["quarantined_slots"] += 1
+                    for slot in range(L):
+                        if not took_h[slot]:
+                            continue
+                        assert staged, "device seated more than was staged"
+                        entry = staged.popleft()
+                        entry["attempts"] += 1
+                        occupants[slot] = entry
+                        self.stats["refills"] += 1
+                        rd_host += 1
 
             while True:
                 dispatched = False
