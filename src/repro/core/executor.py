@@ -10,11 +10,12 @@ and its realisations.  Three backends:
 
 ``"pallas"``
     The fused single-step Pallas kernel iterated on a **persistent halo
-    frame**: the padded, block-rounded frame (:mod:`repro.core.frames`) is
-    the ``while_loop`` carry, so no ``jnp.pad`` or full-grid slice appears
-    inside the loop body — the paper's device-memory persistence taken to
-    the HBM-traffic level.  Only the O(m+n) ghost ring is re-asserted
-    between sweeps.
+    frame**: two padded, block-rounded frames (:mod:`repro.core.frames`)
+    are the ``while_loop`` carry and swap roles every sweep (each sweep
+    writes the frame the previous one read), so no ``jnp.pad``, full-grid
+    slice, select or copy appears inside the loop body — the paper's
+    device-memory persistence taken to the HBM-traffic level.  Only the
+    O(m+n) ghost ring is re-asserted between sweeps.
 
 ``"pallas-multistep"``
     Temporal blocking: the pattern's ``unroll=T`` becomes the fused sweep

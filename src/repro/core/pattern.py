@@ -17,17 +17,19 @@ The ``backend`` axis picks the loop-body realisation (see
 :mod:`repro.core.executor`): ``"jnp"`` applies the stencil through the
 shift algebra (pad per application); ``"pallas"`` iterates the fused
 Pallas kernel on a *persistent halo frame* — padding and block round-up
-happen once before the loop, the frame is the while-carry, and only the
-O(m+n) ghost ring is re-asserted per sweep; ``"pallas-multistep"``
-additionally fuses ``unroll`` sweeps per HBM round-trip (temporal
-blocking).  Read-only per-cell fields (the paper's ``env``) enter through
-``run(..., env=(...))`` and are staged once alongside the frame.
+happen once before the loop, two frames that swap roles every sweep are
+the while-carry, and only the O(m+n) ghost ring is re-asserted per
+sweep; ``"pallas-multistep"`` additionally fuses ``unroll`` sweeps per
+HBM round-trip (temporal blocking).  Read-only per-cell fields (the
+paper's ``env``) enter through ``run(..., env=(...))`` and are staged
+once alongside the frame.
 
-Loop bodies are *done-masked* so the pattern is ``vmap``-safe: under
-``farm`` (streaming 1:1 mode) each stream item runs to its own trip count
-while vmap executes until all are done.  :meth:`LoopOfStencilReduce.
-farm_run` makes that mode first-class — ONE while_loop over a stacked
-(lanes, frame) carry with per-lane done masks — and
+The pattern is ``vmap``-safe: under ``farm`` (streaming 1:1 mode) each
+stream item runs to its own trip count while vmap executes until all are
+done (JAX's batched ``while_loop`` keeps a finished lane's carry).
+:meth:`LoopOfStencilReduce.farm_run` makes that mode first-class — ONE
+while_loop over a stacked (lanes, frame) carry with per-lane done masks —
+and
 :class:`repro.core.streaming.FarmEngine` streams through it with lane
 slots that persist (and are refilled in place) across stream items.
 
@@ -331,14 +333,16 @@ class LoopOfStencilReduce:
 
     # -- the persistent-halo loop (pallas backends) ----------------------
     def _run_persistent(self, a0, state0, env) -> LoopResult:
-        """Zero-copy realisation: the halo frame is the while-carry.
+        """Zero-copy realisation: two halo frames are the while-carry.
 
         Padding/round-up happens once in ``prepare``; the loop body is
-        kernel sweeps + O(m+n) ghost refresh — no ``jnp.pad`` or full-grid
-        slice per iteration.  The domain is sliced back exactly once after
-        convergence.  (The -s variant's ``state_update`` still sees the
-        (m, n) view each check, which costs a slice — avoid combining a
-        per-iteration state with the persistent backends on hot paths.)
+        kernel sweeps, each writing the frame the last one read, + O(m+n)
+        ghost refresh — no ``jnp.pad``, full-grid slice, select or copy per
+        iteration (:meth:`_drive_frames`).  The domain is sliced back
+        exactly once after convergence.  (The -s variant's
+        ``state_update`` still sees the (m, n) view each check, which
+        costs a slice — avoid combining a per-iteration state with the
+        persistent backends on hot paths.)
         """
         from .executor import StencilEngine
 
@@ -348,15 +352,15 @@ class LoopOfStencilReduce:
             measure=self.measure, block=self.block, unroll=self.unroll,
             backend=self.backend, interpret=self.interpret)
         frame0, env_frames, spec = eng.prepare(a0, env)
-        return self._drive(frame0, state0,
-                           step=lambda fr: eng.sweeps(fr, env_frames, spec),
-                           state_view=lambda fr: eng.unframe(fr, spec),
-                           finalize=lambda fr: eng.unframe(fr, spec))
+        return self._drive_frames(
+            frame0, state0,
+            step=lambda fr: eng.sweeps(fr, env_frames, spec),
+            unframe=lambda fr: eng.unframe(fr, spec))
 
     # -- the sharded persistent loop (1:n deployment) --------------------
     def _run_sharded(self, a0, state0, env) -> LoopResult:
         """The whole repeat/until runs INSIDE ``shard_map``: each shard's
-        while-carry is its local halo frame, the per-check ghost refresh
+        while-carry is its two local halo frames, the per-check ghost refresh
         is a ppermute of edge strips, and the fused reduce composes with
         the monoid collective so every shard evaluates the identical
         condition — one SPMD program, no host (and no full-block copy)
@@ -384,11 +388,12 @@ class LoopOfStencilReduce:
 
         def local_run(block, *env_local):
             frame0, env_frames, sspec = eng.prepare(block, env_local)
-            res = self._drive(
+            # every shard reads the same combined reduce, so both steps
+            # of the two-frame body stay in step mesh-wide
+            res = self._drive_frames(
                 frame0, None,
                 step=lambda fr: eng.sweeps(fr, env_frames, sspec),
-                state_view=lambda fr: eng.unframe(fr, sspec),
-                finalize=lambda fr: eng.unframe(fr, sspec))
+                unframe=lambda fr: eng.unframe(fr, sspec))
             return res.a, res.reduced, res.iters, res.health
 
         from jax.sharding import PartitionSpec as P
@@ -576,32 +581,46 @@ class LoopOfStencilReduce:
             early_exit=early_exit)
 
     # -- shared while_loop scaffold (all backends) -----------------------
+    def _advance(self, step, state_view, a, r, it, s, hw, live):
+        """One check of the loop: ``step``, the -s update, the condition
+        and the sentinel.  Returns ``(a', (r', it', s', done', hw'))``,
+        ``done'`` true when the condition fired or the sentinel
+        quarantined the loop."""
+        a_new, r_new = step(a)
+        it_new = it + self.unroll
+        s_new = (self.state_update(s, state_view(a_new), it_new)
+                 if self.state_update is not None else s)
+        done = self._cond_value(r_new, s_new)
+        hw_new, quar = health_update(hw, r_new, r, live, done, it,
+                                     self.sentinel)
+        return a_new, (r_new, it_new, s_new, jnp.logical_or(done, quar),
+                       hw_new)
+
     def _drive(self, a0, state0, *, step, state_view, finalize
                ) -> LoopResult:
         """The repeat/until driver: ``step(a) -> (a_new, reduced)`` does
         ``unroll`` stencil applications in whatever representation the
-        backend carries (plain array or halo frame); ``state_view`` maps
-        that representation to what -s state updates see; ``finalize``
-        maps the converged carry to the result array.  Done-masking keeps
-        every backend vmap/farm safe."""
+        backend carries; ``state_view`` maps that representation to what
+        -s state updates see; ``finalize`` maps the converged carry to
+        the result array.  The jnp backend and the halo deployment
+        (:mod:`repro.core.halo`) drive through here; the frame engines
+        use :meth:`_drive_frames`.
+
+        The done mask is not what makes ``vmap`` safe: the body runs only
+        while ``done`` is False, and under ``vmap`` JAX's batched
+        ``while_loop`` already keeps each finished lane's carry.  It stays
+        because XLA fuses it into the stencil's own fusion here."""
 
         def body(carry):
             a, r, it, s, done, hw = carry
-            a_new, r_new = step(a)
-            it_new = it + self.unroll
-            s_new = (self.state_update(s, state_view(a_new), it_new)
-                     if self.state_update is not None else s)
-            done_new = self._cond_value(r_new, s_new)
-            hw_new, quar = health_update(hw, r_new, r, ~done, done_new,
-                                         it, self.sentinel)
-            # done-masking => vmap/farm safe
+            a_new, (r_new, it_new, s_new, done_new, hw_new) = self._advance(
+                step, state_view, a, r, it, s, hw, ~done)
             keep = lambda old, new: jax.tree.map(
                 lambda o, n: jnp.where(done, o, n), old, new)
             with scope("done_mask"):
                 return (keep(a, a_new), jnp.where(done, r, r_new),
                         jnp.where(done, it, it_new), keep(s, s_new),
-                        jnp.logical_or(done,
-                                       jnp.logical_or(done_new, quar)),
+                        jnp.logical_or(done, done_new),
                         jnp.where(done, hw, hw_new))
 
         def cond_fun(carry):
@@ -617,6 +636,59 @@ class LoopOfStencilReduce:
         a, r, it, s, _, hw = jax.lax.while_loop(cond_fun, body, carry0)
         return LoopResult(a=finalize(a), reduced=r, iters=it, state=s,
                           health=hw)
+
+    # -- the two-frame loop (frame engines) ------------------------------
+    def _drive_frames(self, frame0, state0, *, step, unframe
+                      ) -> LoopResult:
+        """:meth:`_drive` for the frame engines, with no whole-frame select.
+
+        ``step`` is :meth:`_drive`'s; ``unframe`` slices the domain out of
+        a frame (what -s state updates see, and the answer).  The carry
+        holds two frames: each body iteration runs A → B, then B → A, so
+        every kernel output lands in the carry slot of a frame nothing
+        reads any more, and XLA writes it there.  A one-frame carry needs
+        a select or a copy for that, since the kernel reads its input
+        while it writes.  After each step the condition, the sentinel and
+        the -s update run exactly as in :meth:`_drive`.  When the first
+        step stops the loop (condition, quarantine or cap), scalar picks
+        keep its reduce, count, health word and state, and ``odd``
+        records that B holds the answer; the second step's sweep is
+        thrown away.
+
+        Results equal :meth:`_drive`'s bitwise for any trip count, at the
+        cap and under ``vmap``.  Only frame cells beyond the domain's
+        ghost ring (round-up, margin) may differ, and no domain cell
+        depends on them."""
+
+        # the body runs only for a live loop (under vmap, JAX keeps a
+        # finished lane's carry), so every check in it is live
+        def body(carry):
+            a, _, r, it, s, _, hw, _ = carry
+            b, first = self._advance(step, unframe, a, r, it, s, hw, True)
+            r1, it1, s1, done1, hw1 = first
+            a, second = self._advance(step, unframe, b, r1, it1, s1, hw1,
+                                      True)
+            stop = jnp.logical_or(done1, it1 >= self.max_iters)
+            with scope("done_mask"):
+                picked = jax.tree.map(
+                    lambda x, y: jnp.where(stop, x, y), first, second)
+            return (a, b, *picked, stop)
+
+        def cond_fun(carry):
+            it, done = carry[3], carry[5]
+            return jnp.logical_and(~done, it < self.max_iters)
+
+        r_shape = jax.eval_shape(lambda fr: step(fr)[1], frame0)
+        r0 = jnp.asarray(self._id, dtype=r_shape.dtype)
+        carry0 = (frame0, jnp.zeros_like(frame0), r0,
+                  jnp.asarray(0, jnp.int32), state0, jnp.asarray(False),
+                  jnp.asarray(0, jnp.int32), jnp.asarray(False))
+        a, b, r, it, s, _, hw, odd = jax.lax.while_loop(cond_fun, body,
+                                                        carry0)
+        # pick after the slice: one domain-sized pass, no frame copy (a
+        # cond over the frames would hoist the slice and copy a frame)
+        return LoopResult(a=jnp.where(odd, unframe(b), unframe(a)),
+                          reduced=r, iters=it, state=s, health=hw)
 
     # convenience: a jitted runner
     def jit_run(self, donate: bool = True):

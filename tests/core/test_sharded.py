@@ -4,7 +4,8 @@ Parity: the 1:n persistent deployment (per-shard halo frames inside
 shard_map, ppermute ghost exchange, monoid collectives) must match the
 single-device "jnp" and "pallas" backends — values, reduce, iteration
 counts — across 1-D and 2-D decompositions, all four ⊥ models,
-sum/max/any monoids, and unroll ∈ {1, 4} (deep-halo temporal blocking).
+sum/max/any monoids, and unroll ∈ {1, 4} (deep-halo temporal blocking);
+its two-frame loop must equal the done-masked ``_drive`` loop bitwise.
 
 Zero-copy/communication-avoiding: jaxpr inspection of the sharded
 while_loop body shows no ``pad``, no array-sized ``concatenate``, no
@@ -68,6 +69,33 @@ def check(want, got, boundary):
     np.testing.assert_allclose(ga, wa, atol=1e-5)
     np.testing.assert_allclose(float(got.reduced), float(want.reduced),
                                atol=1e-5)
+
+# pallas-sharded driven by the done-masked _drive: the loop the two-frame
+# driver replaced, kept as its oracle
+def drive_ref(lp, x):
+    from jax.sharding import PartitionSpec as P
+    from repro.core.executor import ShardedStencilEngine
+    from repro.sharding.specs import shard_map
+    part = lp.partition
+    eng = ShardedStencilEngine(
+        f=lp.f, part=part, k=lp.k, boundary=lp.boundary,
+        combine=lp.combine, identity=lp.identity, delta=lp.delta,
+        measure=lp.measure, block=lp.block, unroll=lp.unroll,
+        interpret=lp.interpret)
+
+    def local_run(block):
+        fr, efs, sspec = eng.prepare(block, ())
+        view = lambda f: eng.unframe(f, sspec)
+        res = lp._drive(fr, None, step=lambda f: eng.sweeps(f, efs, sspec),
+                        state_view=view, finalize=view)
+        return res.a, res.reduced, res.iters, res.health
+
+    return shard_map(local_run, mesh=part.mesh, in_specs=(part.pspec,),
+                     out_specs=(part.pspec, P(), P(), P()))(x)
+
+def check_bitwise(got, want):
+    for g, w in zip((got.a, got.reduced, got.iters, got.health), want):
+        assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
 
 from repro.sharding.specs import make_mesh
 part1d = lambda: GridPartition(mesh=make_mesh((8,), ("data",)),
@@ -147,6 +175,27 @@ class TestShardedParity:
             print("OKENV")
         """))
         assert "OKENV" in out
+
+    def test_two_frame_driver_equals_drive(self):
+        """The two-frame loop equals the done-masked ``_drive`` loop
+        bitwise on both decompositions: tolerance exits after an odd and
+        an even number of checks, and the cap."""
+        out = run_multidevice(PRELUDE + textwrap.dedent("""
+            never = lambda r: False
+            for part, unroll, tol, max_iters, checks in (
+                (part1d(), 1, 2e-2, 400, 19), (part2d(), 1, 1.5e-2, 400, 24),
+                (part2d(), 1, None, 7, 7), (part2d(), 4, 2e-2, 400, 5),
+                (part1d(), 4, 1.5e-2, 400, 6), (part1d(), 4, None, 9, 3),
+            ):
+                cond = never if tol is None else (lambda r, t=tol: r < t)
+                lp = loop("pallas-sharded", "zero", unroll, part,
+                          cond=cond, max_iters=max_iters)
+                got = lp.run(a)
+                assert int(got.iters) == checks * unroll, int(got.iters)
+                check_bitwise(got, drive_ref(lp, a))
+            print("OKDRIVE")
+        """))
+        assert "OKDRIVE" in out
 
     def test_distributed_front_end_sharded_backend(self):
         """distributed_loop_of_stencil_reduce(backend='pallas-sharded')

@@ -10,6 +10,8 @@ runs — and check that each program holds the kernel (``tpu_custom_call``).
 The topology is described inside a fixture, never at import: one process
 at a time may load the TPU library, and every worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -17,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.executor import StencilEngine
 from repro.core.frames import frame_spec
+from repro.core.pattern import LoopOfStencilReduce
 from repro.kernels import ref as R
 from repro.kernels.multistep import stencil2d_multistep_framed
 from repro.kernels.stencil2d import stencil2d_fused_framed
@@ -115,3 +118,63 @@ def test_swa_attention_hd128(one_chip):
     text = compiled_text(
         lambda q, k, v: swa_attention(q, k, v, window=512), qkv, qkv, qkv)
     assert "tpu_custom_call" in text
+
+
+def hlo_computations(text: str) -> dict:
+    """Instruction lines of each computation of an HLO module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            cur = re.match(r"(?:ENTRY )?%?([\w.\-]+)", line).group(1)
+            comps[cur] = []
+        elif cur is not None and line.startswith("  "):
+            comps[cur].append(line.strip())
+    return comps
+
+
+def while_body_lines(text: str) -> list:
+    """Instructions of every while body, and of the computations they
+    call (fusions, branches), transitively."""
+    comps = hlo_computations(text)
+    todo = [re.search(r"body=%?([\w.\-]+)", line).group(1)
+            for lines in comps.values() for line in lines
+            if " while(" in line]
+    assert todo, "no while loop in the executable"
+    seen, out = set(), []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            out.append(line)
+            todo += re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", line)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}",
+                                    line):
+                todo += [c.strip().lstrip("%") for c in group.split(",")]
+    return out
+
+
+@pytest.mark.parametrize("backend,unroll", [("pallas", 1),
+                                            ("pallas-multistep", 4)])
+def test_solve_body_4096_swaps_frames(backend, unroll, one_chip):
+    """The compiled loop of a 4096^2 Helmholtz solve: two kernel calls,
+    each writing the frame the other read, and no frame-sized select or
+    copy anywhere in the body."""
+    loop = LoopOfStencilReduce(
+        f=helmholtz(), k=1, combine="max", cond=lambda r: r < 1e-5,
+        delta=R.abs_delta, boundary="zero", max_iters=2000, unroll=unroll,
+        backend=backend, interpret=False)
+    grid = jax.ShapeDtypeStruct((4096, 4096), jnp.float32,
+                                sharding=one_chip)
+    text = compiled_text(lambda u, f: loop.run(u, env=(f,)), grid, grid)
+    spec = frame_spec(4096, 4096, k=1, block=(256, 256),
+                      sweeps=unroll if backend == "pallas-multistep" else 1)
+    frame = "f32[{},{}]".format(*spec.shape)
+    body = while_body_lines(text)
+    kernels = [line for line in body if "tpu_custom_call" in line]
+    assert len(kernels) == 2
+    frame_ops = [line for line in body
+                 if re.match(r"(?:ROOT )?%\S+ = " + re.escape(frame)
+                             + r"\S* (select|copy|copy-start)\(", line)]
+    assert not frame_ops, frame_ops
