@@ -58,3 +58,40 @@ def forcing_fields(seed: int, shape, count: int, field_seed: int,
     out = None if sharding is None else (sharding,) * count
     return list(jax.jit(make, out_shardings=out)(
         seed_key(field_seed), flip, np.float32(sign)))
+
+
+def _row_flips(seed: int, count: int):
+    """One bit per frame from the seed: flip that frame's rows?"""
+    words = np.random.SeedSequence([int(seed), 5]).generate_state(count)
+    return (words & 1).astype(bool)
+
+
+def restoration_pool(seed: int, shape, count: int, pool_seed: int,
+                     level: float):
+    """``count`` float32 frames of ``shape`` drawn from ``pool_seed``: a
+    smooth textured scene with ``level`` of its pixels replaced by 0 or 1,
+    equally likely (salt-and-pepper noise, arXiv:1609.04567 sec. 4.3),
+    each frame with its rows flipped where the seed says.  A row flip
+    swaps each pixel's north and south neighbours, which the adaptive
+    median (a sort) and the regularisation sweep (``a + b + c + d`` with
+    ``a``, ``b`` the north and south ones) take exactly, so every seed
+    does the same work.  Made on the device in one jitted call; returned
+    as host (numpy) frames, since a stream's frames arrive from the host."""
+    import jax
+    import jax.numpy as jnp
+
+    def make(key, flips):
+        h, w = shape
+        yy, xx = jnp.mgrid[0:h, 0:w].astype(jnp.float32)
+        base = (0.5 + 0.3 * jnp.sin(xx / 25.0) * jnp.cos(yy / 18.0)
+                + 0.2 * ((xx // 40 + yy // 30) % 2))
+        base = jnp.clip(base, 0.0, 1.0)
+        k1, k2 = jax.random.split(key)
+        hit = jax.random.uniform(k1, (count, h, w)) < level
+        salt = jax.random.uniform(k2, (count, h, w)) < 0.5
+        frames = jnp.where(hit, salt.astype(jnp.float32), base)
+        return jnp.where(flips[:, None, None], frames[:, ::-1], frames)
+
+    frames = np.asarray(jax.jit(make)(seed_key(pool_seed),
+                                      _row_flips(seed, count)))
+    return [frames[i] for i in range(count)]

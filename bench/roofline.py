@@ -41,9 +41,11 @@ def domain_bytes(shape, *, read: int, written: int = 1,
     return (read + written) * cells * itemsize
 
 
-def kernel_share(ctx, kernel: str):
+def kernel_share(ctx, kernel: str, shape: str = "grid", lanes: int = 1):
     """Percent of the bytes roofline a named kernel reached in the traced
-    window, or None where the trace holds no call of it."""
+    window, or None where the trace holds no call of it.  A call sweeps
+    ``lanes`` domains of the configuration's ``shape`` at once (the
+    lane-batched kernel of a farm)."""
     from bench import trace
 
     if ctx.trace is None:
@@ -52,7 +54,7 @@ def kernel_share(ctx, kernel: str):
     if not calls or seconds <= 0:
         return None
     fields = ctx.config["kernel_fields"]
-    per_call = domain_bytes(ctx.config["grid"], read=fields["read"],
-                            written=fields["written"])
+    per_call = lanes * domain_bytes(ctx.config[shape], read=fields["read"],
+                                    written=fields["written"])
     least_s = calls * per_call / ctx.peaks["hbm_bytes_per_s"]
     return 100.0 * least_s / seconds

@@ -1,6 +1,7 @@
 """The program's own names in a run: its device scopes and its recompile
-counts, as the three readers ``done_mask_share.solve``,
-``ghost_refresh_share.solve`` and ``recompiles.solve`` take them.
+counts, as the readers ``done_mask_share.solve``,
+``ghost_refresh_share.solve``, ``recompiles.solve`` and
+``lane_select_share.stream`` take them.
 
 The program (``src/repro/core/spans.py``) puts device scopes
 ``repro.<name>`` in the ``op_name`` metadata of the HLO instructions a
@@ -54,6 +55,21 @@ def solve_recompiles():
     if entry is None or entry.calls < 2:
         return None
     return sum(entry.after_first.values())
+
+
+def entry_op_scopes(entry):
+    """``{instruction name: scope}`` of a jitted entry's executable,
+    rebuilt from ``(jitted function, abstract arguments)`` (a
+    compile-cache hit), or None where there is no entry or the program
+    has no scopes."""
+    try:
+        from repro.core import spans
+    except ImportError:
+        return None
+    if entry is None:
+        return None
+    fn, args = entry
+    return spans.op_scopes(fn.lower(*args).compile())
 
 
 def scope_share(ctx, scope: str):

@@ -1,5 +1,6 @@
 """Tiny copies of the benchmark's cells for CPU rehearsals: the same
-drivers, entries and checks, with Pallas in interpret mode."""
+drivers, entries and checks, with Pallas in interpret mode, at the size
+each configuration file gives under its ``rehearsal`` key."""
 import copy
 
 import jax
@@ -7,14 +8,15 @@ import pytest
 
 from bench import run
 
-SMALL = {
-    "helmholtz-16384": {"grid": [64, 64], "alpha": 1.0, "max_iters": 400},
-}
-
 
 def small_spec(cell: str) -> dict:
+    """The cell at the size its configuration's ``rehearsal`` key gives:
+    numbers that replace the configuration's, and under ``traffic``
+    those that replace the traffic's."""
     spec = copy.deepcopy(run.load_cell(cell))
-    spec["config"].update(SMALL[spec["cell"]["config"]])
+    small = dict(spec["config"]["rehearsal"])
+    spec["traffic"].update(small.pop("traffic", {}))
+    spec["config"].update(small)
     return spec
 
 

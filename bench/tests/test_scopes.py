@@ -240,11 +240,15 @@ def test_scoped_trace_scopes_inside_the_outside_kernel_time(scoped):
 
 
 def test_scoped_trace_program_spans_leave_the_benchmark_reduction(scoped):
-    """The benchmark's own reduction of the same file keeps only its
-    ``bench.*`` spans, and its longest gap is still the host sleep."""
+    """The benchmark's own reduction of the same file keeps its
+    ``bench.*`` spans and the program's ``repro.solve``, and its longest
+    gap is still the host sleep, which no program span covers."""
     tr, _ = scoped
     assert {s[0] for s in tr.spans} == {"bench.window", "bench.solve",
-                                        "bench.host"}
+                                        "bench.host", "repro.solve"}
+    assert sorted(s[1:] for s in tr.spans if s[0] == "repro.solve") == \
+        [s[1:] for s in _host_spans(SCOPED, "repro.")
+         if s[0] == "repro.solve"]
     gaps = T.idle_gaps(tr)
     assert gaps[0][0] == "bench.host"
     assert 0.02 <= gaps[0][1] < 0.05

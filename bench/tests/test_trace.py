@@ -112,6 +112,50 @@ def test_idle_gaps_named_by_innermost_span():
                                ["bench.stream", pytest.approx(10e-9)]]
 
 
+def test_idle_gaps_named_by_program_span_first():
+    """A hole under a program span takes its name, whatever benchmark
+    span is shorter; a hole under none takes the benchmark's."""
+    spans = [("bench.window", 0, 100), ("bench.stream", 0, 100),
+             ("repro.farm.dispatch", 38, 45), ("repro.farm.drain", 40, 62),
+             ("bench.sink", 42, 58), ("repro.farm.stage", 70, 72)]
+    tr = _trace([("a.1", 0, 40), ("b.2", 60, 70), ("c.3", 80, 100)], spans)
+    # [40,60] lies in the drain, not (mostly) in the dispatch; [70,80]
+    # is 20% under the stage span, so it falls back to the benchmark's
+    assert T.idle_gaps(tr) == [["repro.farm.drain", pytest.approx(20e-9)],
+                               ["bench.stream", pytest.approx(10e-9)]]
+
+
+def test_program_spans_and_modules_from_a_profile():
+    """The reader keeps ``repro.*`` host spans beside ``bench.*`` ones,
+    and each device's executable runs by executable name."""
+    from jax.profiler import ProfileData
+
+    text = """
+    planes { id: 1 name: "/device:TPU:0"
+      lines { id: 1 name: "XLA Ops" timestamp_ns: 1000
+        events { metadata_id: 1 offset_ps: 1000 duration_ps: 2000 } }
+      lines { id: 2 name: "XLA Modules" timestamp_ns: 1000
+        events { metadata_id: 2 offset_ps: 0 duration_ps: 4000 } }
+      event_metadata { key: 1 value { id: 1 name: "%sort.1 = f32[8] sort()" } }
+      event_metadata { key: 2 value { id: 2 name: "jit__stage_impl(42)" } } }
+    planes { id: 3 name: "/host:CPU"
+      lines { id: 7 name: "python" timestamp_ns: 1000
+        events { metadata_id: 1 offset_ps: 0 duration_ps: 10000 }
+        events { metadata_id: 2 offset_ps: 3000 duration_ps: 5000 }
+        events { metadata_id: 3 offset_ps: 3000 duration_ps: 1000 } }
+      event_metadata { key: 1 value { id: 1 name: "bench.window" } }
+      event_metadata { key: 2 value { id: 2 name: "repro.farm.drain" } }
+      event_metadata { key: 3 value { id: 3 name: "other" } } }
+    """
+    tr = T.from_profile(ProfileData.from_text_proto(text))
+    assert [s[0] for s in tr.spans] == ["bench.window", "repro.farm.drain"]
+    assert tr.modules == {"/device:TPU:0": [("jit__stage_impl", 1000,
+                                             1004)]}
+    assert T.module_ops_s(tr, "jit__stage_impl") == pytest.approx(2e-9)
+    # the hole [1003, 1010] ns lies 5/7 under the drain
+    assert T.idle_gaps(tr)[0] == ["repro.farm.drain", pytest.approx(7e-9)]
+
+
 def test_text_proto_round_trip():
     """The reader of the profiler's own format: planes, the ops line,
     host spans, and a collective that nothing hides."""

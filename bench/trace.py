@@ -5,9 +5,12 @@ is named ``/device:TPU:<n>``; its ``XLA Ops`` line holds one event per HLO
 operation the chip ran, named by the operation's HLO text
 (``%stencil2d_fused_framed.8 = (...) custom-call(...)``).  A ``while``
 appears there as an event that encloses the events of its body, so only
-the innermost events (leaves) are counted per operation.  The host plane
+the innermost events (leaves) are counted per operation.  Its
+``XLA Modules`` line holds one event per run of an executable, named by
+the executable (``jit__stage_impl(<fingerprint>)``).  The host plane
 ``/host:CPU`` holds the benchmark's own spans, ``bench.<name>``
-(:func:`bench.common.span`); ``bench.window`` marks the measured window.
+(:func:`bench.common.span`), and the program's, ``repro.<name>``;
+``bench.window`` marks the measured window.
 
 Everything below works on plain tuples, so the tests can feed recorded
 and hand-made traces alike:
@@ -15,13 +18,16 @@ and hand-made traces alike:
 * device busy time: the union of a device's op intervals in the window;
 * time per named kernel: the leaf events whose operation name holds the
   kernel's name (the lane-batched kernel is ``vmap_<name>_``);
+* time per executable: the leaf events inside the runs of the
+  executables of one name;
 * exposed collective time: collectives (collective-permute, all-reduce,
   ...) during which no other operation runs on that device.  A
   synchronous collective is an op of ``XLA Ops``; an asynchronous one
   spans its start and done on the ``Async XLA Ops`` line, in flight
   beside the compute it overlaps;
 * idle gaps: the holes of the busy union, each named by the innermost
-  benchmark span open over most of it.
+  program span open over most of it, or else by the innermost benchmark
+  span.
 """
 from __future__ import annotations
 
@@ -33,8 +39,10 @@ import os
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 ASYNC_LINE = "Async XLA Ops"
+MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "repro."
 WINDOW_SPAN = "bench.window"
 COLLECTIVES = ("collective-permute", "all-reduce", "all-gather",
                "reduce-scatter", "all-to-all", "send", "recv")
@@ -43,12 +51,14 @@ COLLECTIVES = ("collective-permute", "all-reduce", "all-gather",
 @dataclasses.dataclass
 class Trace:
     """A reduced trace: per device the leaf ops ``(name, start, end)`` in
-    ns and the asynchronous collectives in flight, the benchmark's host
+    ns, the asynchronous collectives in flight and the executables' runs
+    ``(executable, start, end)``, the benchmark's and the program's host
     spans, and the window ``(start, end)``."""
     devices: dict
     spans: list
     window: tuple
     async_collectives: dict = dataclasses.field(default_factory=dict)
+    modules: dict = dataclasses.field(default_factory=dict)
 
     @property
     def window_s(self) -> float:
@@ -79,13 +89,18 @@ def leaves(events):
     return out
 
 
+def module_name(event_name: str) -> str:
+    """``jit__stage_impl(8817...)`` -> ``jit__stage_impl``."""
+    return event_name.split("(", 1)[0]
+
+
 def from_events(devices: dict, spans: list, window=None,
-                async_ops=None) -> Trace:
+                async_ops=None, modules=None) -> Trace:
     """Build a :class:`Trace` from raw ``(name, start_ns, end_ns)`` events
-    per device (and per device the ``Async XLA Ops`` events) and host
-    spans; ops are reduced to leaves and clipped to the window (by
-    default the ``bench.window`` span, else the span of all device
-    events)."""
+    per device (and per device the ``Async XLA Ops`` and ``XLA Modules``
+    events) and host spans; ops are reduced to leaves and clipped to the
+    window (by default the ``bench.window`` span, else the span of all
+    device events)."""
     if window is None:
         win = [s for s in spans if s[0] == WINDOW_SPAN]
         if win:
@@ -103,7 +118,10 @@ def from_events(devices: dict, spans: list, window=None,
                  spans=list(spans), window=(lo, hi),
                  async_collectives={
                      d: clip([e for e in evs if is_collective(e[0])])
-                     for d, evs in (async_ops or {}).items()})
+                     for d, evs in (async_ops or {}).items()},
+                 modules={d: clip([(module_name(n), s, e)
+                                   for n, s, e in evs])
+                          for d, evs in (modules or {}).items()})
 
 
 def load(path: str) -> Trace:
@@ -121,7 +139,7 @@ def load(path: str) -> Trace:
 
 def from_profile(data) -> Trace:
     """Reduce a ``jax.profiler.ProfileData``."""
-    devices, async_ops, spans = {}, {}, []
+    devices, async_ops, modules, spans = {}, {}, {}, []
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PREFIX):
             rest = plane.name[len(DEVICE_PREFIX):]
@@ -130,17 +148,20 @@ def from_profile(data) -> Trace:
             lines = {line.name: [(op_name(e.name), e.start_ns, e.end_ns)
                                  for e in line.events]
                      for line in plane.lines
-                     if line.name in (OPS_LINE, ASYNC_LINE)}
+                     if line.name in (OPS_LINE, ASYNC_LINE, MODULES_LINE)}
             devices[plane.name] = lines.get(OPS_LINE, [])
             async_ops[plane.name] = lines.get(ASYNC_LINE, [])
+            modules[plane.name] = lines.get(MODULES_LINE, [])
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
                 spans.extend((e.name, e.start_ns, e.end_ns)
                              for e in line.events
-                             if e.name.startswith(SPAN_PREFIX))
+                             if e.name.startswith((SPAN_PREFIX,
+                                                   PROGRAM_PREFIX)))
     if not devices:
         raise ValueError(f"no {DEVICE_PREFIX}<n> plane in the trace")
-    return from_events(devices, spans, async_ops=async_ops)
+    return from_events(devices, spans, async_ops=async_ops,
+                       modules=modules)
 
 
 def union(intervals):
@@ -177,6 +198,11 @@ def subtract(a, b):
     return out
 
 
+def intersect(a, b):
+    """Disjoint sorted intervals ``a`` within disjoint sorted ``b``."""
+    return subtract(a, subtract(a, b))
+
+
 def busy_s(tr: Trace) -> float:
     """Seconds in which an operation ran, averaged over the devices."""
     return sum(length(union((s, e) for _, s, e in evs))
@@ -196,6 +222,20 @@ def kernel_time(tr: Trace, kernel: str):
                 calls += 1
                 ns += e - s
     return calls, ns * 1e-9
+
+
+def module_ops_s(tr: Trace, module: str, ops=None) -> float:
+    """Seconds in which a leaf op ran inside a run of the executable
+    ``module`` (``jit__stage_impl``), averaged over the devices; only
+    the ops whose names ``ops`` holds, where it is given."""
+    total = 0.0
+    for dev, evs in tr.devices.items():
+        runs = union((s, e) for n, s, e in tr.modules.get(dev, [])
+                     if n == module)
+        busy = union((s, e) for n, s, e in evs
+                     if ops is None or n in ops)
+        total += length(intersect(busy, runs))
+    return total * 1e-9 / len(tr.devices)
 
 
 def is_collective(name: str) -> bool:
@@ -231,8 +271,9 @@ def exposed_collective_s(tr: Trace) -> float:
 
 def idle_gaps(tr: Trace, top: int = 10):
     """The ``top`` longest holes of the busy union inside the window, over
-    all devices, each as ``[span, seconds]``: the innermost benchmark span
-    (other than the window) that covers most of the hole, or ``"none"``."""
+    all devices, each as ``[span, seconds]``: of the spans that cover
+    most of the hole, the innermost program span (``repro.*``), else the
+    innermost benchmark span other than the window, else ``"none"``."""
     gaps = []
     lo, hi = tr.window
     for evs in tr.devices.values():
@@ -242,10 +283,12 @@ def idle_gaps(tr: Trace, top: int = 10):
     inner = [sp for sp in tr.spans if sp[0] != WINDOW_SPAN]
     out = []
     for s, e in gaps[:top]:
-        # of the spans open over most of the hole, the shortest
-        cover = [(se - ss, name) for name, ss, se in inner
+        # of the spans open over most of the hole, the shortest, the
+        # program's before the benchmark's
+        cover = [(not name.startswith(PROGRAM_PREFIX), se - ss, name)
+                 for name, ss, se in inner
                  if min(e, se) - max(s, ss) > 0.5 * (e - s)]
-        out.append([min(cover)[1] if cover else "none", (e - s) * 1e-9])
+        out.append([min(cover)[2] if cover else "none", (e - s) * 1e-9])
     return out
 
 
