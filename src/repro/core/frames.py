@@ -456,6 +456,7 @@ def _refresh_axis_local(frame, spec: FrameSpec, axis: int,
     raise ValueError(boundary)
 
 
+@scope("halo_exchange")
 def _refresh_axis_sharded(frame, sspec: ShardedFrameSpec, axis: int,
                           boundary: Boundary, olo: int, ohi: int):
     """ppermute one axis's ghost strips from the mesh neighbours.
@@ -464,7 +465,8 @@ def _refresh_axis_sharded(frame, sspec: ShardedFrameSpec, axis: int,
     ghost strip and vice versa — O(pad·width) cells on the wire, written
     straight into the ring.  Global-edge shards fill the missing side
     from the ⊥ model (constants / local mirror); WRAP closes the ring so
-    the permutation is total.
+    the permutation is total.  All of it, strips, ppermutes and writes,
+    is the device scope ``repro.halo_exchange``.
     """
     spec = sspec.local
     name = sspec.axis_names[axis]

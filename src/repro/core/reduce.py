@@ -17,6 +17,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from .spans import scope
+
 # Named monoids usable across the codebase (op, identity).
 MONOIDS = {
     "sum": (operator.add, 0.0),
@@ -37,6 +39,7 @@ def resolve_monoid(op, identity):
     return op, identity
 
 
+@scope("global_reduce")
 def collective_combine(op: Callable, r: jnp.ndarray,
                        axis_names) -> jnp.ndarray:
     """Monoid-aware global combine of per-shard partials over mesh axes.
@@ -48,7 +51,9 @@ def collective_combine(op: Callable, r: jnp.ndarray,
     collective (``psum``/``pmax``/``pmin``); ``any``/``all`` go through a
     psum of indicator counts; other associative ops must be psum-compatible
     (i.e. ``op`` must *be* addition-like) — there is no generic
-    all-reduce for arbitrary combinators on the mesh.
+    all-reduce for arbitrary combinators on the mesh.  The collectives
+    and the NaN re-propagation are the device scope
+    ``repro.global_reduce``.
     """
     from jax import lax
     for name in axis_names:

@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "..", "src")
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 
 
 def run_multidevice(code: str, devices: int = 8) -> str:
@@ -263,6 +264,59 @@ class TestShardedZeroCopy:
             print("OKZC")
         """))
         assert "OKZC" in out
+
+
+# the converged 1:n solve at 128² over a 2×2 mesh against the plain
+# reference of the benchmark (bench/reference.py, jax.numpy only); FAULT
+# swaps each exchanged axis's ppermute for the local zero fill
+AGAINST_REFERENCE = """
+import sys
+import numpy as np, jax.numpy as jnp
+sys.path.insert(0, ROOT)
+from bench.reference import helmholtz_solve
+from repro.core import GridPartition, frames
+from repro.core.semantics import Boundary
+from repro.kernels import ops
+from repro.sharding.specs import make_mesh
+
+if FAULT:
+    def local_fill(frame, sspec, axis, boundary, olo, ohi):
+        return frames._refresh_axis_local(frame, sspec.local, axis,
+                                          Boundary.ZERO, olo, ohi)
+    frames._refresh_axis_sharded = local_fill
+
+part = GridPartition(mesh=make_mesh((2, 2), ("rows", "cols")),
+                     axis_names=("rows", "cols"), array_axes=(0, 1))
+kw = dict(alpha=0.1, dx=1.0, max_iters=2000)
+tol = np.float32(1e-5)
+for seed in (0, 1):
+    f = jnp.asarray(np.random.default_rng(seed).normal(size=(128, 128)),
+                    jnp.float32)
+    u, d, it = ops.jacobi_solve(jnp.zeros_like(f), f, tol=tol, part=part,
+                                backend="pallas-sharded", unroll=1, **kw)
+    ur, dr, ir = helmholtz_solve(f, tol, **kw)
+    err = float(jnp.max(jnp.abs(u - ur)) / jnp.max(jnp.abs(ur)))
+    agree = (err <= 1e-4 and abs(int(it) - int(ir)) <= 1
+             and abs(float(d) - float(dr)) <= tol)
+    print("AGREE" if agree else "DISAGREE", seed, err, int(it), int(ir),
+          float(d), float(dr))
+"""
+
+
+@pytest.mark.parametrize("fault", [False, True], ids=["exchange",
+                                                      "local_fill"])
+def test_converged_2x2_solve_against_the_reference(fault):
+    """A converged ``jacobi_solve(part=2×2)`` on seeded N(0, 1) forcings
+    agrees with the plain reference: u within 1e-4 of max|u_ref|, the
+    iteration counts within one check, the last change within one tol.
+    With the exchange replaced by the local zero fill it must not."""
+    code = (f"ROOT = {ROOT!r}\nFAULT = {fault}\n"
+            + textwrap.dedent(AGAINST_REFERENCE))
+    lines = [ln for ln in run_multidevice(code, devices=4).splitlines()
+             if ln.startswith(("AGREE", "DISAGREE"))]
+    assert len(lines) == 2, lines
+    want = "DISAGREE" if fault else "AGREE"
+    assert all(ln.split()[0] == want for ln in lines), lines
 
 
 class TestShardedValidation:

@@ -33,6 +33,71 @@ def test_op_scopes_name_done_mask_and_ghost_refresh(backend, unroll):
     assert set(scopes) <= names
 
 
+@pytest.mark.parametrize("backend,unroll", [("pallas", 1),
+                                            ("pallas-multistep", 2)])
+def test_one_device_scope_set_is_unchanged(backend, unroll):
+    """A solve on one chip calls no collective: its executable names the
+    done-mask and the ghost refresh, and neither mesh scope."""
+    u0, f = _fields()
+    compiled = ops.jacobi_solve.lower(
+        u0, f, alpha=1.0, dx=1.0, tol=np.float32(1e-5), max_iters=40,
+        backend=backend, unroll=unroll).compile()
+    assert set(spans.op_scopes(compiled).values()) == {
+        "repro.done_mask", "repro.ghost_refresh"}
+
+
+SHARDED_SCOPES = """
+import json, re
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding
+from repro.core import GridPartition, spans
+from repro.kernels import ops
+from repro.sharding.specs import make_mesh
+
+part = GridPartition(mesh=make_mesh((2, 2), ("rows", "cols")),
+                     axis_names=("rows", "cols"), array_axes=(0, 1))
+x = jax.ShapeDtypeStruct((128, 128), jnp.float32,
+                         sharding=NamedSharding(part.mesh, part.pspec))
+compiled = ops.jacobi_solve.lower(
+    x, x, alpha=0.1, dx=1.0, tol=np.float32(1e-5), max_iters=2000,
+    backend="pallas-sharded", unroll=1, part=part).compile()
+scopes = spans.op_scopes(compiled)
+ops_ = {}
+for line in compiled.as_text().splitlines():
+    name = spans._INSTR.match(line)
+    kind = re.search(r"\\s(collective-permute|all-reduce)(-start|-done)?\\(",
+                     line)
+    if name and kind:
+        ops_[name.group(1)] = kind.group(1)
+print(json.dumps({"scopes": scopes, "ops": ops_}))
+"""
+
+
+def test_sharded_solve_scopes_its_collectives():
+    """On a 2×2 mesh of CPU devices every collective-permute of the
+    solve's executable is the halo exchange, and every all-reduce the
+    mesh-wide reduce."""
+    import json
+    import subprocess
+    import sys
+
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "..", "src"))
+    out = subprocess.run([sys.executable, "-c", SHARDED_SCOPES], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    scopes, kinds = got["scopes"], got["ops"]
+    permutes = [n for n, k in kinds.items() if k == "collective-permute"]
+    reduces = [n for n, k in kinds.items() if k == "all-reduce"]
+    assert permutes and reduces
+    assert {scopes.get(n) for n in permutes} == {"repro.halo_exchange"}
+    assert {scopes.get(n) for n in reduces} == {"repro.global_reduce"}
+
+
 def test_op_scopes_fusion_takes_its_root_scope():
     text = """HloModule m
 

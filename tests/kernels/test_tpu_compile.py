@@ -4,8 +4,9 @@ Interpret mode (every other kernel test) cannot see what Mosaic refuses:
 scalar stores to VMEM, DMA windows or offsets off the (8, 128) tiling,
 primitives without a TPU lowering, the generic Pallas batching rule on
 HBM operands.  These tests hand the installed TPU compiler the kernels at
-real widths for one chip of a described ``v5e:2x2`` topology — nothing
-runs — and check that each program holds the kernel (``tpu_custom_call``).
+real widths for one chip of a described ``v5e:2x2`` topology, and the
+1:n solve for all four — nothing runs — and check that each program holds
+the kernel (``tpu_custom_call``).
 
 The topology is described inside a fixture, never at import: one process
 at a time may load the TPU library, and every worker imports this file.
@@ -14,9 +15,11 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
+from repro.core import GridPartition
 from repro.core.executor import StencilEngine
 from repro.core.frames import frame_spec
 from repro.core.pattern import LoopOfStencilReduce
@@ -178,3 +181,36 @@ def test_solve_body_4096_swaps_frames(backend, unroll, one_chip):
                  if re.match(r"(?:ROOT )?%\S+ = " + re.escape(frame)
                              + r"\S* (select|copy|copy-start)\(", line)]
     assert not frame_ops, frame_ops
+
+
+def test_sharded_solve_32768_on_2x2(topo):
+    """The 1:n solve of the benchmark's four-chip cell, 32768² split 2×2
+    by rows and columns: each chip's loop holds two kernel calls that
+    swap its 16384² shard's frames, with no frame-sized select or copy,
+    exchanges halos by collective-permutes, combines the reduce by
+    all-reduces, and fits the chip's 16 GB."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("rows", "cols"))
+    part = GridPartition(mesh=mesh, axis_names=("rows", "cols"),
+                         array_axes=(0, 1))
+    loop = LoopOfStencilReduce(
+        f=helmholtz(), k=1, combine="max", cond=lambda r: r < 1e-5,
+        delta=R.abs_delta, boundary="zero", max_iters=2000,
+        backend="pallas-sharded", partition=part, interpret=False)
+    grid = jax.ShapeDtypeStruct(
+        (32768, 32768), jnp.float32,
+        sharding=NamedSharding(part.mesh, part.pspec))
+    compiled = jax.jit(lambda u, f: loop.run(u, env=(f,)).a).lower(
+        grid, grid).compile()
+    text = compiled.as_text()
+    frame = "f32[{},{}]".format(*frame_spec(16384, 16384).shape)
+    body = while_body_lines(text)
+    assert len([ln for ln in body if "tpu_custom_call" in ln]) == 2
+    assert not [ln for ln in body
+                if re.match(r"(?:ROOT )?%\S+ = " + re.escape(frame)
+                            + r"\S* (select|copy|copy-start)\(", ln)]
+    assert any("collective-permute" in ln for ln in body)
+    assert any("all-reduce" in ln for ln in body)
+    mem = compiled.memory_analysis()
+    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+    assert per_chip < 16e9, per_chip
