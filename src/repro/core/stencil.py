@@ -5,7 +5,7 @@ Two execution strategies, both extensionally equal to
 
 * :func:`stencil_windows` — materialise the window tensor (general; memory
   cost ×(2k+1)^n).  Used for elemental functions that need the whole window
-  (e.g. the adaptive median filter's sort).
+  (e.g. a sort over the window).
 * :func:`stencil_taps` — the elemental function receives a *tap accessor*
   ``get(*offsets)`` returning the array shifted by the given offsets.  XLA
   fuses the shifts; nothing is materialised.  This is the fast path used by

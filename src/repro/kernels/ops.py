@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.core import spans
 from repro.core.executor import sweep_once
 from repro.core.pattern import LoopOfStencilReduce
+from repro.core.stencil import stencil_taps
 
 from . import ref as R
 
@@ -126,12 +127,20 @@ def adaptive_median_detect(frame, *, kmax=3, use_pallas=False, backend=None):
     """Detection phase (§4.3): classic adaptive median filter with window
     escalation 3×3→5×5→7×7.  Returns (noise_mask, repaired_frame) where the
     repaired frame replaces flagged pixels by the AMF median — the
-    restoration phase's initial guess."""
+    restoration phase's initial guess.
+
+    The jnp path evaluates the filter once per pixel for both planes; the
+    Pallas kernels emit one plane a sweep, so that path sweeps twice."""
     be = _resolve_backend(use_pallas, backend)
-    f_mask, f_repl = R.amf_detect_taps(kmax)
-    mask, frac = sweep_once(frame, f_mask, k=kmax, combine="sum",
-                            identity=0.0, boundary="reflect", backend=be)
-    repl, _ = sweep_once(frame, f_repl, k=kmax, combine="sum",
-                         identity=0.0, boundary="reflect", backend=be)
+    f = R.amf_detect_taps(kmax)
+    if be == "jnp":
+        mask, repl = stencil_taps(f, frame, kmax, "reflect")
+    else:
+        mask, _ = sweep_once(frame, lambda get: f(get)[0], k=kmax,
+                             combine="sum", identity=0.0,
+                             boundary="reflect", backend=be)
+        repl, _ = sweep_once(frame, lambda get: f(get)[1], k=kmax,
+                             combine="sum", identity=0.0,
+                             boundary="reflect", backend=be)
     repaired = jnp.where(mask > 0, repl, frame)
     return mask, repaired

@@ -6,6 +6,7 @@ tests close the loop: Pallas kernel ≡ core stencil ≡ paper semantics.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -75,11 +76,62 @@ def gol_taps():
     return f
 
 
+@functools.lru_cache(maxsize=None)
+def _selection_network(n: int, ranks: tuple) -> tuple:
+    """Comparators ``(i, j, keep_min, keep_max)`` that leave the ``ranks``
+    of ``n`` keys in place, ``i < j``: Batcher's odd–even merge sort on the
+    next power of two, with every comparator that touches a padding index
+    dropped (padding would hold +inf and sit above every key, so those
+    comparators move nothing), pruned backwards to the comparators some
+    output depends on.  ``keep_min`` / ``keep_max`` say which of a kept
+    comparator's two results is read later."""
+    p = 1 << (n - 1).bit_length()
+    full = []
+    size = 1
+    while size < p:
+        k = size
+        while k >= 1:
+            for j in range(k % size, p - k, 2 * k):
+                for i in range(min(k, p - j - k)):
+                    a, b = i + j, i + j + k
+                    if a // (2 * size) == b // (2 * size) and b < n:
+                        full.append((a, b))
+            k //= 2
+        size *= 2
+    need, kept = set(ranks), []
+    for a, b in reversed(full):
+        if a in need or b in need:
+            kept.append((a, b, a in need, b in need))
+            need |= {a, b}
+    return tuple(reversed(kept))
+
+
+def select_ranks(values, ranks):
+    """The elements at ``ranks`` of the elementwise-sorted ``values`` (a
+    sequence of equal-shape arrays): a comparator network of
+    ``jnp.minimum`` / ``jnp.maximum``, built at trace time from
+    ``len(values)`` and ``ranks`` alone — no stack, no sort.
+
+    A sorting network's output at a rank is that rank's element, so for
+    inputs without NaN each result is bitwise the element ``jnp.sort``
+    puts there, except that the sign of a zero may differ where +0.0 and
+    -0.0 tie.  NaN propagates through min/max instead of sorting last.
+    """
+    v = list(values)
+    for a, b, keep_min, keep_max in _selection_network(len(v), tuple(ranks)):
+        x, y = v[a], v[b]
+        if keep_min:
+            v[a] = jnp.minimum(x, y)
+        if keep_max:
+            v[b] = jnp.maximum(x, y)
+    return [v[r] for r in ranks]
+
+
 def median3_taps():
     """3×3 median (detection phase of the video-restoration app, §4.3)."""
     def f(get, *_):
-        w = jnp.stack([get(di, dj) for di in (-1, 0, 1) for dj in (-1, 0, 1)])
-        return jnp.sort(w, axis=0)[4]
+        w = [get(di, dj) for di in (-1, 0, 1) for dj in (-1, 0, 1)]
+        return select_ranks(w, (4,))[0]
     return f
 
 
@@ -92,21 +144,24 @@ def amf_detect_taps(kmax: int = 3):
     made there — the pixel is noise iff it equals a window extreme;
     otherwise the window grows.  Pixels undecided at kmax are flagged.
 
-    Returns a taps function emitting ``select`` of the decision:
-    ``what='mask'`` → 1.0 where noise, ``what='repl'`` → median replacement.
-    (Two planes, two sweeps; the detection runs once per frame.)
+    Each level's min, median (rank ``n // 2`` of the ``n`` taps) and max
+    come from one selection network (:func:`select_ranks`), sort-free.
+    For inputs without NaN the decisions and the median are bitwise those
+    of a full sort of the window (up to a zero's sign where ±0 tie).
+
+    Returns a taps function emitting both planes from one evaluation:
+    ``(noise, repl)``, 1.0 where noise and the median replacement.
     """
-    def core(get):
+    def f(get, *_):
         x = get(0, 0)
         decided = jnp.zeros_like(x, dtype=bool)
         noise = jnp.zeros_like(x, dtype=bool)
         repl = x
         for k in range(1, kmax + 1):
-            w = jnp.stack([get(di, dj)
-                           for di in range(-k, k + 1)
-                           for dj in range(-k, k + 1)])
-            srt = jnp.sort(w, axis=0)
-            mn, med, mx = srt[0], srt[w.shape[0] // 2], srt[-1]
+            w = [get(di, dj)
+                 for di in range(-k, k + 1)
+                 for dj in range(-k, k + 1)]
+            mn, med, mx = select_ranks(w, (0, len(w) // 2, len(w) - 1))
             level_a = (med > mn) & (med < mx)
             is_noise_here = ~((x > mn) & (x < mx))
             newly = level_a & ~decided
@@ -116,13 +171,7 @@ def amf_detect_taps(kmax: int = 3):
         noise = jnp.where(decided, noise, True)
         repl = jnp.where(~decided, med, repl)  # last-level median fallback
         return noise.astype(x.dtype), repl
-
-    def f_mask(get, *_):
-        return core(get)[0]
-
-    def f_repl(get, *_):
-        return core(get)[1]
-    return f_mask, f_repl
+    return f
 
 
 def restore_taps(beta: float = 2.0):

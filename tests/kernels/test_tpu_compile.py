@@ -23,6 +23,7 @@ from repro.core import GridPartition
 from repro.core.executor import StencilEngine
 from repro.core.frames import frame_spec
 from repro.core.pattern import LoopOfStencilReduce
+from repro.kernels import ops
 from repro.kernels import ref as R
 from repro.kernels.multistep import stencil2d_multistep_framed
 from repro.kernels.stencil2d import stencil2d_fused_framed
@@ -113,6 +114,22 @@ def test_lane_sweep_720p(backend, one_chip):
         lambda fr, *e: eng.sweeps_lanes(fr, e, lspec),
         frames, *[env] * n_env)
     assert "tpu_custom_call" in text
+
+
+def test_amf_prep_720p_sorts_nothing(one_chip):
+    """The stream farm's prep, adaptive median detection of one 720x1280
+    frame on the jnp path: the compiled program holds no sort, and its
+    temporaries stay under 128 MiB (a sort of the stacked windows took
+    547,547,136 B; a network that kept a frame per comparator in HBM
+    would take gigabytes)."""
+    frame = jax.ShapeDtypeStruct((720, 1280), jnp.float32,
+                                 sharding=one_chip)
+    compiled = jax.jit(
+        lambda fr: ops.adaptive_median_detect(fr, kmax=3)).lower(
+            frame).compile()
+    assert not re.search(r" sort\(", compiled.as_text())
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 128 * 2**20, temp
 
 
 def test_swa_attention_hd128(one_chip):
