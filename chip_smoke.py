@@ -218,7 +218,7 @@ def stream_farm(phase: str, lanes: int, mesh=None):
     for _ in range(2):       # cold stream (compiles), then a warm one
         outs = []
         t0 = time.perf_counter()
-        n = eng.run(items, outs.append, continuous=True)
+        n = eng.run(items, outs.append)
         walls.append(time.perf_counter() - t0)
         assert n == RESTORE_ITEMS, n
         idx = sorted(r.index for r in outs)

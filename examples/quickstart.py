@@ -68,18 +68,21 @@ def main():
     # backend="pallas" is the point: frames are built once per lane slot
     # and REFILLED in place with each next item — no re-pad, no re-alloc,
     # no host round-trip of the frame (interpret-mode kernels on CPU, so
-    # the demo uses a smaller grid + tolerance to stay quick)
+    # the demo uses a smaller grid + tolerance to stay quick); a finished
+    # lane takes the next item while the other lane runs on
     v0 = u0[:48, :48]
     streamer = LoopOfStencilReduce(
         f=jacobi, k=1, combine="max", identity=-jnp.inf,
         cond=lambda d: d < 1e-2, delta=lambda n, o: jnp.abs(n - o),
         max_iters=600, backend="pallas", block=(48, 128))
+    # and results arrive in completion order, each carrying its index
     eng = FarmEngine(streamer, lanes=2)
-    iters = []
-    n = eng.run([v0 * s for s in (1.0, 5.0, 0.1, 2.0, 0.5)],
-                lambda res: iters.append(int(res.iters)))
+    done = []
+    n = eng.run([v0 * s for s in (1.0, 5.0, 0.1, 2.0, 0.5)], done.append)
+    iters = [int(r.iters) for r in sorted(done, key=lambda r: r.index)]
     print(f"[stream]  {n} items through 2 persistent lane slots "
-          f"({eng.stats['rounds']} rounds, backend=pallas); "
+          f"({eng.stats['segments']} segments, backend=pallas); "
+          f"emission order: {[r.index for r in done]}; "
           f"trip counts: {iters}")
 
 

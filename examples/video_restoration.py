@@ -6,8 +6,8 @@ pipe(read, detect, ofarm(restore), write): adaptive-median detection
 FarmEngine: the detection pass is the per-item ``prep`` stage, the
 restoration loop runs in persistent lane slots that are refilled in
 place with each next frame (device buffers persist across stream items,
-as in the paper's FastFlow realisation), and host-side double buffering
-overlaps read/write with device compute.
+as in the paper's FastFlow realisation) the moment their lane finishes,
+and host-side staging overlaps read/write with device compute.
 
     PYTHONPATH=src python examples/video_restoration.py \
         [--frames 8] [--noise 0.3] [--res vga] [--lanes 2]
@@ -80,13 +80,14 @@ def main():
     t0 = time.perf_counter()
     n = eng.run(noisys, done.append)
     dt = time.perf_counter() - t0
+    done.sort(key=lambda r: r.index)        # emitted in completion order
 
     ps_in = np.mean([psnr(noisys[i], cleans[i]) for i in range(n)])
     ps_out = np.mean([psnr(done[i].a, cleans[i]) for i in range(n)])
     its = [int(done[i].iters) for i in range(n)]
     print(f"restored {n} {args.res} frames @ {args.noise:.0%} noise in "
-          f"{dt:.2f}s ({n / dt:.2f} fps; {eng.stats['rounds']} rounds "
-          f"through {args.lanes} lane slots)")
+          f"{dt:.2f}s ({n / dt:.2f} fps; {eng.stats['segments']} "
+          f"segments through {args.lanes} lane slots)")
     print(f"host transfer: {eng.stats['h2d_bytes'] / max(n, 1):.0f} B/item"
           f" in, {eng.stats['d2h_bytes'] / max(n, 1):.0f} B/item out")
     print(f"PSNR {ps_in:.1f} -> {ps_out:.1f} dB; iterations/frame: {its}")

@@ -6,9 +6,9 @@ Public API:
     reduce      — /(⊕) tree reduce + two-phase reduce
     pattern     — LoopOfStencilReduce + -i/-d/-s variants (lax.while_loop)
     halo        — multi-device 1:n mode (shard_map + ppermute halo swap)
-    streaming   — pipe / farm / ofarm stream tier + the lane-resident
-                  FarmEngine (persistent-frame farms, device-side slot
-                  refill, host-side double buffering)
+    streaming   — FarmEngine, the lane-resident streaming farm
+                  (persistent-frame lane slots refilled in place as
+                  their loops finish, results in completion order)
 """
 from .semantics import Boundary
 from .stencil import TapAccessor, stencil_taps, stencil_windows, conv_taps
@@ -18,8 +18,7 @@ from .pattern import (LoopOfStencilReduce, LoopResult, loop_of_stencil_reduce,
                       loop_of_stencil_reduce_d, loop_of_stencil_reduce_s)
 from .halo import (GridPartition, exchange_halo,
                    distributed_loop_of_stencil_reduce)
-from .streaming import (pipe, farm, ofarm, sharded_farm, StreamRunner,
-                        FarmEngine, StreamResult, NonFiniteItemError,
+from .streaming import (FarmEngine, StreamResult, NonFiniteItemError,
                         item_status)
 
 __all__ = [
@@ -28,7 +27,6 @@ __all__ = [
     "MONOIDS", "LoopOfStencilReduce", "LoopResult",
     "loop_of_stencil_reduce", "loop_of_stencil_reduce_d",
     "loop_of_stencil_reduce_s", "GridPartition", "exchange_halo",
-    "distributed_loop_of_stencil_reduce", "pipe", "farm", "ofarm",
-    "sharded_farm", "StreamRunner", "FarmEngine", "StreamResult",
+    "distributed_loop_of_stencil_reduce", "FarmEngine", "StreamResult",
     "Sentinel", "health_status", "NonFiniteItemError", "item_status",
 ]

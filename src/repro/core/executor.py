@@ -51,8 +51,6 @@ import jax.numpy as jnp
 from .frames import (FrameSpec, LaneFrameSpec, ShardedFrameSpec, ceil_mul,
                      frame_spec, make_frame, frame_env, frame_env_sharded,
                      lane_env_frames, make_frame_sharded, make_lane_frames,
-                     refill_lane_env, refill_lane_env_sharded,
-                     refill_lane_frames, refill_lane_frames_sharded,
                      refresh_frame, refresh_frame_sharded,
                      shard_domain_bounds, sharded_frame_spec, unframe,
                      unframe_lanes)
@@ -257,8 +255,7 @@ class StencilEngine:
         return LaneFrameSpec(lanes=lanes, frame=spec)
 
     def prepare_lanes(self, a: jnp.ndarray, env=()):
-        """Stage a (lanes, m, n) stack into lane frames — one-shot entry
-        (:meth:`refill_lanes` is the streaming path that reuses slots)."""
+        """Stage a (lanes, m, n) stack into lane frames (one-shot)."""
         lanes, m, n = a.shape
         lspec = self.lane_spec(lanes, m, n)
         frames = make_lane_frames(a, lspec.frame, self.boundary)
@@ -266,19 +263,6 @@ class StencilEngine:
             lane_env_frames(e, lspec.frame, self.boundary,
                             halo=self._halo_env) for e in env)
         return frames, env_frames, lspec
-
-    def refill_lanes(self, frames, env_frames, interiors, env_new,
-                     lspec: LaneFrameSpec):
-        """Refill the lane slots in place with the next stream items —
-        O(interior) writes + O(m+n) ghost refresh per lane; no pad, no
-        re-framing, no new allocation (donate the buffers under jit)."""
-        frames = refill_lane_frames(frames, interiors, lspec.frame,
-                                    self.boundary)
-        env_frames = tuple(
-            refill_lane_env(ef, e, lspec.frame, self.boundary,
-                            halo=self._halo_env)
-            for ef, e in zip(env_frames, env_new))
-        return frames, env_frames
 
     def sweeps_lanes(self, frames, env_frames, lspec: LaneFrameSpec):
         """``unroll`` sweeps on every lane; returns (frames', (lanes,) r).
@@ -395,29 +379,6 @@ class ShardedStencilEngine:
             lm, ln, self.part, k=self.k, block=self.block,
             sweeps=self.unroll if self._multistep else 1)
 
-    def prepare_lanes(self, a_local: jnp.ndarray, env_local=()):
-        """Stage this shard's (lanes, lm, ln) stack into lane frames."""
-        _, lm, ln = a_local.shape
-        sspec = self.lane_sspec(lm, ln)
-        frames = jax.vmap(
-            lambda b: make_frame_sharded(b, sspec, self.boundary))(a_local)
-        env_frames = tuple(
-            jax.vmap(lambda e: frame_env_sharded(
-                e, sspec, self.boundary, halo=self._multistep))(e)
-            for e in env_local)
-        return frames, env_frames, sspec
-
-    def refill_lanes(self, frames, env_frames, interiors, env_new,
-                     sspec: ShardedFrameSpec):
-        """In-place lane-slot refill with this shard's next local blocks."""
-        frames = refill_lane_frames_sharded(frames, interiors, sspec,
-                                            self.boundary)
-        env_frames = tuple(
-            refill_lane_env_sharded(ef, e, sspec, self.boundary,
-                                    halo=self._multistep)
-            for ef, e in zip(env_frames, env_new))
-        return frames, env_frames
-
     def sweeps_lanes(self, frames, env_frames, sspec: ShardedFrameSpec):
         """``unroll`` sweeps + ONE lane-batched ghost exchange + the
         global combine; returns (frames', (local_lanes,) r).  The combine
@@ -428,10 +389,6 @@ class ShardedStencilEngine:
         return jax.vmap(
             lambda fr, *efs: self.sweeps(fr, tuple(efs), sspec)
         )(frames, *env_frames)
-
-    def unframe_lanes(self, frames, sspec: ShardedFrameSpec):
-        """Slice every local lane's domain back out."""
-        return unframe_lanes(frames, sspec.local)
 
 
 def sweep_once(a, f, *, env=(), k=1, combine="sum", identity=None,

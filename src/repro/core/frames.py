@@ -222,7 +222,7 @@ def refill_lane_frames(frames: jnp.ndarray, interiors: jnp.ndarray,
 
 
 def unframe_lanes(frames: jnp.ndarray, spec: FrameSpec) -> jnp.ndarray:
-    """Slice every lane's (m, n) domain back out — once per round."""
+    """Slice every lane's (m, n) domain back out."""
     return frames[(slice(None), *spec.domain)]
 
 
@@ -231,7 +231,7 @@ def refill_slot_frame(frames: jnp.ndarray, interior: jnp.ndarray,
                       boundary: Boundary | str) -> jnp.ndarray:
     """Refill ONE lane slot (dynamic index ``idx``) with the next item.
 
-    The continuous-refill twin of :func:`refill_lane_frames`: the (m, n)
+    The single-slot twin of :func:`refill_lane_frames`: the (m, n)
     interior lands at the slot's domain offset via one O(interior)
     dynamic_update_slice, then every lane's ghost ring is re-asserted —
     O(lanes·(m+n)), cheaper than slicing the one (H, W) frame out and
@@ -247,7 +247,7 @@ def refill_slot_frame(frames: jnp.ndarray, interior: jnp.ndarray,
 def refill_slot_env(env_frames: jnp.ndarray, e: jnp.ndarray, idx,
                     spec: FrameSpec, boundary: Boundary | str,
                     halo: bool = False) -> jnp.ndarray:
-    """Refill ONE lane's env slot (continuous twin of
+    """Refill ONE lane's env slot (single-slot twin of
     :func:`refill_lane_env`) — interior write at the dynamic index; with
     ``halo`` the ghost rings re-assert exactly as :func:`frame_env`."""
     if not halo:
@@ -570,41 +570,12 @@ def frame_env_sharded(e_local: jnp.ndarray, sspec: ShardedFrameSpec,
         frame, sspec, b if b is Boundary.WRAP else Boundary.ZERO)
 
 
-def refill_lane_frames_sharded(frames: jnp.ndarray, interiors: jnp.ndarray,
-                               sspec: ShardedFrameSpec,
-                               boundary: Boundary | str) -> jnp.ndarray:
-    """Per-shard lane-slot refill (runs inside ``shard_map``): each lane's
-    LOCAL interior is written in place and the ghost rings re-assert via
-    the lane-batched ppermute exchange — the sharded twin of
-    :func:`refill_lane_frames`."""
-    frames = jax.lax.dynamic_update_slice(
-        frames, interiors.astype(frames.dtype), (0, *sspec.local.origin))
-    return jax.vmap(
-        lambda f: refresh_frame_sharded(f, sspec, boundary))(frames)
-
-
-def refill_lane_env_sharded(env_frames: jnp.ndarray, e: jnp.ndarray,
-                            sspec: ShardedFrameSpec,
-                            boundary: Boundary | str,
-                            halo: bool = False) -> jnp.ndarray:
-    """Sharded twin of :func:`refill_lane_env` (inside ``shard_map``)."""
-    if not halo:
-        return jax.lax.dynamic_update_slice(
-            env_frames, e.astype(env_frames.dtype), (0, 0, 0))
-    b = Boundary(boundary)
-    ghost = b if b is Boundary.WRAP else Boundary.ZERO
-    env_frames = jax.lax.dynamic_update_slice(
-        env_frames, e.astype(env_frames.dtype), (0, *sspec.local.origin))
-    return jax.vmap(
-        lambda f: refresh_frame_sharded(f, sspec, ghost))(env_frames)
-
-
 def refill_slot_frame_sharded(frames: jnp.ndarray, interior: jnp.ndarray,
                               li, owns, sspec: ShardedFrameSpec,
                               boundary: Boundary | str) -> jnp.ndarray:
     """Owner-masked refill of ONE lane slot of a SHARDED frame stack
-    (runs inside ``shard_map`` — the continuous-refill twin of
-    :func:`refill_lane_frames_sharded`).
+    (runs inside ``shard_map``; the sharded twin of
+    :func:`refill_slot_frame`).
 
     ``interior`` is this shard's LOCAL (lm, ln) block of the next item;
     ``li`` is the slot's local lane index (pre-clipped into range) and
@@ -630,7 +601,7 @@ def refill_slot_env_sharded(env_frames: jnp.ndarray, e: jnp.ndarray,
                             boundary: Boundary | str,
                             halo: bool = False) -> jnp.ndarray:
     """Owner-masked single-slot env refill inside ``shard_map`` (the
-    continuous twin of :func:`refill_lane_env_sharded`): the owner lane
+    sharded twin of :func:`refill_slot_env`): the owner lane
     shard's slot takes this shard's LOCAL env block; with ``halo`` the
     ghost strips re-assert via the ppermute exchange as
     :func:`frame_env_sharded`."""

@@ -5,8 +5,8 @@ The zero-copy and communication-avoiding claims are *structural*: no
 the ``while_loop`` body, and ppermute rounds per body that amortise over
 ``unroll`` fused sweeps.  This module is the single place that knows how
 to dig those bodies out of a traced jaxpr (shared by
-``tests/core/test_sharded.py``, ``tests/core/test_executor.py``-style
-inspections, and ``benchmarks/bench_sharded.py``).
+``tests/core/test_sharded.py``, ``tests/core/test_farm.py`` and
+``tests/core/test_executor.py``-style inspections).
 """
 from __future__ import annotations
 

@@ -24,13 +24,13 @@ HBM round-trip (temporal blocking).  Read-only per-cell fields (the
 paper's ``env``) enter through ``run(..., env=(...))`` and are staged
 once alongside the frame.
 
-The pattern is ``vmap``-safe: under ``farm`` (streaming 1:1 mode) each
-stream item runs to its own trip count while vmap executes until all are
-done (JAX's batched ``while_loop`` keeps a finished lane's carry).
+The pattern is ``vmap``-safe: under ``vmap`` (the streaming 1:1 mode)
+each stream item runs to its own trip count while vmap executes until all
+are done (JAX's batched ``while_loop`` keeps a finished lane's carry).
 :meth:`LoopOfStencilReduce.farm_run` makes that mode first-class — ONE
 while_loop over a stacked (lanes, frame) carry with per-lane done masks —
-and
-:class:`repro.core.streaming.FarmEngine` streams through it with lane
+and :class:`repro.core.streaming.FarmEngine` streams the same lane body
+in bounded segments (:meth:`LoopOfStencilReduce.lane_segment`) with lane
 slots that persist (and are refilled in place) across stream items.
 
 ``step`` mode generalises the stencil to an arbitrary pytree transformer —
@@ -418,9 +418,9 @@ class LoopOfStencilReduce:
         does every ``env`` field (stream items bring their own env).  On
         the Pallas backends the lane frames are built once and every
         sweep is ONE vmapped kernel launch; each lane runs to its own
-        trip count (``done0`` pre-masks lanes — the streaming engine uses
-        it for ragged final rounds).  Results match ``vmap(self.run)``
-        lane for lane; ordering is positional (ofarm's contract).
+        trip count (``done0`` pre-masks lanes, which then keep their
+        input).  Results match ``vmap(self.run)`` lane for lane; ordering
+        is positional (ofarm's contract).
 
         The sharded 1:n×1:1 composition (lanes spread over a mesh axis)
         lives in :class:`repro.core.streaming.FarmEngine`, which also
@@ -521,8 +521,8 @@ class LoopOfStencilReduce:
         it, done = carry[2], carry[3]
         return jnp.logical_or(done, it >= self.max_iters)
 
-    def _drive_lanes(self, a0, *, step, finalize, done0=None,
-                     cond_fold=None) -> LoopResult:
+    def _drive_lanes(self, a0, *, step, finalize, done0=None
+                     ) -> LoopResult:
         """Lane-stacked repeat/until: ``step(carry) -> (carry', r)`` with
         ``r`` of shape (lanes,); each lane owns a done flag and an
         iteration counter, and a lane whose flag (or iteration cap) has
@@ -530,13 +530,6 @@ class LoopOfStencilReduce:
         while_loop exits when no live lane remains.  Semantically
         identical to ``vmap``-ing :meth:`_drive` lane by lane, but shaped
         so a streaming executor can hold the stacked carry across items.
-
-        ``cond_fold`` optionally folds the scalar any-live predicate
-        across shard groups (inside ``shard_map``): the composed farm
-        passes a lane-axis ``pmax`` so every shard runs the SAME trip
-        count — its body carries spatial ppermutes whose rendezvous must
-        stay step-aligned mesh-wide (done-masking keeps per-lane results
-        unchanged; the extra sweeps are the barrier's waste).
         """
         r_aval = jax.eval_shape(lambda a: step(a)[1], a0)
         lanes = r_aval.shape[0]
@@ -549,8 +542,7 @@ class LoopOfStencilReduce:
 
         def cond_fun(carry):
             it, done = carry[2], carry[3]
-            live = jnp.any(jnp.logical_and(~done, it < self.max_iters))
-            return live if cond_fold is None else cond_fold(live)
+            return jnp.any(jnp.logical_and(~done, it < self.max_iters))
 
         a, r, it, _, hw = jax.lax.while_loop(cond_fun, body,
                                              (a0, r0, it0, d0, hw0))

@@ -1,60 +1,50 @@
-"""Stream-parallel tier: pipe / farm / ofarm on the persistent engine.
+"""The streaming farm: :class:`FarmEngine`, a farm of convergence loops.
 
 The paper's two-tier model [1]: data-parallel patterns (stencil, reduce,
-Loop-of-stencil-reduce) nest inside stream-parallel ones (pipe, farm).  The
-experiments use exactly two compositions:
+Loop-of-stencil-reduce) nest inside stream-parallel ones (pipe, farm).
+Its experiments use two compositions:
 
     pipe(read, sobel, write)                       (§4.2)
     pipe(read, detect, ofarm(restore), write)      (§4.3)
 
-JAX realisation, two tiers of its own:
+Both run on :class:`FarmEngine`.  Loop-of-stencil-reduce subsumes map,
+so the Sobel stream is a farm of one-sweep loops (``max_iters=1``); the
+restoration's detection pass is the farm's per-item ``prep``; ofarm's
+order is the ``index`` every result carries.
 
-* the *generic* tier — :func:`pipe`, :func:`farm`, :func:`ofarm`,
-  :func:`sharded_farm`, :class:`StreamRunner` — maps arbitrary workers
-  over stream items (vmap / batch sharding / async double-buffered
-  dispatch).  Kept for map-style stages (Sobel) and as the reference
-  path; every batch re-enters the worker from the host.
+L lane *slots* hold persistent halo frames (:mod:`repro.core.frames`).
+The farm advances in bounded early-exit segments of ONE done-masked
+lane loop over the stacked (lanes, frame) carry
+(:meth:`repro.core.pattern.LoopOfStencilReduce.lane_segment`).  A lane
+whose loop finishes hands its slot to the next item mid-flight (the
+FastFlow farm's worker refill): an O(interior) write into the slot, no
+re-pad, no re-allocation, no host round trip of the frame.  Results
+emit in completion order, so throughput does not depend on the spread
+of the items' trip counts; ``stats["wasted_lane_steps"]`` counts the
+done-masked sweeps the farm still runs.
 
-* the *engine* tier — :class:`FarmEngine` — the FastFlow-style
-  persistent-device deployment for farms whose worker is a
-  Loop-of-stencil-reduce.  L lane *slots* hold persistent halo frames
-  (:mod:`repro.core.frames`), the whole farm advances as ONE done-masked
-  ``while_loop`` over the stacked (lanes, frame) carry
-  (:meth:`repro.core.pattern.LoopOfStencilReduce.farm_run` semantics),
-  and a finished round's slots are *refilled in place* with the next
-  items' interiors — no re-pad, no re-allocation, no host round-trip of
-  the frame; only new input and extracted output cross the host
-  boundary, exactly the paper's device-buffer-persistence-across-stream-
-  items design point.  Host-side double buffering (the read stage
-  prepares round i+1 and the write stage drains round i-1 while the
-  device runs round i) rides on JAX async dispatch.
+Two dispatchers drive the segments:
 
-  The engine tier's *continuous* mode (``run(..., continuous=True)``)
-  removes the round barrier itself: the while becomes a bounded
-  early-exit segment loop, finished lanes hand their slots to the next
-  items mid-flight (the FastFlow farm's worker refill), and results
-  emit in completion order — throughput independent of the per-item
-  trip-count spread.  ``stats["wasted_lane_steps"]`` counts the
-  done-masked sweeps the barrier would have burned.  Continuous mode
-  covers EVERY deployment the round path does, including the composed
-  lanes × spatial ``pallas-sharded`` farm: there the refill scatters
-  each finished lane's LOCAL interior blocks inside ``shard_map`` with
-  owner masking (:func:`repro.core.frames.refill_slot_frame_sharded`)
-  and the ghost rings re-assert through the same O(k·n) edge-strip
-  ppermute the loop body uses — per-shard segments, no cross-lane
-  collectives.
-
-``ofarm`` ordering comes for free in the round modes: lanes are
-positional and batched execution is deterministic.  Continuous mode
-emits :class:`StreamResult` (completion order, stream index attached)
-instead.
+* **chained** (the default): ONE donated jitted entry per segment runs
+  the segment, captures the finished lanes' results and seats their
+  successors from a device staging ring the host fills ahead of need.
+  The host reads one packed metadata vector per segment, after the
+  next segment is in flight.
+* **synchronous** (``chained=False``, the composed lanes × spatial
+  ``pallas-sharded`` farm, and the repair phase after a retry): one
+  segment, a blocking read, then a per-slot refill of each finished
+  lane.  In the composed farm the refill scatters each finished lane's
+  LOCAL interior blocks inside ``shard_map`` with owner masking
+  (:func:`repro.core.frames.refill_slot_frame_sharded`), and the ghost
+  rings re-assert through the same O(k·n) edge-strip ppermute the loop
+  body uses.
 """
 from __future__ import annotations
 
 import dataclasses
 from collections import deque
 from itertools import islice
-from typing import Any, Callable, Iterator, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,10 +56,9 @@ from .spans import span
 
 
 class NonFiniteItemError(ValueError):
-    """A stream item carried NaN/Inf leaves at the prep boundary.  Round
-    mode raises it (loudly, at admission — not as an opaque NaN cascade
-    ten sweeps downstream); continuous mode routes the item to the
-    dead-letter list with ``status="rejected"`` and keeps streaming."""
+    """A stream item carried NaN/Inf leaves at the prep boundary.  The
+    farm routes the item to the dead-letter list with
+    ``status="rejected"`` and keeps streaming."""
 
 
 def item_status(hw: int, iters: int, max_iters: int) -> str:
@@ -87,123 +76,8 @@ def item_status(hw: int, iters: int, max_iters: int) -> str:
     return "timed_out" if int(iters) >= max_iters else "nonconverged"
 
 
-def pipe(*stages: Callable) -> Callable:
-    """pipe(a, b, ...) — functional composition b∘a, per stream item."""
-    def run(x):
-        for s in stages:
-            x = s(x)
-        return x
-    return run
-
-
-def farm(worker: Callable, *, lanes_axis: int = 0) -> Callable:
-    """1:1 mode, generic tier — apply ``worker`` to every item of a
-    stacked stream batch via vmap.
-
-    ``worker`` may itself be a Loop-of-stencil-reduce ``run``; done-
-    masking makes the vmapped while_loop per-lane correct.  For a farm of
-    loops on the persistent engine (one kernel launch per sweep for the
-    whole farm, lane slots reusable across stream items) use
-    :meth:`~repro.core.pattern.LoopOfStencilReduce.farm_run` /
-    :class:`FarmEngine` instead.
-    """
-    return jax.vmap(worker, in_axes=lanes_axis, out_axes=lanes_axis)
-
-
-def ofarm(worker: Callable, *, lanes_axis: int = 0) -> Callable:
-    """Order-preserving farm.  vmap is deterministic + order-preserving, so
-    this is ``farm`` with the paper's ordering contract made explicit."""
-    return farm(worker, lanes_axis=lanes_axis)
-
-
-def sharded_farm(worker: Callable, mesh: Mesh, axis: str = "data") -> Callable:
-    """Generic-tier farm whose lanes are spread over a mesh axis.
-
-    The jit wrapper is built ONCE here — constructing ``jax.jit(vw)``
-    inside ``run`` would mint a fresh wrapper (and compilation cache) per
-    call, retracing the worker on every batch (regression-tested by
-    trace counting in tests/core/test_streaming.py).  Every batch still
-    ``device_put``s its items and re-enters the worker from the host —
-    :class:`FarmEngine` (with ``mesh=``) is the engine-tier replacement
-    that keeps per-lane halo frames device-resident across batches.
-    """
-    jvw = jax.jit(jax.vmap(worker))
-    sharding = NamedSharding(mesh, P(axis))
-
-    def run(batch):
-        batch = jax.device_put(batch, sharding)
-        return jvw(batch)
-    return run
-
-
-@dataclasses.dataclass
-class StreamRunner:
-    """Host-side streaming driver: feeds batches of stream items through a
-    (jitted) worker with double-buffered async dispatch.
-
-    This is the runtime glue of the paper's streaming experiments: while the
-    device processes batch i, the host 'read' stage prepares batch i+1 and
-    the 'write' stage consumes batch i-1 (JAX async dispatch provides the
-    overlap; ``block_until_ready`` only at the sink).
-
-    Generic tier: the worker re-enters from the host per batch.  Farms of
-    convergence loops should ride :class:`FarmEngine`, which shares this
-    host protocol but keeps the loop state (the halo frames) on device
-    between batches.
-    """
-
-    worker: Callable                  # jitted device stage
-    source: Callable[[], Iterator]    # read stage: yields host items
-    sink: Callable[[Any], None]       # write stage: consumes results
-    batch: int = 1
-
-    def run(self) -> int:
-        it = self.source()
-        n = 0
-        inflight = None
-        while True:
-            chunk = []
-            for _ in range(self.batch):
-                try:
-                    chunk.append(next(it))
-                except StopIteration:
-                    break
-            if not chunk and inflight is None:
-                break
-            nxt = None
-            if chunk:
-                stacked = jax.tree.map(
-                    lambda *xs: jnp.stack(xs), *chunk) if len(chunk) > 1 \
-                    else jax.tree.map(lambda x: jnp.asarray(x)[None], chunk[0])
-                nxt = self.worker(stacked)   # async dispatch
-            if inflight is not None:
-                for item in self._unstack(inflight):
-                    self.sink(item)
-                    n += 1
-            inflight = nxt
-            if not chunk:
-                break
-        if inflight is not None:
-            for item in self._unstack(inflight):
-                self.sink(item)
-                n += 1
-        return n
-
-    @staticmethod
-    def _unstack(batched) -> Iterator:
-        """Yield per-item views of a stacked result LAZILY — the sink runs
-        on item i before item i+1 is sliced, so a sink that consumes (or
-        discards) items incrementally never holds the whole batch of
-        slices at once."""
-        leaves = jax.tree.leaves(batched)
-        if not leaves:
-            return
-        for i in range(leaves[0].shape[0]):
-            yield jax.tree.map(lambda x: x[i], batched)
-
-
 # ---------------------------------------------------------------------------
-# FarmEngine — the lane-resident streaming engine (engine tier).
+# FarmEngine — the lane-resident streaming engine.
 # ---------------------------------------------------------------------------
 
 
@@ -233,22 +107,13 @@ def _item_nbytes(item) -> int:
     return sum(leaf.nbytes for leaf in _item_leaves(item))
 
 
-def _stack_items(batch: list):
-    """Stack a list of (normalised) stream items leaf-wise."""
-    batch = [_as_item(it) for it in batch]
-    if isinstance(batch[0], tuple):
-        return tuple(np.stack([it[j] for it in batch])
-                     for j in range(len(batch[0])))
-    return np.stack(batch)
-
-
 @dataclasses.dataclass
 class StreamResult:
-    """One continuous-mode emission: the item's stream position plus the
-    fields of :class:`~repro.core.pattern.LoopResult`.  Continuous farms
-    emit in COMPLETION order (that is the point — a 1-sweep item must not
-    wait behind a 200-sweep straggler), so the index carries the ofarm
-    identity the positional contract used to.
+    """One farm emission: the item's stream position plus the fields of
+    :class:`~repro.core.pattern.LoopResult`.  The farm emits in
+    COMPLETION order (that is the point — a 1-sweep item must not wait
+    behind a 200-sweep straggler), so the index carries the ofarm
+    identity.
 
     ``status`` is the failure-semantics verdict (see :func:`item_status`
     plus ``"rejected"`` for items that failed the admission-time finite
@@ -273,37 +138,25 @@ class FarmEngine:
 
     ``loop`` is the per-item worker (a :class:`~repro.core.pattern.
     LoopOfStencilReduce`); ``lanes`` is the number of device-resident
-    slots.  Two execution modes share the slots:
-
-    * **Round-based** (default): L items are staged into the slots (an
-      O(interior) in-place refill — the frames were allocated once, at
-      stream start), the whole farm runs as ONE done-masked while_loop
-      to each lane's own trip count, and the (m, n) results are sliced
-      out.  A round completes when its *slowest* lane converges — fast
-      lanes idle behind the straggler (their done-masked sweeps are
-      counted in ``stats["wasted_lane_steps"]``).
-
-    * **Continuous** (``run(..., continuous=True)``): the while_loop
-      becomes a *segmented* loop (:meth:`~repro.core.pattern.
-      LoopOfStencilReduce.lane_segment`) that returns to the dispatcher
-      as soon as any lane converges (bounded by ``segment`` body steps);
-      the dispatcher refills ONLY the finished lanes' slots in place —
-      one O(interior) dynamic_update_slice each, no re-pad, no
-      re-framing — and resumes the SAME carry.  Results are emitted as
-      :class:`StreamResult` (completion order, stream index attached)
-      the moment their lane finishes, and throughput becomes independent
-      of the trip-count spread.  One compilation serves every segment
-      and every refill of the stream.
+    slots.  :meth:`run` streams: the farm advances as a *segmented*
+    loop (:meth:`~repro.core.pattern.LoopOfStencilReduce.lane_segment`)
+    that returns to the dispatcher as soon as any lane finishes (bounded
+    by ``segment`` body steps); ONLY the finished lanes' slots are
+    refilled in place — an O(interior) write each, no re-pad, no
+    re-framing — and the SAME carry resumes.  Each result is emitted as
+    a :class:`StreamResult` (completion order, stream index attached)
+    the moment its lane finishes.  One compilation serves every segment
+    and every refill of the stream.
 
     ``prep`` optionally maps a raw stream item to ``(a0, env_tuple)`` on
-    device (vmapped over lanes in round mode, per item in continuous
-    mode) — the farm's per-item read stage (e.g. the §4.3 detection pass
-    feeding restoration).  ``prep`` runs on the WHOLE item before any
-    spatial decomposition, so stencil-shaped preps (halo-dependent, like
-    AMF detection) see their full neighbourhood even under the composed
-    sharded deployment.  Stream items may also be TUPLES
-    ``(a, *env_items)`` carrying externally produced env fields; the
-    default prep splits them, a user ``prep`` receives the whole tuple.
+    device, one item at a time — the farm's per-item read stage (e.g.
+    the §4.3 detection pass feeding restoration).  ``prep`` runs on the
+    WHOLE item before any spatial decomposition, so stencil-shaped preps
+    (halo-dependent, like AMF detection) see their full neighbourhood
+    even under the composed sharded deployment.  Stream items may also
+    be TUPLES ``(a, *env_items)`` carrying externally produced env
+    fields; the default prep splits them, a user ``prep`` receives the
+    whole tuple.
     Every leaf — main and env alike — is shape/dtype-guarded against
     mid-stream drift (build a fresh engine per item geometry).
 
@@ -314,19 +167,16 @@ class FarmEngine:
       "pallas-multistep") — lanes spread over ``mesh[lane_axis]`` via
       ``shard_map`` (the 1:1 mode across devices: each shard owns
       lanes/P slots and its own while trip count — no collectives cross
-      the lane axis).  Both modes support this deployment.
+      the lane axis).
     * ``loop.backend == "pallas-sharded"`` — the two-tier composition:
       lanes over ``lane_axis`` × each lane's frame spatially decomposed
       over ``loop.partition``'s axes (all on the same ``mesh``), with the
       lane-batched ppermute ghost exchange inside the shared while body.
-      Both modes run here too: continuous refill scatters a finished
+      It runs the synchronous dispatcher: the refill scatters a finished
       lane's LOCAL interior blocks per shard (owner-masked, inside
       ``shard_map``) and re-asserts the ghosts through the same
       edge-strip ppermute — the segmented while runs per lane shard with
       no cross-lane collectives.
-
-    Use :meth:`run` for the full source→sink stream protocol, or
-    :meth:`round` to push one stacked batch through the slots.
     """
 
     loop: Any                          # LoopOfStencilReduce worker
@@ -334,8 +184,8 @@ class FarmEngine:
     prep: Optional[Callable] = None    # item -> (a0, env tuple), on device
     mesh: Optional[Mesh] = None
     lane_axis: str = "data"
-    segment: int = 16                  # continuous mode: max body steps
-                                       # between dispatcher check-ins
+    segment: int = 16                  # max body steps between
+                                       # dispatcher check-ins
     max_attempts: int = 1              # slot occupations per item: a
                                        # non-ok item re-enters the retry
                                        # queue (fresh slot) until this
@@ -347,8 +197,8 @@ class FarmEngine:
     check_finite: bool = True          # admission-time NaN/Inf guard on
                                        # every item leaf (host-side
                                        # O(item) scan)
-    chained: bool = True               # continuous mode: chain segments
-                                       # through the fused segment+refill
+    chained: bool = True               # chain segments through
+                                       # the fused segment+refill
                                        # entry (device staging ring, no
                                        # blocking host sync in steady
                                        # state); False restores the
@@ -415,16 +265,13 @@ class FarmEngine:
                                         # were rejected at admission
                                         # (their emitted StreamResults)
         self._prep1 = self.prep or _default_prep
-        self._vprep = jax.vmap(self._prep1)
         self._bound = False
-        self._mode = None               # "round" | "continuous" once used
         self._frames = None
         self._env_frames = ()
         # one jit wrapper per entry point for the stream's lifetime:
-        # every round / segment / refill hits the same compilation
-        # (trace-count regression-tested); the slot buffers are donated
-        # so refills update them in place
-        self._round_fn = jax.jit(self._round_impl, donate_argnums=(0, 1))
+        # every segment / refill hits the same compilation (trace-count
+        # regression-tested); the slot buffers are donated so refills
+        # update them in place
         self._segment_fn = jax.jit(self._segment_entry,
                                    donate_argnums=(0, 1, 2, 3, 4, 5))
         self._refill_fn = jax.jit(self._refill_impl,
@@ -439,12 +286,8 @@ class FarmEngine:
         self._chain_fn = jax.jit(self._chain_entry,
                                  donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7))
         self._stage_fn = jax.jit(self._stage_impl, donate_argnums=(0, 1))
-        self._waste_buf: list = []      # (waste, iters, hw, count)
-                                        # device tuples, converted
-                                        # lazily (no sync in the
-                                        # double-buffered hot path)
-        self.stats = {"items": 0, "rounds": 0, "h2d_bytes": 0,
-                      "d2h_bytes": 0, "segments": 0, "refills": 0,
+        self.stats = {"items": 0, "h2d_bytes": 0, "d2h_bytes": 0,
+                      "segments": 0, "refills": 0,
                       "lane_steps": 0, "wasted_lane_steps": 0,
                       "quarantined_lane_steps": 0, "retries": 0,
                       "rejected": 0, "quarantined_slots": 0,
@@ -455,7 +298,7 @@ class FarmEngine:
                       "recovery_seconds": 0.0}
         self._resume_state = None       # staged by restore()
         self._rt_capture = None         # live snapshot closure, set by
-                                        # run_continuous for snapshot()
+                                        # run for snapshot()
 
     # -- static geometry (first item binds the shapes) -------------------
     def _bind(self, item):
@@ -467,18 +310,17 @@ class FarmEngine:
         items_aval = jax.tree.map(
             lambda leaf: jax.ShapeDtypeStruct((L, *leaf.shape),
                                               leaf.dtype), item)
-        a_aval, env_avals = jax.eval_shape(self._vprep, items_aval)
+        a_aval, env_avals = jax.eval_shape(jax.vmap(self._prep1),
+                                           items_aval)
         if len(a_aval.shape) != 3:
             raise ValueError(
                 f"stream items must be 2-D grids; prep produced "
                 f"{a_aval.shape}")
         m, n = a_aval.shape[1:]
-        # continuous mode folds the segment length into unroll="auto":
-        # the tuned segment (T·segment sweeps per dispatch) amortizes
-        # the remaining per-dispatch cost of the chained path
-        self._loop = self.loop._resolve_unroll(
-            (m, n),
-            segment=self.segment if self._mode == "continuous" else None)
+        # the segment length folds into unroll="auto": the tuned segment
+        # (T·segment sweeps per dispatch) amortizes the remaining
+        # per-dispatch cost of the chained path
+        self._loop = self.loop._resolve_unroll((m, n), segment=self.segment)
         loop = self._loop
         self._prep_avals = (a_aval, env_avals)
         self._nshards = (1 if self.mesh is None
@@ -563,208 +405,15 @@ class FarmEngine:
         sharded mode: lanes × spatial)."""
         return P(self.lane_axis, *self._spatial)
 
-    # -- one round: refill slots, run the farm, slice results ------------
-    def _round_impl(self, frames, env_frames, items, active):
-        a0s, envs = self._vprep(items)
-        if self.mesh is None:
-            return self._local_round(frames, env_frames, a0s, envs,
-                                     active)
-        from repro.sharding.specs import shard_map
-
-        loop = self._loop
-        if loop.backend == "pallas-sharded":
-            data_spec = self._fspec()
-        else:
-            data_spec = P(self.lane_axis)
-        fr_spec = P() if loop.backend == "jnp" else data_spec
-        env_specs = tuple(data_spec for _ in env_frames)
-        fn = shard_map(
-            self._local_round, mesh=self.mesh,
-            in_specs=(fr_spec, env_specs, data_spec,
-                      tuple(data_spec for _ in envs), P(self.lane_axis)),
-            out_specs=(fr_spec, env_specs, data_spec, P(self.lane_axis),
-                       P(self.lane_axis), P(self.lane_axis),
-                       P(self.lane_axis)))
-        return fn(frames, env_frames, a0s, envs, active)
-
-    @staticmethod
-    def _round_waste(iters):
-        """Done-masked lane sweeps of one round: the barrier runs every
-        lane to the round's slowest trip count, so a lane that finished
-        at ``it_i`` idled for ``max(it) - it_i`` sweeps (premasked
-        padding lanes idle the whole round).  Shape (1,): per-shard under
-        shard_map, summed on the host."""
-        lanes = iters.shape[0]
-        return (lanes * jnp.max(iters) - jnp.sum(iters))[None]
-
-    def _round_waste_composed(self, iters):
-        """Composed-mode round waste: the barrier is MESH-global (see
-        :meth:`_lane_cond_fold`), so every lane idles behind the
-        slowest lane of ANY lane shard — fold the per-shard max over the
-        lane axis before differencing."""
-        lanes = iters.shape[0]
-        gmax = jax.lax.pmax(jnp.max(iters), self.lane_axis)
-        return (lanes * gmax - jnp.sum(iters))[None]
-
-    def _lane_cond_fold(self):
-        """Composed backend only: fold the round's any-live predicate
-        over the lane axis (ONE scalar pmax per body step) so every lane
-        shard runs the same trip count.  The loop body exchanges ghost
-        strips by ppermute along the spatial axes; lane shards pacing
-        their whiles independently would desynchronise those exchange
-        rendezvous (a latent deadlock on runtimes with global collective
-        rendezvous).  Single-device-backend lane farms carry no body
-        collectives and keep their per-shard trip counts."""
-        if self._loop.backend != "pallas-sharded":
-            return None
-        axis = self.lane_axis
-
-        def fold(live_any):
-            return jax.lax.pmax(live_any.astype(jnp.int32), axis) > 0
-        return fold
-
-    def _local_round(self, frames, env_frames, interiors, envs, active):
-        """The device-side round (directly, or per-shard inside
-        shard_map): in-place slot refill → ONE done-masked lane
-        while_loop → O(interior) result slices.  Returns
-        (frames', env_frames', outs, reduced, iters, health, waste)."""
-        loop = self._loop
-        done0 = jnp.logical_not(active)
-        if loop.backend == "jnp":
-            res = loop.farm_run(interiors, env=envs, done0=done0)
-            return (frames, env_frames, res.a, res.reduced, res.iters,
-                    res.health, self._round_waste(res.iters))
-        eng, lspec = self._eng, self._lspec
-        frames, env_frames = eng.refill_lanes(frames, env_frames,
-                                              interiors, envs, lspec)
-        fold = self._lane_cond_fold()
-        res = loop._drive_lanes(
-            frames,
-            step=lambda fr: eng.sweeps_lanes(fr, env_frames, lspec),
-            finalize=lambda fr: fr, done0=done0, cond_fold=fold)
-        outs = eng.unframe_lanes(res.a, lspec)
-        waste = (self._round_waste(res.iters) if fold is None
-                 else self._round_waste_composed(res.iters))
-        return (res.a, env_frames, outs, res.reduced, res.iters,
-                res.health, waste)
-
-    def round(self, items, count: Optional[int] = None):
-        """Push one stacked (≤ lanes, ...) batch through the slots.
-
-        ``items`` is a stacked array, a LIST of stream items, or — for
-        tuple stream items ``(a, *env)`` — a TUPLE of per-leaf stacks
-        (stack each leaf across the batch; a tuple argument is always
-        read this way, so pass a list, not a tuple, of items).
-        Returns per-item ``(a, reduced, iters, health)`` stacks of
-        length ``count`` (short batches are padded to the lane count on
-        the host and masked out on device — the shapes, and therefore
-        the compilation, never change).  Decode ``health`` with
-        :func:`repro.core.reduce.health_status`.
-        """
-        if isinstance(items, list):
-            items = _stack_items(items)
-        elif isinstance(items, tuple):
-            items = tuple(np.asarray(leaf) for leaf in items)
-        else:
-            items = np.asarray(items)
-        leaves = _item_leaves(items)
-        B = leaves[0].shape[0]
-        if any(leaf.shape[0] != B for leaf in leaves):
-            raise ValueError(
-                f"per-leaf stacks of a tuple batch must share the "
-                f"leading batch dim; got "
-                f"{tuple(leaf.shape[0] for leaf in leaves)} (a tuple "
-                "argument is read as (main, *env) per-leaf stacks — "
-                "pass a list of items to stack leaf-wise)")
-        count = B if count is None else count
-        if count > self.lanes:
-            raise ValueError(f"batch of {count} items exceeds "
-                             f"lanes={self.lanes}")
-        if self._mode == "continuous":
-            raise ValueError("engine already streamed in continuous mode;"
-                             " build a fresh FarmEngine for rounds")
-        self._mode = "round"
-        rep = jax.tree.map(lambda leaf: leaf[0], items)
-        if not self._bound:
-            self._bind(rep)
-        else:
-            self._check_item(_as_item(rep))
-        if self.check_finite:
-            # the drift check above reads only the representative item;
-            # the finite guard must sweep the WHOLE stack — round mode
-            # has no per-slot quarantine to catch a poisoned lane later
-            for i, leaf in enumerate(leaves):
-                if np.issubdtype(leaf.dtype, np.floating) \
-                        and not np.isfinite(leaf[:count]).all():
-                    which = ("stream batch" if i == 0
-                             else f"env stream batch (leaf {i - 1})")
-                    raise NonFiniteItemError(
-                        f"{which} carries NaN/Inf input values — "
-                        "rejected at the prep boundary before any lane "
-                        "is dirtied (pass check_finite=False to admit "
-                        "it anyway under sentinel quarantine)")
-        # payload accounting, symmetric with _drain's d2h: the zero
-        # lanes padding a ragged round are implementation overhead, not
-        # per-item traffic
-        self.stats["h2d_bytes"] += \
-            sum(leaf.nbytes // B for leaf in leaves) * count
-        if B < self.lanes:
-            items = jax.tree.map(
-                lambda leaf: np.concatenate(
-                    [leaf, np.zeros((self.lanes - B, *leaf.shape[1:]),
-                                    leaf.dtype)], axis=0), items)
-        if count == self.lanes:
-            if getattr(self, "_active_full", None) is None:
-                self._active_full = jnp.ones((self.lanes,), bool)
-            active = self._active_full
-        else:
-            active = jnp.asarray(np.arange(self.lanes) < count)
-        self.stats["rounds"] += 1
-        self.stats["items"] += count
-        (self._frames, self._env_frames, outs, red, iters, hw,
-         waste) = self._round_fn(
-            self._frames, self._env_frames,
-            jax.tree.map(jnp.asarray, items), active)
-        self._waste_buf.append((waste, iters, hw, count))  # lazy convert
-        if len(self._waste_buf) > 64:            # bound the buffer on
-            self._flush_waste(keep=2)            # long streams; the old
-                                                 # rounds are long done
-        return outs[:count], red[:count], iters[:count], hw[:count]
-
-    # -- lane-step/waste accounting shared by both modes -----------------
-    def _flush_waste(self, keep: int = 0):
-        """Fold buffered per-round (waste, iters, health, count) device
-        tuples into the stats — deferred so ``round()`` never forces a
-        host sync inside the double-buffered stream.  ``keep`` leaves
-        the newest entries buffered (their rounds may still be in
-        flight).  A non-ok lane's sweeps are additionally booked as
-        ``quarantined_lane_steps`` — work burned on an item that never
-        produced a usable result (the waste axis the fault plan's
-        round-vs-continuous comparison reads)."""
-        while len(self._waste_buf) > keep:
-            waste, iters, hw, count = self._waste_buf.pop(0)
-            w = int(np.asarray(waste).sum())
-            it_h = np.asarray(iters)
-            hw_h = np.asarray(hw)
-            u = int(it_h.sum())
-            self.stats["wasted_lane_steps"] += w
-            self.stats["lane_steps"] += w + u
-            for i in range(count):
-                if item_status(hw_h[i], it_h[i],
-                               self._loop.max_iters) != "ok":
-                    self.stats["quarantined_lane_steps"] += int(it_h[i])
-
+    # -- lane-step accounting ---------------------------------------------
     @property
     def wasted_lane_steps(self) -> int:
-        """Total done-masked / idle-slot lane sweeps executed so far —
-        the straggler-barrier metric continuous mode exists to shrink."""
-        self._flush_waste()
+        """Total done-masked / idle-slot lane sweeps executed so far."""
         return self.stats["wasted_lane_steps"]
 
     @property
     def lane_steps(self) -> int:
         """Total lane sweeps executed (useful + wasted)."""
-        self._flush_waste()
         return self.stats["lane_steps"]
 
     @property
@@ -772,10 +421,9 @@ class FarmEngine:
         """Lane sweeps burned on occupants that finished non-ok
         (poisoned / diverged / timed out) — the fault-waste axis next
         to ``wasted_lane_steps``."""
-        self._flush_waste()
         return self.stats["quarantined_lane_steps"]
 
-    # -- continuous mode: segmented loop + per-slot refill ---------------
+    # -- the segmented loop + per-slot refill (synchronous path) ---------
     def _lane_step(self, env_frames):
         """The per-body-step farm advance for the resident carry: the
         vmapped persistent-kernel sweep (pallas backends) or the vmapped
@@ -947,7 +595,7 @@ class FarmEngine:
 
     def _extract_impl(self, frames, idx):
         """Slice ONE lane's (m, n) domain out at a dynamic index — the
-        only per-item device→host payload of the continuous path."""
+        only per-item device→host payload of the synchronous path."""
         if self._loop.backend == "jnp":
             return jax.lax.dynamic_index_in_dim(frames, idx, axis=0,
                                                 keepdims=False)
@@ -1128,8 +776,8 @@ class FarmEngine:
                         "check_finite=False to admit it anyway under "
                         "sentinel quarantine)")
 
-    def _bind_continuous(self):
-        """Allocate the continuous carry around the bound slots: the jnp
+    def _bind_carry(self):
+        """Allocate the stream's carry around the bound slots: the jnp
         backend's resident (lanes, m, n) stack (the pallas backends reuse
         the lane frames ``_bind`` staged) plus the per-lane (r, it, done)
         vectors — all slots start retired (done, unoccupied)."""
@@ -1175,7 +823,7 @@ class FarmEngine:
         if loop.backend == "pallas-sharded":
             # the per-lane reduce dtype, evaluated abstractly through
             # the same shard_map the segments run in (the lane frames
-            # _bind staged are already the continuous slots)
+            # _bind staged are already the stream's slots)
             from repro.sharding.specs import shard_map
 
             fspec = self._fspec()
@@ -1204,7 +852,7 @@ class FarmEngine:
 
     # -- snapshot / restore (preemption recovery) ------------------------
     def snapshot(self) -> dict:
-        """The in-flight continuous-stream state as ONE logical tree:
+        """The in-flight stream state as ONE logical tree:
         every occupied slot's mid-flight interior (extracted UNSHARDED,
         whatever the deployment), its ``(reduce, iter, health)`` carry
         and raw item, the retry queue, and the source cursor
@@ -1217,24 +865,21 @@ class FarmEngine:
 
         Only meaningful at a segment boundary — call it from an
         ``on_segment`` callback (or pass ``recovery=`` to
-        :meth:`run_continuous`, which snapshots automatically)."""
+        :meth:`run`, which snapshots automatically)."""
         if self._rt_capture is None:
             raise ValueError(
-                "snapshot() captures continuous-stream state; nothing "
-                "has streamed yet — run run_continuous (pass recovery= "
+                "snapshot() captures in-flight stream state; nothing "
+                "has streamed yet — call run (pass recovery= "
                 "to persist snapshots automatically)")
         return self._rt_capture()
 
     def restore(self, state: dict) -> "FarmEngine":
-        """Stage a :meth:`snapshot` tree; the next :meth:`run_continuous`
+        """Stage a :meth:`snapshot` tree; the next :meth:`run`
         resumes from it: the source is fast-forwarded past the snapshot's
         cursor, in-flight occupants re-enter fresh slots mid-iteration,
         and pre-crash retries keep their attempt counts.  The engine's
         own geometry may differ from the snapshotting engine's (elastic
         resume); the ITEM geometry may not."""
-        if self._mode == "round":
-            raise ValueError("engine already streamed in round mode; "
-                             "build a fresh FarmEngine to restore into")
         if not isinstance(state, dict) or state.get("kind") != "farm":
             raise ValueError("not a FarmEngine snapshot tree")
         if int(state.get("version", -1)) != 1:
@@ -1243,18 +888,20 @@ class FarmEngine:
         self._resume_state = state
         return self
 
-    def run_continuous(self, source, sink, *, recovery=None,
-                       resume: bool = False,
-                       on_segment: Optional[Callable] = None) -> int:
-        """Drive a whole stream with continuous per-lane refill.
+    def run(self, source, sink, *, recovery=None, resume: bool = False,
+            on_segment: Optional[Callable] = None,
+            continuous: bool = True) -> int:
+        """Drive a whole stream with per-lane refill.
 
-        ``sink`` receives one :class:`StreamResult` per stream item —
-        EXACTLY once each, in COMPLETION order (``.index`` is the stream
-        position).  Protocol: the farm advances in bounded segments; the
-        moment a lane's convergence loop finishes, its (m, n) result is
-        extracted, the next queued item takes over the slot in place,
-        and the SAME carry resumes — the other lanes never notice.  One
-        compilation serves every segment, refill and extraction.
+        ``source`` yields items (a callable returning an iterator, or an
+        iterable).  ``sink`` receives one :class:`StreamResult` per
+        stream item — EXACTLY once each, in COMPLETION order (``.index``
+        is the stream position).  Protocol: the farm advances in bounded
+        segments; the moment a lane's convergence loop finishes, its
+        (m, n) result is extracted, the next queued item takes over the
+        slot in place, and the SAME carry resumes — the other lanes
+        never notice.  One compilation serves every segment, refill and
+        extraction.
 
         Failure semantics (DESIGN.md §Failure semantics): a lane the
         sentinel quarantined (poisoned / diverged) or that exhausted its
@@ -1269,8 +916,8 @@ class FarmEngine:
         the last slot standing.  Items failing the admission-time
         finite check emit ``status="rejected"`` without touching a
         slot.  Sweeps burned on non-ok occupants are booked as
-        ``stats["quarantined_lane_steps"]`` next to the barrier-waste
-        metric.
+        ``stats["quarantined_lane_steps"]`` next to
+        ``stats["wasted_lane_steps"]``.
 
         Preemption recovery (DESIGN.md §Recovery): with ``recovery=``
         (a :class:`repro.resilience.recovery.RecoveryConfig`) every
@@ -1289,14 +936,14 @@ class FarmEngine:
         emitted result is ever emitted twice.  ``on_segment`` is called
         with the cumulative segment count at every segment boundary —
         the seam ``FaultPlan.preempt_hook`` kills through, and where a
-        caller may take its own :meth:`snapshot`.
+        caller may take its own :meth:`snapshot`.  ``continuous=True``
+        changes nothing; ``False`` raises (the farm has no other mode).
         """
         import time as _time
 
-        if self._mode == "round":
-            raise ValueError("engine already streamed in round mode; "
-                             "build a fresh FarmEngine for continuous")
-        self._mode = "continuous"
+        if not continuous:
+            raise ValueError("FarmEngine.run streams continuously only; "
+                             "continuous=False is not supported")
 
         t_resume0 = _time.perf_counter()
         state = None
@@ -1398,7 +1045,7 @@ class FarmEngine:
             return n_out
         if not self._bound:
             self._bind(probe)
-        self._bind_continuous()
+        self._bind_carry()
         loop = self._loop
         L, unroll = self.lanes, loop.unroll
         frames, env_frames = self._frames, self._env_frames
@@ -1920,60 +1567,3 @@ class FarmEngine:
                 journal.close()
         self.stats["items"] += n_out
         return n_out
-
-    # -- the stream protocol (read ∥ compute ∥ write) --------------------
-    def run(self, source, sink, *, continuous: bool = False,
-            recovery=None, resume: bool = False,
-            on_segment: Optional[Callable] = None) -> int:
-        """Drive a whole stream: ``source`` yields items (callable
-        returning an iterator, or an iterable), ``sink`` consumes one
-        :class:`~repro.core.pattern.LoopResult` per item, in order.
-
-        Host-side double buffering: round i's dispatch is asynchronous,
-        so the host drains round i-1 into the sink (and reads round
-        i+1's items) while the device runs round i.
-
-        With ``continuous=True`` the stream runs in continuous per-lane
-        refill mode instead (see :meth:`run_continuous`): the sink
-        receives :class:`StreamResult` objects in completion order and
-        no lane ever idles behind a straggler in another slot.
-        ``recovery`` / ``resume`` / ``on_segment`` pass through to the
-        continuous path (round mode has no segment boundaries to
-        snapshot at).
-        """
-        if continuous:
-            return self.run_continuous(source, sink, recovery=recovery,
-                                       resume=resume,
-                                       on_segment=on_segment)
-        if recovery is not None or resume or on_segment is not None:
-            raise ValueError(
-                "recovery/resume/on_segment need continuous=True "
-                "(round mode has no segment boundaries to snapshot at)")
-        it = iter(source() if callable(source) else source)
-        n = 0
-        inflight = None
-        while True:
-            batch = list(islice(it, self.lanes))
-            nxt = self.round(_stack_items(batch), len(batch)) if batch \
-                else None
-            if inflight is not None:
-                n += self._drain(inflight, sink)
-            inflight = nxt
-            if not batch:
-                break
-        if inflight is not None:
-            n += self._drain(inflight, sink)
-        return n
-
-    def _drain(self, result, sink) -> int:
-        from .pattern import LoopResult
-
-        # ONE device→host pull per round (this is the point where the
-        # host blocks on the in-flight round); per-item results are then
-        # zero-copy numpy views, handed to the sink one at a time
-        outs, red, iters, hw = jax.device_get(result)
-        self.stats["d2h_bytes"] += outs.nbytes + red.nbytes + iters.nbytes
-        for i in range(outs.shape[0]):
-            sink(LoopResult(a=outs[i], reduced=red[i], iters=iters[i],
-                            health=hw[i]))
-        return outs.shape[0]

@@ -53,7 +53,7 @@ class FaultPlan:
     ``preempt_at_segment`` — a PROCESS fault: the dispatcher is killed
                            after completing this many segments (1-based)
                            via the ``on_segment`` seam in
-                           ``FarmEngine.run_continuous`` /
+                           ``FarmEngine.run`` /
                            ``ContinuousEngine.run``.  See
                            :meth:`preempt_hook`.
     """

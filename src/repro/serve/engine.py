@@ -181,8 +181,7 @@ def _arch_has_ssm(cfg: ArchConfig) -> bool:
 class ContinuousEngine:
     """Continuous-batching decode: persistent KV-cache slots with
     per-sequence refill — the serve-side twin of the farm tier's
-    continuous lane refill (:meth:`repro.core.streaming.FarmEngine.
-    run_continuous`).
+    lane refill (:meth:`repro.core.streaming.FarmEngine.run`).
 
     ``slots`` KV-cache lanes persist on device.  Decode advances in
     bounded *segments* (the :func:`repro.core.pattern.segmented_while`

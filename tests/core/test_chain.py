@@ -55,7 +55,7 @@ def stream(eng, items, **kw):
         assert r.index not in got, f"duplicate emission for {r.index}"
         got[r.index] = r
 
-    n = eng.run_continuous(items, sink, **kw)
+    n = eng.run(items, sink, **kw)
     assert n == len(got)
     return got
 
@@ -374,7 +374,7 @@ class TestChainedResilience:
                          stage_depth=8)
         got0 = {}
         with pytest.raises(PreemptionError):
-            eng.run_continuous(
+            eng.run(
                 items, lambda r: got0.__setitem__(r.index, r),
                 recovery=rec,
                 on_segment=plan.preempt_hook(mode="raise"))

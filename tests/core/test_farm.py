@@ -1,15 +1,15 @@
 """Lane-resident streaming engine: farm_run parity + lane-slot reuse.
 
 Parity: ``farm_run`` (ONE done-masked while_loop over a stacked
-(lanes, frame) carry) must match ``farm(run)`` (vmap of the scalar loop)
+(lanes, frame) carry) must match ``vmap(run)`` (vmap of the scalar loop)
 lane for lane — values, reduces, per-lane trip counts — on mixed
 convergence speeds, across the jnp / pallas / pallas-multistep backends.
 
 Slot reuse: processing stream item i+1 in an existing lane slot performs
 no ``jnp.pad``, no full-frame copy, and no re-framing — only the
 O(interior) refill plus the ghost-ring refresh.  Verified by jaxpr
-inspection of the FarmEngine round, by trace counting across a whole
-stream (ONE compilation, ragged final round included), and by the
+inspection of the FarmEngine's chained entry, by trace counting across
+a whole stream (ONE compilation, ragged tail included), and by the
 engine's own host-transfer accounting (interiors cross the boundary,
 frames never do).
 """
@@ -23,7 +23,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core import FarmEngine, LoopOfStencilReduce, farm
+from repro.core import FarmEngine, LoopOfStencilReduce
 from repro.core.executor import auto_unroll, check_unroll_feasible
 from repro.core.introspect import flatten_eqns, while_body_eqns
 from repro.kernels import ref as R
@@ -59,7 +59,7 @@ class TestFarmRunParity:
                                                    rng):
         loop = mkloop(backend, unroll)
         batch = mixed_batch(rng)
-        want = farm(loop.run)(batch)
+        want = jax.vmap(loop.run)(batch)
         got = loop.farm_run(batch)
         iters = np.asarray(got.iters)
         assert len(set(iters.tolist())) > 1, "want MIXED trip counts"
@@ -110,15 +110,17 @@ class TestFarmRunParity:
 class TestFarmEngineStream:
     @pytest.mark.parametrize("backend", BACKENDS)
     def test_stream_parity_with_per_item_runs(self, backend, rng):
-        """5 items through 2 lane slots (2 full rounds + a ragged one):
-        every item must match its solo run exactly — the refilled slot
-        carries nothing over from the previous occupant."""
+        """5 items through 2 lane slots (a ragged tail included): every
+        item must match its solo run exactly — the refilled slot carries
+        nothing over from the previous occupant."""
         loop = mkloop(backend, unroll=3 if "multistep" in backend else 1)
         items = [np.asarray(x) for x in mixed_batch(rng, n=5)]
         eng = FarmEngine(loop, lanes=2)
         outs = []
         n = eng.run(items, outs.append)
-        assert n == 5 and eng.stats["rounds"] == 3
+        assert n == 5 and eng.stats["refills"] == 5
+        assert sorted(r.index for r in outs) == list(range(5))
+        outs.sort(key=lambda r: r.index)
         for it, res in zip(items, outs):
             ref = loop.run(jnp.asarray(it))
             assert int(res.iters) == int(ref.iters)
@@ -126,15 +128,21 @@ class TestFarmEngineStream:
                                        np.asarray(ref.a), atol=1e-5)
 
     def test_empty_source_and_oversize_batch(self):
+        """An empty source emits nothing; a stream longer than the lanes
+        runs through them; ``segment=0`` is refused."""
         eng = FarmEngine(mkloop("pallas"), lanes=2)
         assert eng.run(lambda: iter([]), lambda r: None) == 0
-        with pytest.raises(ValueError, match="exceeds"):
-            eng.round(np.zeros((3, 8, 128), np.float32))
+        outs = []
+        assert eng.run([np.zeros((8, 128), np.float32)] * 3,
+                       outs.append) == 3
+        assert sorted(r.index for r in outs) == [0, 1, 2]
+        with pytest.raises(ValueError, match="segment"):
+            FarmEngine(mkloop("pallas"), lanes=2, segment=0)
 
     def test_one_compilation_across_the_stream(self, rng):
-        """The whole stream — ragged final round included — must hit ONE
-        compilation of the round function: the host pads short batches
-        to the lane count, so shapes never change."""
+        """The whole stream — ragged tail included — must hit ONE
+        compilation of the worker: the carry and the staging ring keep
+        their shapes from segment to segment and stream to stream."""
         traces = {"n": 0}
 
         def counted_heat(get, *_):
@@ -151,7 +159,7 @@ class TestFarmEngineStream:
         assert n == 3
         after_first = traces["n"]
         assert after_first > 0
-        n = eng.run(items[3:], lambda r: None)      # incl. ragged round
+        n = eng.run(items[3:], lambda r: None)      # incl. ragged tail
         assert n == 4
         assert traces["n"] == after_first, \
             f"worker retraced: {traces['n']} != {after_first}"
@@ -166,44 +174,54 @@ class TestFarmEngineStream:
                                                     shape=(m, n_))]
         eng = FarmEngine(loop, lanes=2)
         count = eng.run(items, lambda r: None)
+        assert count == 4
         cell = 4                                   # f32
-        want_h2d = eng.stats["rounds"] * 2 * m * n_ * cell
-        want_d2h = eng.stats["rounds"] * 2 * (m * n_ * cell + cell + 4)
+        want_h2d = count * m * n_ * cell
+        want_d2h = count * (m * n_ * cell + cell + 4)
         assert eng.stats["h2d_bytes"] == want_h2d
         assert eng.stats["d2h_bytes"] == want_d2h
         frame_bytes = (m + 2) * (n_ + 2) * cell
         assert eng.stats["h2d_bytes"] / count < frame_bytes
 
 
-def _round_jaxpr(backend, rng, unroll=1):
-    """Trace one FarmEngine round (slots already bound — this is the
-    steady-state 'process item i+1 in an existing slot' program)."""
+def _chain_args(eng):
+    """The chained entry's arguments at the engine's steady state: the
+    slots, the carry, the staging ring, the ring cursor, the staged-count
+    watermark and the live-slot mask."""
+    return (eng._frames, eng._env_frames, *eng._cont_carry, eng._ring,
+            eng._ring_envs, jnp.int32(0), jnp.int32(0),
+            jnp.ones((eng.lanes,), bool))
+
+
+def _chained_engine(backend, rng, unroll=1):
+    """A FarmEngine after one stream (slots, carry and ring bound — the
+    steady-state 'seat item i+1 in an existing slot' program)."""
     loop = mkloop(backend, unroll=unroll, max_iters=8)
     eng = FarmEngine(loop, lanes=2)
-    items = np.stack([np.asarray(x) for x in mixed_batch(rng, n=2)])
-    eng.round(items)                     # binds + fills the slots
-    active = jnp.ones((2,), bool)
-    return jax.make_jaxpr(eng._round_impl)(
-        eng._frames, eng._env_frames, jnp.asarray(items), active)
+    eng.run([np.asarray(x) for x in mixed_batch(rng, n=3)], lambda r: None)
+    return eng
 
 
 class TestLaneSlotReuse:
-    """The acceptance criterion, by jaxpr inspection: stream item i+1
-    lands in an existing lane slot with no pad, no full-frame copy and
-    no re-framing — only the O(interior) refill + ghost refresh."""
+    """The acceptance criterion, by jaxpr inspection of the chained
+    entry (one segment, the emission capture and the masked refill from
+    the staging ring): stream item i+1 lands in an existing lane slot
+    with no pad, no full-frame copy and no re-framing — only the
+    O(interior) refill + ghost refresh."""
 
     @pytest.mark.parametrize("backend,unroll",
                              [("pallas", 1), ("pallas-multistep", 3)])
     def test_no_pad_no_reframe_in_round(self, backend, unroll, rng):
-        jaxpr = _round_jaxpr(backend, rng, unroll)
+        eng = _chained_engine(backend, rng, unroll)
+        jaxpr = jax.make_jaxpr(eng._chain_entry)(*_chain_args(eng))
         eqns = flatten_eqns(jaxpr.jaxpr, [])
         names = [e.primitive.name for e in eqns]
-        assert "pad" not in names, "re-framing pad in the streaming round"
+        assert "pad" not in names, "re-framing pad in the chained entry"
 
         # no re-allocation of the frame stack: nothing materialises a
         # fresh full-frame-sized float array (the bool done-mask select
         # is the only frame-sized broadcast allowed)
-        lanes, fh, fw = 2, 42, 138                 # (40,136) + 2*pad
+        lanes, (fh, fw) = 2, eng._lspec.frame.shape
         frame_elems = lanes * fh * fw
         for e in eqns:
             if e.primitive.name in ("broadcast_in_dim", "iota"):
@@ -211,7 +229,7 @@ class TestLaneSlotReuse:
                     if (np.issubdtype(v.aval.dtype, np.floating)
                             and int(np.prod(v.aval.shape)) >= frame_elems):
                         raise AssertionError(
-                            f"full-frame allocation in round: {e}")
+                            f"full-frame allocation in chained entry: {e}")
 
         # every dynamic_update_slice writes at most the interior stack
         # (the refill) — a full-frame copy would exceed it
@@ -220,22 +238,20 @@ class TestLaneSlotReuse:
             if e.primitive.name == "dynamic_update_slice":
                 upd = e.invars[1].aval
                 assert int(np.prod(upd.shape)) <= interior_elems, \
-                    f"super-interior DUS in round: {upd.shape}"
+                    f"super-interior DUS in chained entry: {upd.shape}"
 
     @pytest.mark.parametrize("backend", ["pallas", "pallas-multistep"])
     def test_while_body_is_the_persistent_kernel(self, backend, rng):
         """Inside the shared while body: the vmapped fused kernel and
         the edge-sized ghost refresh — no pad, no interior-sized copies
         beyond the kernel's own frame round-trip."""
-        loop = mkloop(backend, unroll=3 if "multistep" in backend else 1,
-                      max_iters=8)
-        eng = FarmEngine(loop, lanes=2)
-        items = np.stack([np.asarray(x) for x in mixed_batch(rng, n=2)])
-        eng.round(items)
-        active = jnp.ones((2,), bool)
+        eng = _chained_engine(
+            backend, rng, unroll=3 if "multistep" in backend else 1)
+        args = _chain_args(eng)
         eqns = while_body_eqns(
-            lambda fr, it, act: eng._round_impl(fr, (), it, act)[2],
-            eng._frames, jnp.asarray(items), active)
+            lambda fr, ring: eng._chain_entry(fr, (), *args[2:6], ring,
+                                              (), *args[8:])[0],
+            eng._frames, eng._ring)
         names = [e.primitive.name for e in eqns]
         assert "pallas_call" in names
         assert "pad" not in names
@@ -269,10 +285,20 @@ SPREADS = {
 }
 
 
+def barrier_waste(iters, lanes):
+    """The done-masked lane sweeps a barrier farm would burn on these
+    per-item trip counts: batches of ``lanes`` items in stream order,
+    each run until its slowest item finishes (a short last batch runs
+    all ``lanes`` lanes too)."""
+    iters = [int(i) for i in iters]
+    return sum(lanes * max(b) - sum(b)
+               for b in (iters[i:i + lanes]
+                         for i in range(0, len(iters), lanes)))
+
+
 class TestContinuousFarm:
-    """The tentpole acceptance: continuous refill matches the round farm
-    and the sequential reference item for item, while strictly cutting
-    the done-masked lane sweeps the straggler barrier burns."""
+    """The farm matches the solo runs item for item, while strictly
+    cutting the done-masked lane sweeps a straggler barrier would burn."""
 
     @pytest.mark.parametrize("spread", list(SPREADS))
     def test_parity_and_waste_drop_jnp(self, spread):
@@ -280,33 +306,24 @@ class TestContinuousFarm:
         items = trip_items(trips)
         loop = mk_countdown("jnp")
 
-        # sequential reference: farm(run) over the stacked batch
-        want = farm(loop.run)(jnp.stack(items))
-        np.testing.assert_array_equal(np.asarray(want.iters), trips)
+        eng = FarmEngine(loop, lanes=2, segment=8)
+        outs = []
+        assert eng.run(items, outs.append) == len(items)
+        outs.sort(key=lambda r: r.index)
 
-        eng_round = FarmEngine(loop, lanes=2)
-        round_outs = []
-        assert eng_round.run(items, round_outs.append) == len(items)
-
-        eng_cont = FarmEngine(loop, lanes=2, segment=8)
-        cont_outs = []
-        assert eng_cont.run(items, cont_outs.append,
-                            continuous=True) == len(items)
-        cont_outs.sort(key=lambda r: r.index)
-
-        for i, (ro, co) in enumerate(zip(round_outs, cont_outs)):
+        for i, (it, co) in enumerate(zip(items, outs)):
+            solo = loop.run(jnp.asarray(it))
             assert co.index == i
-            assert int(ro.iters) == int(co.iters) == trips[i]
-            np.testing.assert_array_equal(np.asarray(ro.a), co.a)
-            np.testing.assert_array_equal(np.asarray(want.a[i]), co.a)
+            assert int(solo.iters) == int(co.iters) == trips[i]
+            np.testing.assert_array_equal(np.asarray(solo.a), co.a)
 
         # the metric: total lane sweeps strictly drop whenever the
         # spread gives the barrier something to waste
-        assert eng_cont.lane_steps <= eng_round.lane_steps
+        waste = barrier_waste(trips, 2)
+        assert eng.lane_steps <= sum(trips) + waste
         if spread != "uniform":
-            assert eng_cont.lane_steps < eng_round.lane_steps
-            assert (eng_cont.stats["wasted_lane_steps"]
-                    < eng_round.stats["wasted_lane_steps"])
+            assert eng.lane_steps < sum(trips) + waste
+            assert eng.stats["wasted_lane_steps"] < waste
 
     @pytest.mark.parametrize("backend,unroll",
                              [("pallas", 1), ("pallas-multistep", 3)])
@@ -315,32 +332,25 @@ class TestContinuousFarm:
         items = trip_items(trips)
         loop = mk_countdown(backend, max_iters=60, unroll=unroll)
 
-        eng_round = FarmEngine(loop, lanes=2)
-        round_outs = []
-        assert eng_round.run(items, round_outs.append) == len(items)
-
-        eng_cont = FarmEngine(loop, lanes=2, segment=6)
-        cont_outs = []
-        assert eng_cont.run(items, cont_outs.append,
-                            continuous=True) == len(items)
-        cont_outs.sort(key=lambda r: r.index)
-        for i, (ro, co) in enumerate(zip(round_outs, cont_outs)):
-            assert int(ro.iters) == int(co.iters)
-            np.testing.assert_allclose(np.asarray(ro.a), co.a, atol=1e-5)
-        assert eng_cont.wasted_lane_steps < eng_round.wasted_lane_steps
+        eng = FarmEngine(loop, lanes=2, segment=6)
+        outs = []
+        assert eng.run(items, outs.append) == len(items)
+        outs.sort(key=lambda r: r.index)
+        for it, co in zip(items, outs):
+            solo = loop.run(jnp.asarray(it))
+            assert int(solo.iters) == int(co.iters)
+            np.testing.assert_allclose(np.asarray(solo.a), co.a, atol=1e-5)
+        assert eng.wasted_lane_steps < barrier_waste(
+            [o.iters for o in outs], 2)
 
     def test_completion_order_beats_the_barrier(self):
         """A 1-sweep item sharing a cohort with a 200-sweep straggler is
-        emitted FIRST in continuous mode — the round barrier would hold
-        it until the straggler converged."""
+        emitted FIRST — a barrier would hold it until the straggler
+        converged."""
         items = trip_items([200, 1, 1, 1])
         eng = FarmEngine(mk_countdown("jnp"), lanes=2, segment=8)
-        trips = []
-        eng.run(items, lambda r: trips.append(int(r.iters)))
-        assert trips == [200, 1, 1, 1]          # barrier: item 0 first
-        eng = FarmEngine(mk_countdown("jnp"), lanes=2, segment=8)
         order = []
-        eng.run(items, lambda r: order.append(r.index), continuous=True)
+        eng.run(items, lambda r: order.append(r.index))
         assert order[0] == 1 and order[-1] == 0, order
 
     def test_one_compilation_across_segments_and_refills(self):
@@ -359,7 +369,7 @@ class TestContinuousFarm:
             interpret=True, block=(32, 128))
         eng = FarmEngine(loop, lanes=3, segment=5)
         n = eng.run(trip_items([2, 9, 4, 17, 3, 5, 2]),
-                    lambda r: None, continuous=True)
+                    lambda r: None)
         assert n == 7
         assert eng.stats["segment_traces"] == 1
         # chained + ring-seeded initial cohort: the classic per-slot
@@ -369,37 +379,23 @@ class TestContinuousFarm:
         after_first = traces["n"]
         assert after_first > 0
         # a second stream through the same engine state must not retrace
-        eng.run(trip_items([4, 2]), lambda r: None, continuous=True)
-        assert traces["n"] == after_first, "continuous worker retraced"
+        eng.run(trip_items([4, 2]), lambda r: None)
+        assert traces["n"] == after_first, "worker retraced"
         assert eng.stats["segment_traces"] == 1
 
     def test_ragged_tail_and_empty_source(self):
         eng = FarmEngine(mk_countdown("jnp"), lanes=4, segment=4)
-        assert eng.run(lambda: iter([]), lambda r: None,
-                       continuous=True) == 0
+        assert eng.run(lambda: iter([]), lambda r: None) == 0
         outs = []
-        assert eng.run(trip_items([5, 2]), outs.append,
-                       continuous=True) == 2    # items < lanes
+        assert eng.run(trip_items([5, 2]), outs.append) == 2  # items < lanes
         outs.sort(key=lambda r: r.index)
         assert [int(o.iters) for o in outs] == [5, 2]
 
-    def test_mode_mixing_rejected(self):
-        eng = FarmEngine(mk_countdown("jnp"), lanes=2)
-        eng.run(trip_items([2, 3]), lambda r: None)
-        with pytest.raises(ValueError, match="round mode"):
-            eng.run(trip_items([2]), lambda r: None, continuous=True)
-        eng = FarmEngine(mk_countdown("jnp"), lanes=2, segment=3)
-        eng.run(trip_items([2]), lambda r: None, continuous=True)
-        with pytest.raises(ValueError, match="continuous mode"):
-            eng.round(np.stack(trip_items([2, 3])))
-        with pytest.raises(ValueError, match="segment"):
-            FarmEngine(mk_countdown("jnp"), lanes=2, segment=0)
-
     def test_composed_sharded_continuous_accepted(self):
-        """The PR-4 rejection is GONE: a composed (lanes × spatial)
-        engine streams continuously — parity and waste are pinned by
-        the multi-device matrix in TestComposedContinuous; here the
-        1×1-mesh degenerate case runs in process."""
+        """A composed (lanes × spatial) engine streams — parity and
+        waste are pinned by the multi-device matrix in
+        TestComposedContinuous; here the 1×1-mesh degenerate case runs
+        in process."""
         from repro.core import GridPartition
         from repro.sharding.specs import make_mesh
         mesh = make_mesh((1, 1), ("lanes", "model"))
@@ -412,8 +408,7 @@ class TestContinuousFarm:
         eng = FarmEngine(loop, lanes=1, mesh=mesh, lane_axis="lanes",
                          segment=4)
         outs = []
-        assert eng.run(trip_items([3, 5]), outs.append,
-                       continuous=True) == 2
+        assert eng.run(trip_items([3, 5]), outs.append) == 2
         outs.sort(key=lambda r: r.index)
         assert [int(o.iters) for o in outs] == [3, 5]
 
@@ -427,19 +422,18 @@ class TestContinuousFarm:
 
         def boom(r):
             raise RuntimeError("sink failed")
-        assert eng.run(trip_items([2, 3, 4]), boom, continuous=True) == 3
+        assert eng.run(trip_items([2, 3, 4]), boom) == 3
         assert eng.stats["sink_errors"] == 3
         failed = [r for r in eng.dead_letter
                   if r.error and "sink failed" in r.error]
         assert sorted(r.index for r in failed) == [0, 1, 2]
         assert all(r.status == "failed" for r in failed)
         outs = []
-        assert eng.run(trip_items([2, 3, 4]), outs.append,
-                       continuous=True) == 3
+        assert eng.run(trip_items([2, 3, 4]), outs.append) == 3
         assert sorted(r.index for r in outs) == [0, 1, 2]
 
     def test_env_fields_survive_refill(self, rng):
-        """Per-item env fields ride the continuous refill: every item's
+        """Per-item env fields ride the refill: every item's
         result must match its solo run with ITS OWN env — a slot that
         kept the previous occupant's env would diverge."""
         loop = LoopOfStencilReduce(
@@ -454,7 +448,7 @@ class TestContinuousFarm:
 
         eng = FarmEngine(loop, lanes=2, prep=prep, segment=6)
         outs = []
-        assert eng.run(items, outs.append, continuous=True) == 5
+        assert eng.run(items, outs.append) == 5
         outs.sort(key=lambda r: r.index)
         for it, res in zip(items, outs):
             a0, envs = prep(jnp.asarray(it))
@@ -465,11 +459,11 @@ class TestContinuousFarm:
 
 
 def _segment_jaxpr(backend, unroll=1):
-    """Trace one steady-state continuous segment (slots bound and the
-    carry mid-stream — the program every segment of the stream reuses)."""
+    """Trace one steady-state segment (slots bound and the carry
+    mid-stream — the program every segment of the stream reuses)."""
     loop = mk_countdown(backend, max_iters=32, unroll=unroll)
     eng = FarmEngine(loop, lanes=2, segment=4)
-    eng.run(trip_items([3, 5, 4]), lambda r: None, continuous=True)
+    eng.run(trip_items([3, 5, 4]), lambda r: None)
     r, it, done, hw = eng._cont_carry
     return eng, jax.make_jaxpr(eng._segment_entry)(
         eng._frames, eng._env_frames, r, it, done, hw)
@@ -544,7 +538,7 @@ class TestContinuousJaxpr:
 
 class TestEnvStreamItems:
     """Tuple stream items ``(a, *env)`` carry externally produced env
-    fields through both modes, and EVERY leaf — env included — is
+    fields through both dispatchers, and EVERY leaf — env included — is
     guarded against mid-stream shape/dtype drift (regression: only the
     main leaf was checked, so a drifted env leaf reached the jitted
     refill and died as an opaque XLA shape error)."""
@@ -562,15 +556,14 @@ class TestEnvStreamItems:
         base = [np.asarray(x) for x in mixed_batch(rng, n=n)]
         return [(b, b, (b > 1.0).astype(np.float32)) for b in base]
 
-    @pytest.mark.parametrize("continuous", [False, True])
-    def test_tuple_items_match_solo_runs(self, continuous, rng):
+    @pytest.mark.parametrize("chained", [False, True])
+    def test_tuple_items_match_solo_runs(self, chained, rng):
         loop = self._mkloop()
         items = self._items(rng)
-        eng = FarmEngine(loop, lanes=2, segment=6)
+        eng = FarmEngine(loop, lanes=2, segment=6, chained=chained)
         outs = []
-        assert eng.run(items, outs.append, continuous=continuous) == 5
-        if continuous:
-            outs.sort(key=lambda r: r.index)
+        assert eng.run(items, outs.append) == 5
+        outs.sort(key=lambda r: r.index)
         for it, res in zip(items, outs):
             ref = loop.run(jnp.asarray(it[0]),
                            env=(jnp.asarray(it[1]), jnp.asarray(it[2])))
@@ -578,8 +571,8 @@ class TestEnvStreamItems:
             np.testing.assert_allclose(np.asarray(res.a),
                                        np.asarray(ref.a), atol=1e-5)
 
-    @pytest.mark.parametrize("continuous", [False, True])
-    def test_env_item_drift_is_guarded(self, continuous, rng):
+    @pytest.mark.parametrize("chained", [False, True])
+    def test_env_item_drift_is_guarded(self, chained, rng):
         """A drifted ENV leaf mid-stream must raise the same loud
         build-a-fresh-FarmEngine error the main leaf gets — not an XLA
         shape error from inside the jitted refill."""
@@ -587,17 +580,18 @@ class TestEnvStreamItems:
         a2 = items[2]
         bad = items[:2] + [(a2[0], a2[1],
                             np.zeros((8, 8), np.float32))]
-        eng = FarmEngine(self._mkloop(), lanes=2, segment=6)
+        eng = FarmEngine(self._mkloop(), lanes=2, segment=6,
+                         chained=chained)
         with pytest.raises(ValueError, match="env stream item.*fresh "
                                              "FarmEngine"):
-            eng.run(bad, lambda r: None, continuous=continuous)
+            eng.run(bad, lambda r: None)
 
     def test_env_item_arity_drift_is_guarded(self, rng):
         items = self._items(rng, n=3)
         bad = items[:2] + [(items[2][0], items[2][1])]   # env leaf lost
         eng = FarmEngine(self._mkloop(), lanes=2, segment=6)
         with pytest.raises(ValueError, match="arity changed"):
-            eng.run(bad, lambda r: None, continuous=True)
+            eng.run(bad, lambda r: None)
 
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "..", "src")
@@ -639,6 +633,8 @@ def check(eng):
     outs = []
     n = eng.run(items, outs.append)
     assert n == len(items), n
+    assert sorted(r.index for r in outs) == list(range(len(items)))
+    outs.sort(key=lambda r: r.index)
     for res, ref in zip(outs, refs):
         assert int(res.iters) == int(ref.iters), (res.iters, ref.iters)
         np.testing.assert_allclose(np.asarray(res.a), np.asarray(ref.a),
@@ -653,14 +649,14 @@ class TestFarmEngineSharded:
     def test_lanes_over_data_axis(self):
         out = run_multidevice(SHARDED_PRELUDE + """
 mesh = make_mesh((4,), ("data",))
-check(FarmEngine(mkloop("pallas"), lanes=4, mesh=mesh))
-check(FarmEngine(mkloop("jnp"), lanes=4, mesh=mesh))
+check(FarmEngine(mkloop("pallas"), lanes=4, mesh=mesh, chained=False))
+check(FarmEngine(mkloop("jnp"), lanes=4, mesh=mesh, chained=False))
 print("OKLANES")
 """)
         assert "OKLANES" in out
 
     def test_continuous_lanes_over_data_axis(self):
-        """Continuous refill with lanes spread over the mesh: each lane
+        """Chained refill with lanes spread over the mesh: each lane
         shard runs its own segments (no collectives cross the lane
         axis); parity vs the solo runs, every item exactly once."""
         out = run_multidevice(SHARDED_PRELUDE + """
@@ -668,7 +664,7 @@ mesh = make_mesh((4,), ("data",))
 for backend in ("pallas", "jnp"):
     eng = FarmEngine(mkloop(backend), lanes=4, mesh=mesh, segment=6)
     outs = []
-    n = eng.run(items, outs.append, continuous=True)
+    n = eng.run(items, outs.append)
     assert n == len(items), n
     assert sorted(r.index for r in outs) == list(range(len(items)))
     outs.sort(key=lambda r: r.index)
@@ -710,6 +706,7 @@ eng = FarmEngine(mkrestore("pallas-sharded", part), lanes=4, mesh=mesh,
 outs = []
 n = eng.run(items, outs.append)
 assert n == len(items), n
+outs.sort(key=lambda r: r.index)
 jref = mkrestore("jnp")
 for it, res in zip(items, outs):
     a0, envs = prep(jnp.asarray(it))
@@ -731,8 +728,9 @@ mesh = make_mesh((2, 4), ("data", "model"))
 part = GridPartition(mesh=mesh, axis_names=("model",), array_axes=(0,))
 check(FarmEngine(mkloop("pallas-sharded", part), lanes=4, mesh=mesh))
 # unroll='auto' checks the condition every T sweeps: parity against the
-# jnp path at the SAME resolved T (iters overshoot by < T vs unroll=1)
-T = auto_unroll(64, 64, k=1, block=(16, 128), part=part)
+# jnp path at the SAME resolved T (iters overshoot by < T vs unroll=1);
+# the farm folds its segment length (16 body steps) into T
+T = auto_unroll(64, 64, k=1, block=(16, 128), part=part, segment=16)
 assert T > 1, T
 refs = [mkloop("jnp", unroll=T).run(jnp.asarray(it)) for it in items]
 check(FarmEngine(mkloop("pallas-sharded", part, unroll="auto"),
@@ -741,8 +739,8 @@ print("OKCOMPOSED")
 """)
         assert "OKCOMPOSED" in out
 
-    @pytest.mark.parametrize("continuous", [False, True])
-    def test_default_mesh_accepted(self, continuous, rng):
+    @pytest.mark.parametrize("chained", [False, True])
+    def test_default_mesh_accepted(self, chained, rng):
         """``jax.make_mesh``'s default mesh (Explicit axes) is normalised
         to Auto by FarmEngine and GridPartition, so lanes over it stream
         and match the solo runs."""
@@ -754,12 +752,13 @@ print("OKCOMPOSED")
                              array_axes=(0,))
         assert all(t == AxisType.Auto for t in part.mesh.axis_types)
         loop = mkloop("pallas")
-        eng = FarmEngine(loop, lanes=2, mesh=mesh, segment=6)
+        eng = FarmEngine(loop, lanes=2, mesh=mesh, segment=6,
+                         chained=chained)
         assert all(t == AxisType.Auto for t in eng.mesh.axis_types)
         items = list(np.asarray(mixed_batch(rng, n=3)))
         outs = []
-        assert eng.run(items, outs.append, continuous=continuous) == 3
-        outs.sort(key=lambda r: getattr(r, "index", 0))
+        assert eng.run(items, outs.append) == 3
+        outs.sort(key=lambda r: r.index)
         for it, res in zip(items, outs):
             ref = loop.run(jnp.asarray(it))
             assert int(res.iters) == int(ref.iters)
@@ -812,11 +811,11 @@ part = GridPartition(mesh=mesh, axis_names=("model",), array_axes=(0,))
 
 @pytest.mark.slow
 class TestComposedContinuous:
-    """The tentpole acceptance on the composed (lanes × spatial)
-    deployment, in an 8-virtual-device subprocess: continuous refill
-    matches round mode item for item on adversarial trip-count spreads,
-    strictly cuts the barrier's wasted lane sweeps on non-uniform
-    spreads, compiles once per entry point, and is structurally clean
+    """The composed (lanes × spatial) deployment, in an 8-virtual-device
+    subprocess: the farm matches the solo runs item for item on
+    adversarial trip-count spreads, strictly cuts the wasted lane sweeps
+    a barrier would burn on non-uniform spreads, compiles once per entry
+    point, and is structurally clean
     (no pad, owner-masked interior-sized refill writes, collectives
     along the SPATIAL axes only — nothing crosses the lane axis)."""
 
@@ -827,25 +826,34 @@ SPREADS = {
     "bimodal": [1, 200, 1, 1, 200, 1, 1, 1, 1, 1, 1, 1],
     "straggler": [2, 2, 2, 200, 2, 2, 2, 2],
 }
+solo = LoopOfStencilReduce(
+    f=countdown, k=1, combine="max", cond=lambda r: r < 0.5,
+    boundary="zero", max_iters=256, backend="jnp")
+
+def barrier_waste(iters, lanes):
+    # batches of `lanes` items in stream order, each run to its slowest
+    return sum(lanes * max(b) - sum(b)
+               for b in (iters[i:i + lanes]
+                         for i in range(0, len(iters), lanes)))
+
 for name, trips in SPREADS.items():
     items = trip_items(trips)
-    eng_r = FarmEngine(mk(part), lanes=4, mesh=mesh)
-    r_outs = []
-    assert eng_r.run(items, r_outs.append) == len(trips)
     eng_c = FarmEngine(mk(part), lanes=4, mesh=mesh, segment=8)
     c_outs = []
-    assert eng_c.run(items, c_outs.append, continuous=True) == len(trips)
+    assert eng_c.run(items, c_outs.append) == len(trips)
     assert sorted(r.index for r in c_outs) == list(range(len(trips)))
     c_outs.sort(key=lambda r: r.index)
-    for i, (ro, co) in enumerate(zip(r_outs, c_outs)):
+    for i, (it, co) in enumerate(zip(items, c_outs)):
+        ro = solo.run(jnp.asarray(it))
         assert int(ro.iters) == int(co.iters) == trips[i], (
             name, i, ro.iters, co.iters)
         np.testing.assert_array_equal(np.asarray(ro.a), co.a)
     assert eng_c.stats["segment_traces"] == 1
     assert eng_c.stats["refill_traces"] == 1
     if name != "uniform":
-        assert eng_c.wasted_lane_steps < eng_r.wasted_lane_steps, (
-            name, eng_c.wasted_lane_steps, eng_r.wasted_lane_steps)
+        waste = barrier_waste(trips, 4)
+        assert eng_c.wasted_lane_steps < waste, (
+            name, eng_c.wasted_lane_steps, waste)
 print("OKMATRIX")
 """)
         assert "OKMATRIX" in out
@@ -858,12 +866,12 @@ print("OKMATRIX")
 eng = FarmEngine(mk(part), lanes=4, mesh=mesh, segment=4)
 order = []
 n = eng.run(trip_items([200, 1, 1, 1, 1, 1]),
-            lambda r: order.append(r.index), continuous=True)
+            lambda r: order.append(r.index))
 assert n == 6, n
 assert order[-1] == 0, order       # the straggler emits LAST
 assert eng.stats["segment_traces"] == 1
 assert eng.stats["refill_traces"] == 1
-eng.run(trip_items([2, 3]), lambda r: None, continuous=True)
+eng.run(trip_items([2, 3]), lambda r: None)
 assert eng.stats["segment_traces"] == 1    # no retrace across streams
 assert eng.stats["refill_traces"] == 1
 print("OKORDER")
@@ -874,7 +882,7 @@ print("OKORDER")
         out = run_multidevice(COMPOSED_PRELUDE + """
 from repro.core.introspect import flatten_eqns
 eng = FarmEngine(mk(part), lanes=4, mesh=mesh, segment=4)
-eng.run(trip_items([3, 5, 4, 2, 6]), lambda r: None, continuous=True)
+eng.run(trip_items([3, 5, 4, 2, 6]), lambda r: None)
 r, it, done, hw = eng._cont_carry
 
 def collective_axes(eqns):
@@ -923,7 +931,7 @@ print("OKJAXPR")
 
     def test_continuous_prep_and_env_refill(self):
         """Halo-aware prep + per-item env slots ride the composed
-        continuous refill: every item must match its solo run with ITS
+        refill: every item must match its solo run with ITS
         OWN env (a slot keeping the previous occupant's env — or a
         non-owner shard clobbering a live slot — would diverge)."""
         out = run_multidevice(SHARDED_PRELUDE + """
@@ -948,7 +956,7 @@ def mkrestore(backend, part=None):
 eng = FarmEngine(mkrestore("pallas-sharded", part), lanes=4, mesh=mesh,
                  prep=prep, segment=6)
 outs = []
-n = eng.run(items, outs.append, continuous=True)
+n = eng.run(items, outs.append)
 assert n == len(items), n
 outs.sort(key=lambda r: r.index)
 jref = mkrestore("jnp")

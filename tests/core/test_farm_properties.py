@@ -52,7 +52,7 @@ class TestRefillInvariants:
         each result equal to its solo run (trip count AND values)."""
         eng = FarmEngine(mk_countdown(), lanes=lanes, segment=segment)
         outs = []
-        n = eng.run(trip_items(trips), outs.append, continuous=True)
+        n = eng.run(trip_items(trips), outs.append)
         assert n == len(trips)
         assert sorted(r.index for r in outs) == list(range(len(trips)))
         outs.sort(key=lambda r: r.index)
@@ -69,7 +69,7 @@ class TestRefillInvariants:
         """lane_steps = useful + wasted, with useful = Σ trip counts —
         the waste metric never undercounts (and never goes negative)."""
         eng = FarmEngine(mk_countdown(), lanes=lanes, segment=segment)
-        n = eng.run(trip_items(trips), lambda r: None, continuous=True)
+        n = eng.run(trip_items(trips), lambda r: None)
         assert n == len(trips)
         useful = sum(trips)
         assert eng.stats["wasted_lane_steps"] >= 0
@@ -97,7 +97,7 @@ class TestRefillInvariants:
         items = [base * s for s in scales]
         eng = FarmEngine(loop, lanes=lanes, segment=segment)
         outs = []
-        assert eng.run(items, outs.append, continuous=True) == len(items)
+        assert eng.run(items, outs.append) == len(items)
         outs.sort(key=lambda r: r.index)
         for it, res in zip(items, outs):
             ref = loop.run(jnp.asarray(it))
@@ -116,6 +116,6 @@ class TestRefillInvariants:
         trips = list(range(1, n_items + 1))
         eng = FarmEngine(mk_countdown(), lanes=lanes, segment=segment)
         outs = []
-        n = eng.run(trip_items(trips), outs.append, continuous=True)
+        n = eng.run(trip_items(trips), outs.append)
         assert n == n_items == len(outs)
         assert sorted(r.index for r in outs) == list(range(n_items))

@@ -9,7 +9,7 @@ pytest.importorskip("hypothesis", reason="property tests need hypothesis "
                     "(pip install -r requirements-dev.txt)")
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (LoopOfStencilReduce, farm, loop_of_stencil_reduce,
+from repro.core import (LoopOfStencilReduce, loop_of_stencil_reduce,
                         loop_of_stencil_reduce_d, loop_of_stencil_reduce_s)
 from repro.core import semantics as sem
 
@@ -123,7 +123,7 @@ class TestStreaming:
             cond=lambda r: r < 1e-3, delta=lambda n, o: jnp.abs(n - o),
             max_iters=2000)
         batch = jnp.stack([field(1), field(2) * 10.0, field(3) * 0.01])
-        out = farm(runner.run)(batch)
+        out = jax.vmap(runner.run)(batch)
         solo = [runner.run(batch[i]) for i in range(3)]
         for i in range(3):
             assert int(out.iters[i]) == int(solo[i].iters)

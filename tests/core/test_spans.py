@@ -249,7 +249,7 @@ def test_farm_spans_on_the_host_plane(tmp_path):
     got = []
     jax.profiler.start_trace(str(tmp_path))
     try:
-        eng.run_continuous(items, got.append)
+        eng.run(items, got.append)
     finally:
         jax.profiler.stop_trace()
     assert sorted(r.index for r in got) == list(range(len(items)))

@@ -13,10 +13,10 @@ tests use.  The contracts under fault:
   loud failure     — every faulty item surfaces a non-ok status (and
                      the dead-letter list); nothing hangs, nothing
                      silently returns NaN
-  waste dominance  — under faults, continuous-mode
-                     ``wasted + quarantined`` lane steps stay strictly
-                     below round mode's (the barrier burns the fault's
-                     straggler shadow on every lane)
+  waste dominance  — under faults, the farm's ``wasted + quarantined``
+                     lane steps stay strictly below a barrier farm's on
+                     the same trip counts (the barrier burns the
+                     fault's straggler shadow on every lane)
 """
 import os
 import subprocess
@@ -32,7 +32,7 @@ from repro.core import FarmEngine, LoopOfStencilReduce
 from repro.core.reduce import (HEALTH_CONVERGED, HEALTH_DIVERGED,
                                HEALTH_POISONED, Sentinel, health_status,
                                health_update)
-from repro.core.streaming import NonFiniteItemError, item_status
+from repro.core.streaming import item_status
 from repro.resilience import FaultPlan
 
 
@@ -123,14 +123,10 @@ class TestSentinel:
 
     def test_sentinel_off_still_reports_converged(self):
         """health rides every run (sentinel or not): a plain loop's
-        results decode 'ok' for free, in both modes."""
+        results decode 'ok' for free."""
         eng = FarmEngine(mk_countdown(), lanes=2, segment=4)
-        got = stream(eng, trip_items([3, 5]), continuous=True)
+        got = stream(eng, trip_items([3, 5]))
         assert all(r.status == "ok" for r in got.values())
-        eng2 = FarmEngine(mk_countdown(), lanes=2)
-        outs = []
-        eng2.run(trip_items([3, 5]), outs.append)
-        assert [health_status(r.health) for r in outs] == ["ok", "ok"]
 
 
 class TestFaultPlan:
@@ -161,7 +157,7 @@ class TestFaultPlan:
 
 
 # ---------------------------------------------------------------------------
-# Farm chaos — the acceptance fault plan through both modes
+# Farm chaos — the acceptance fault plan through both dispatchers
 # ---------------------------------------------------------------------------
 
 PLAN = FaultPlan(lanes=4, nan_events=((1, 2),), stall_events=((2, 1 << 20),))
@@ -181,10 +177,9 @@ class TestFarmChaos:
         to the fault-free run; nothing hangs, nothing emits twice."""
         clean, faulty = self._loops()
         items = trip_items(TRIPS)
-        ref = stream(FarmEngine(clean, lanes=4, segment=4), items,
-                     continuous=True)
+        ref = stream(FarmEngine(clean, lanes=4, segment=4), items)
         eng = FarmEngine(faulty, lanes=4, segment=4)
-        got = stream(eng, items, continuous=True)
+        got = stream(eng, items)
         assert sorted(got) == list(range(len(items)))
         statuses = {i: got[i].status for i in got}
         assert "poisoned" in statuses.values()
@@ -208,11 +203,10 @@ class TestFarmChaos:
         failures and are quarantined out of the rotation."""
         clean, faulty = self._loops()
         items = trip_items(TRIPS)
-        ref = stream(FarmEngine(clean, lanes=4, segment=4), items,
-                     continuous=True)
+        ref = stream(FarmEngine(clean, lanes=4, segment=4), items)
         eng = FarmEngine(faulty, lanes=4, segment=4, max_attempts=3,
                          slot_patience=2)
-        got = stream(eng, items, continuous=True)
+        got = stream(eng, items)
         assert all(r.status == "ok" for r in got.values()), {
             i: r.status for i, r in got.items()}
         for i, r in got.items():
@@ -227,32 +221,38 @@ class TestFarmChaos:
         assert eng.stats["refill_traces"] == 1
 
     def test_round_mode_surfaces_statuses_too(self):
-        """Round mode has no retry path, but the health word rides the
-        stacked result: per-lane statuses decode from LoopResult."""
+        """The synchronous dispatcher (``chained=False``) seats item i in
+        slot i and surfaces the same per-slot statuses: the NaN-ed slot
+        poisons its item, the stalled slot's item fails, the rest are
+        ok and finite."""
         _, faulty = self._loops()
-        eng = FarmEngine(faulty, lanes=4)
-        got = []
-        eng.run(trip_items([3, 5, 4, 6]), got.append)
-        statuses = [health_status(r.health) for r in got]
+        eng = FarmEngine(faulty, lanes=4, segment=4, chained=False)
+        got = stream(eng, trip_items([3, 5, 4, 6]))
+        statuses = [got[i].status for i in range(4)]
         assert statuses[1] == "poisoned"
         assert statuses[0] == "ok" and np.isfinite(got[0].a).all()
         assert statuses[2] != "ok"                    # stalled lane
         assert eng.quarantined_lane_steps > 0
 
     def test_waste_dominance_under_faults(self):
-        """The acceptance inequality: under the SAME fault plan,
-        continuous wasted+quarantined lane steps stay strictly below
-        round mode's — the stalled lane becomes a straggler whose
-        shadow the round barrier burns on every healthy lane."""
+        """The acceptance inequality: under the fault plan, the farm's
+        wasted+quarantined lane steps stay strictly below those of a
+        barrier farm on the same emitted trip counts — batches of
+        ``lanes`` items in stream order, each run to its slowest, burn
+        ``lanes·max(it) − Σit``: the stalled lane becomes a straggler
+        whose shadow the barrier burns on every healthy lane."""
         _, faulty = self._loops()
         items = trip_items(TRIPS)
-        eng_r = FarmEngine(faulty, lanes=4)
-        eng_r.run(items, lambda r: None)
-        eng_c = FarmEngine(faulty, lanes=4, segment=4)
-        eng_c.run(items, lambda r: None, continuous=True)
-        cost = lambda e: e.wasted_lane_steps + e.quarantined_lane_steps
-        assert cost(eng_c) < cost(eng_r), (
-            eng_c.stats, eng_r.stats)
+        eng = FarmEngine(faulty, lanes=4, segment=4)
+        got = stream(eng, items)
+        its = [int(got[i].iters) for i in range(len(items))]
+        barrier = sum(4 * max(its[i:i + 4]) - sum(its[i:i + 4])
+                      for i in range(0, len(its), 4))
+        quarantined = sum(int(r.iters) for r in got.values()
+                          if r.status != "ok")
+        assert eng.quarantined_lane_steps == quarantined
+        cost = eng.wasted_lane_steps + eng.quarantined_lane_steps
+        assert cost < barrier + quarantined, (eng.stats, its)
 
     def test_quarantine_never_eats_the_last_slot(self):
         """lanes=1 degenerate: the only slot fails every occupant, yet
@@ -262,7 +262,7 @@ class TestFarmChaos:
         loop = plan.instrument(mk_countdown(max_iters=8))
         eng = FarmEngine(loop, lanes=1, segment=4, max_attempts=2,
                          slot_patience=1)
-        got = stream(eng, trip_items([3, 4]), continuous=True)
+        got = stream(eng, trip_items([3, 4]))
         assert all(r.status != "ok" for r in got.values())
         assert all(r.attempts == 2 for r in got.values())
         assert eng.stats["quarantined_slots"] == 0
@@ -276,12 +276,18 @@ class TestFarmChaos:
 
 class TestAdmissionCheck:
     def test_round_mode_rejects_nonfinite_batch_loudly(self):
-        eng = FarmEngine(mk_countdown(), lanes=2)
+        """A NaN item after the slots are bound is rejected at the prep
+        boundary — ``status="rejected"``, dead-lettered — and the rest
+        of the stream is untouched."""
+        eng = FarmEngine(mk_countdown(), lanes=2, segment=4)
         eng.run(trip_items([2, 3]), lambda r: None)   # binds
         bad = trip_items([2, 3])
         bad[1][4, 7] = np.nan
-        with pytest.raises(NonFiniteItemError, match="NaN/Inf"):
-            eng.run(bad, lambda r: None)
+        got = stream(eng, bad)
+        assert {i: r.status for i, r in got.items()} == {
+            0: "ok", 1: "rejected"}
+        assert got[1].a is None
+        assert [d.index for d in eng.dead_letter] == [1]
 
     def test_continuous_mode_rejects_and_keeps_streaming(self):
         """A corrupted item is shed at the door — status='rejected',
@@ -290,7 +296,7 @@ class TestAdmissionCheck:
         plan = FaultPlan(lanes=2, corrupt_indices=(1, 4))
         items = trip_items([3, 5, 4, 6, 2])
         eng = FarmEngine(mk_countdown(), lanes=2, segment=4)
-        got = stream(eng, plan.corrupt_stream(items), continuous=True)
+        got = stream(eng, plan.corrupt_stream(items))
         assert {i: r.status for i, r in got.items()} == {
             0: "ok", 1: "rejected", 2: "ok", 3: "ok", 4: "rejected"}
         assert all(got[i].a is None for i in (1, 4))
@@ -306,12 +312,15 @@ class TestAdmissionCheck:
             interpret=True)
         a = trip_items([3])[0]
         mask = (a > 0.5).astype(np.float32)
-        eng = FarmEngine(loop, lanes=2)
-        eng.run([(a, a.copy(), mask)], lambda r: None)
+        eng = FarmEngine(loop, lanes=2, segment=4)
+        assert stream(eng, [(a, a.copy(), mask)])[0].status != "rejected"
         bad_mask = mask.copy()
         bad_mask[3, 9] = np.inf
-        with pytest.raises(NonFiniteItemError, match="env"):
-            eng.run([(a, a.copy(), bad_mask)], lambda r: None)
+        # only the env leaf is non-finite: the check reads every leaf
+        got = stream(eng, [(a, a.copy(), mask), (a, a.copy(), bad_mask)])
+        assert got[0].status != "rejected" and got[0].a is not None
+        assert got[1].status == "rejected" and got[1].a is None
+        assert eng.stats["rejected"] == 1
 
     def test_check_finite_off_defers_to_the_sentinel(self):
         """check_finite=False admits the poisoned item; the sentinel
@@ -322,7 +331,7 @@ class TestAdmissionCheck:
         eng = FarmEngine(
             mk_countdown(max_iters=32, sentinel=Sentinel(nan=True)),
             lanes=2, segment=4, check_finite=False)
-        got = stream(eng, plan.corrupt_stream(items), continuous=True)
+        got = stream(eng, plan.corrupt_stream(items))
         assert got[1].status == "poisoned"
         assert int(got[1].iters) < 32                 # no spin to cap
         assert got[0].status == "ok" and got[2].status == "ok"
@@ -389,8 +398,7 @@ def drive(items):
     eng = FarmEngine(mk(part), lanes=2, mesh=mesh, segment=4,
                      check_finite=False)
     got = {}
-    n = eng.run(items, lambda r: got.setdefault(r.index, r),
-                continuous=True)
+    n = eng.run(items, lambda r: got.setdefault(r.index, r))
     assert n == len(items) == len(got), (n, len(got))
     return got
 

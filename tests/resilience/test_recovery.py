@@ -257,7 +257,7 @@ class TestSeededPreemptPlans:
 def run_reference(items, lanes=4):
     eng = FarmEngine(loop=mk_countdown(), lanes=lanes, segment=2)
     got, sink = collect()
-    eng.run(items, sink, continuous=True)
+    eng.run(items, sink)
     return got
 
 
@@ -274,14 +274,13 @@ class TestFarmElasticResume:
         eng = FarmEngine(loop=mk_countdown(), lanes=lanes0, segment=2)
         got0, sink0 = collect()
         with pytest.raises(PreemptionError):
-            eng.run(items, sink0, continuous=True, recovery=rec,
+            eng.run(items, sink0, recovery=rec,
                     on_segment=plan.preempt_hook(mode="raise"))
         # resumed process: FRESH consumer, different lane count, hook
         # disarmed (first-launch-only arming)
         eng2 = FarmEngine(loop=mk_countdown(), lanes=lanes1, segment=2)
         got, sink = collect()
-        n = eng2.run(items, sink, continuous=True, recovery=rec,
-                     resume=True)
+        n = eng2.run(items, sink, recovery=rec, resume=True)
         assert n == len(items) and sorted(got) == list(range(len(items)))
         for i in range(len(items)):
             assert got[i].status == ref[i].status == "ok"
@@ -306,7 +305,7 @@ class TestFarmElasticResume:
                              fsync=False)
         eng3 = FarmEngine(loop=mk_countdown(), lanes=3, segment=2)
         got, sink = collect()
-        n = eng3.run(items, sink, continuous=True, recovery=rec,
+        n = eng3.run(items, sink, recovery=rec,
                      resume=True)
         assert n == len(items)
         assert eng3.stats["replayed_items"] == len(items)
@@ -320,20 +319,19 @@ class TestFarmElasticResume:
         ref_eng = FarmEngine(loop=mk_countdown(backend="pallas"),
                              lanes=2, segment=2)
         ref, ref_sink = collect()
-        ref_eng.run(items, ref_sink, continuous=True)
+        ref_eng.run(items, ref_sink)
         rec = RecoveryConfig(dir=str(tmp_path), snapshot_every=1,
                              fsync=False)
         plan = FaultPlan(lanes=2, preempt_at_segment=2)
         eng = FarmEngine(loop=mk_countdown(backend="pallas"), lanes=2,
                          segment=2)
         with pytest.raises(PreemptionError):
-            eng.run(items, collect()[1], continuous=True, recovery=rec,
+            eng.run(items, collect()[1], recovery=rec,
                     on_segment=plan.preempt_hook(mode="raise"))
         eng2 = FarmEngine(loop=mk_countdown(backend="pallas"), lanes=3,
                          segment=2)
         got, sink = collect()
-        n = eng2.run(items, sink, continuous=True, recovery=rec,
-                     resume=True)
+        n = eng2.run(items, sink, recovery=rec, resume=True)
         assert n == 4
         for i in range(4):
             np.testing.assert_array_equal(got[i].a, ref[i].a)
@@ -350,7 +348,7 @@ class TestFarmElasticResume:
             if r.index == 1:
                 raise IOError("disk full")
             got[r.index] = r
-        n = eng.run(items, sink, continuous=True)
+        n = eng.run(items, sink)
         assert n == 6
         assert eng.stats["sink_errors"] == 1
         assert sorted(got) == [0, 2, 3, 4, 5]
@@ -522,7 +520,7 @@ def sink(r):
                            "sum": float(np.asarray(r.a).sum()),
                            "a00": float(np.asarray(r.a)[0, 0])}}) + "\\n")
     out.flush()
-n = eng.run(items, sink, continuous=True, recovery=rec, resume=resume,
+n = eng.run(items, sink, recovery=rec, resume=resume,
             on_segment=hook)
 out.close()
 """
@@ -640,7 +638,7 @@ def sink(r):
                            "iters": int(r.iters),
                            "sum": float(np.asarray(r.a).sum())}}) + "\\n")
     out.flush()
-eng.run(items, sink, continuous=True, recovery=rec, resume=resume,
+eng.run(items, sink, recovery=rec, resume=resume,
         on_segment=hook)
 out.close()
 """
@@ -659,8 +657,7 @@ class TestKillAndRespawnComposed:
             loop=mk_countdown(max_iters=32),
             lanes=2, segment=2)
         ref, ref_sink = collect()
-        ref_eng.run(trip_items(self.TRIPS, shape=(32, 64)), ref_sink,
-                    continuous=True)
+        ref_eng.run(trip_items(self.TRIPS, shape=(32, 64)), ref_sink)
 
         outpath = str(tmp_path / "emitted.jsonl")
         code = _COMPOSED_CHILD.format(

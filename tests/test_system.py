@@ -3,10 +3,8 @@ applications run through the Loop-of-stencil-reduce machinery and produce
 physically sensible results (paper §4 structure)."""
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-from repro.core import (LoopOfStencilReduce, GridPartition, farm, pipe,
-                        StreamRunner, loop_of_stencil_reduce_d)
+from repro.core import FarmEngine, LoopOfStencilReduce
 from repro.kernels import ops, ref as R
 
 
@@ -29,19 +27,23 @@ class TestHelmholtzApp:
 
 class TestSobelApp:
     def test_stream_of_images(self, rng):
-        """pipe(read, sobel, write) over a stream (paper §4.2)."""
-        import jax
-        frames = [jnp.asarray(rng.uniform(size=(32, 64)), jnp.float32)
+        """pipe(read, sobel, write) over a stream (paper §4.2): the
+        pattern subsumes map, so the Sobel stage is a farm of one-sweep
+        loops."""
+        frames = [np.asarray(rng.uniform(size=(32, 64)), np.float32)
                   for _ in range(7)]
+        sobel = LoopOfStencilReduce(
+            f=R.sobel_taps(), k=1, combine="max", identity=-jnp.inf,
+            cond=lambda r: True, boundary="reflect", max_iters=1)
         outs = []
-        worker = jax.jit(jax.vmap(lambda im: ops.sobel(im)[0]))
-        n = StreamRunner(worker=worker,
-                         source=lambda: iter(frames),
-                         sink=lambda o: outs.append(o), batch=3).run()
+        n = FarmEngine(sobel, lanes=3).run(frames, outs.append)
         assert n == 7
-        want, _ = ops.sobel(frames[0])
-        np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(want),
-                                   atol=1e-5)
+        assert sorted(r.index for r in outs) == list(range(7))
+        for res in outs:
+            assert res.status == "ok" and int(res.iters) == 1
+            want, r = ops.sobel(jnp.asarray(frames[res.index]))
+            np.testing.assert_allclose(res.a, np.asarray(want), atol=1e-5)
+            np.testing.assert_allclose(res.reduced, float(r), rtol=1e-6)
 
     def test_edge_detector_finds_edges(self):
         img = np.zeros((40, 80), np.float32)
@@ -53,7 +55,8 @@ class TestSobelApp:
 
 class TestRestorationApp:
     def test_two_phase_pipeline(self, rng):
-        """pipe(read, detect, ofarm(restore), write) (paper §4.3)."""
+        """pipe(read, detect, ofarm(restore), write) (paper §4.3), one
+        frame: detection, then restoration from the detected frame."""
         yy, xx = np.mgrid[0:48, 0:64]
         frame = np.clip(0.5 + 0.4 * np.sin(xx / 9.0) * np.cos(yy / 7.0),
                         0, 1).astype(np.float32)
@@ -61,15 +64,8 @@ class TestRestorationApp:
         sp = np.where(rng.uniform(size=frame.shape) < 0.5, 0.0, 1.0)
         noisy = jnp.asarray(np.where(imp, sp, frame), jnp.float32)
 
-        def detect(x):
-            mask, repaired = ops.adaptive_median_detect(x)
-            return repaired, mask
-
-        def restore(args):
-            u0, mask = args
-            out, d, it = ops.restore(u0, mask, max_iters=50)
-            return out
-        restored = pipe(detect, restore)(noisy)
+        mask, repaired = ops.adaptive_median_detect(noisy)
+        restored, _, _ = ops.restore(repaired, mask, max_iters=50)
 
         def psnr(x):
             return -10 * np.log10(np.mean((np.asarray(x) - frame) ** 2)
